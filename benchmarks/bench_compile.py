@@ -1,4 +1,4 @@
-"""Compile-time accounting for the mesh programs (VERDICT r3 #4).
+"""Compile-time accounting for the mesh programs.
 
 A pod-scale program whose compile takes tens of minutes per
 (shape, P) config is a real deployment cost: this tool measures the

@@ -32,7 +32,7 @@ from benchmarks.common import (Timer, build_graph, cpu_mesh_env, emit,
 def capacity_worker(num_parts: int, slack, batch: int, fanout,
                     num_nodes: int):
   """One capacity config on a ``num_parts``-device virtual mesh —
-  measures what VERDICT-r1 called the frontier-capacity math: hop-3
+  measures the frontier-capacity math: hop-3
   frontier = batch * 15 * 10 ids/device exchanged under a 2x-balanced
   cap vs exact."""
   import jax
@@ -76,7 +76,7 @@ def capacity_worker(num_parts: int, slack, batch: int, fanout,
 
 def subgraph_worker(num_parts: int, hop_chunk, batch: int,
                     num_nodes: int):
-  """SEAL-at-scale envelope (VERDICT r2 item 7): induced-subgraph
+  """SEAL-at-scale envelope: induced-subgraph
   loader with the full-window hop CHUNKED, so the widest all_to_all is
   ``[P, chunk, max_degree]`` regardless of closure size — the config
   that aborted at P>=16 when the window spanned the whole node table."""
@@ -130,7 +130,7 @@ IGBH_LARGE_SHAPES = {
 
 def memory_envelope(num_parts: int = 128, hbm_gb: float = 95.0,
                     split_ratio: float = 0.25, feat_bytes: int = 4):
-  """BASELINE north-star check (VERDICT r4 #9): does IGBH-large fit a
+  """BASELINE north-star check: does IGBH-large fit a
   v5p-128 pod under the host-local tiered layout?  Array-residency
   bytes per chip, analytic from `IGBH_LARGE_SHAPES`:
 
@@ -333,8 +333,8 @@ def _locality_comparison(num_parts: int, rows, cols, num_nodes: int,
 
 def envelope_worker(num_parts: int, mode: str, batch: int,
                     num_nodes: int, epochs: int = 5):
-  """Scale-envelope probe at ``num_parts`` VIRTUAL devices (VERDICT r3
-  #6: past P=32): a deliberately tiny workload — the point is the
+  """Scale-envelope probe at ``num_parts`` VIRTUAL devices (past
+  P=32): a deliberately tiny workload — the point is the
   PER-P exchange behavior (padding waste, drops, adaptive-slack
   convergence), not throughput, since 64-128 virtual devices
   oversubscribe this box's cores ~10x.  ``mode``: 'homo' (adaptive
@@ -466,7 +466,7 @@ def envelope_worker(num_parts: int, mode: str, batch: int,
     except Exception as e:          # never sink the envelope row
       out['locality_error'] = f'{type(e).__name__}: {e}'
   # the BASELINE north-star memory check rides along on every
-  # envelope row (VERDICT r4 #9)
+  # envelope row
   out['memory_envelope_v5p128'] = memory_envelope(128)
   print(json.dumps(out), flush=True)
   from benchmarks.common import tee_record
@@ -885,7 +885,7 @@ def capacity_sweep(quick: bool):
         ['--subgraph-worker', '--num-parts', p, '--hop-chunk', chunk,
          '--batch', 32, '--nodes', sg_n],
         env=cpu_mesh_env(p))
-  # scale envelope past P=32 (VERDICT r3 #6): P=64/128 homo with
+  # scale envelope past P=32: P=64/128 homo with
   # adaptive slack, hetero and chunked-SEAL at P=64 — tiny shapes (the
   # virtual devices oversubscribe the cores; the exchange accounting,
   # not throughput, is the deliverable)
@@ -910,7 +910,7 @@ def main():
   ap.add_argument('--envelope-worker', action='store_true')
   ap.add_argument('--memory-envelope', action='store_true',
                   help='print the IGBH-large-on-v5p-128 per-chip '
-                       'memory table (VERDICT r4 #9)')
+                       'memory table')
   ap.add_argument('--chaos', action='store_true',
                   help='resilience smoke: fault-free host '
                        'server->client throughput with the retry '

@@ -2,9 +2,7 @@
 
 The tiered store's static ``split_ratio`` slice (`sort_by_in_degree`
 hot prefix) leaves every cold lookup a synchronous host gather on the
-batch critical path — BENCH_r05 measured the tiered mesh loader
-*losing* throughput to the untiered one (250.6 vs 282.0 seeds/s, cold
-hit rate 0.329).  PyTorch-Direct and Global Neighbor Sampling
+batch critical path.  PyTorch-Direct and Global Neighbor Sampling
 (PAPERS.md) both show that a small dynamically-maintained device cache
 plus overlapped cold access recovers most of the fully-resident
 throughput.  This module is that cache, TPU-shaped:
@@ -424,7 +422,7 @@ class DeviceColdCache:
         next(iter(self.rows.devices())))
 
 
-# -- pinned-host zero-copy cold gather (r19, ISSUE 18) ---------------------
+# -- pinned-host cold gather (r19, ISSUE 18) --------------------------------
 
 _PINNED_ENV = 'GLT_PALLAS_COLD'
 
@@ -436,53 +434,55 @@ def pinned_cold_enabled() -> bool:
       '1', 'true', 'on', 'yes')
 
 
-def _host_memory_sharding(dev):
-  """Best available host-side memory placement for ``dev``:
-  ``pinned_host`` where the backend has it (TPU — device-initiated
-  DMA reads the buffer without a host staging copy), else the
-  backend's plain host kind (CPU tier-1: the gather program is the
-  exact functional twin, just without the zero-copy property).
-  Returns ``(sharding, kind)``."""
-  from jax.sharding import SingleDeviceSharding
-  kinds = {m.kind for m in dev.addressable_memories()}
-  for kind in ('pinned_host', 'unpinned_host'):
-    if kind in kinds:
-      return SingleDeviceSharding(dev, memory_kind=kind), kind
-  return SingleDeviceSharding(dev), 'device'
+def _host_take(rows: jax.Array, idx: jax.Array) -> jax.Array:
+  """``rows[idx]`` with BOTH operands in host memory: the gather runs
+  as XLA host compute and only its ``[B, D]`` result crosses to device
+  memory.  This is the one formulation that lowers on jax 0.9.0 on
+  both backends (v5e run, PR 21): a gather whose operands sit in
+  different memory spaces is refused at trace time, a host/host
+  gather outside ``compute_on`` fails in XLA:TPU, and XLA:CPU refuses
+  a host->device ``out_shardings`` but accepts the in-program
+  `device_put`.  A raw `lax.gather`, because `jnp.take`'s index
+  normalisation compares against device-space constants."""
+  from jax.experimental.compute_on import compute_on
+  dnums = jax.lax.GatherDimensionNumbers(
+      offset_dims=(1,), collapsed_slice_dims=(0,), start_index_map=(0,))
+  with compute_on('device_host'):
+    out = jax.lax.gather(
+        rows, idx[:, None], dnums, slice_sizes=(1, rows.shape[1]),
+        mode=jax.lax.GatherScatterMode.PROMISE_IN_BOUNDS)
+  return jax.device_put(out, jax.memory.Space.Device)
 
 
 class PinnedColdBuffer:
   """Cold-tier feature rows resident in pinned HOST memory, served by
-  a device-initiated jitted gather (PyTorch-Direct / GIDS style —
+  one jitted gather per batch (PyTorch-Direct / GIDS in spirit —
   PAPERS.md arXiv 2101.07956, 2306.16384).
 
-  The PR 5 overlay's cold fill is ``np.take`` on the host followed by
-  a full-batch transfer — the host CPU touches every cold byte twice
+  The PR 5 overlay's cold fill is ``np.take`` in Python followed by a
+  full-batch transfer — the interpreter touches every cold byte twice
   (gather + copy into the transfer buffer).  Here the cold rows are
-  device_put ONCE into the accelerator-visible host memory kind and
-  every per-batch fill is one compiled ``take`` whose output lands in
-  device memory: the irregular access moves into the gather program
-  (device-initiated DMA over PCIe/ICI on TPU), the host stops
-  touching feature bytes per batch.  Byte parity with the ``np.take``
-  path is exact — same rows, same dtype cast (applied once at build
-  instead of per batch) — and pinned by tests/test_pallas_sample.py.
+  device_put ONCE into ``pinned_host`` memory and every per-batch fill
+  is one compiled program (`_host_take`): the take runs as XLA host
+  compute next to the rows and its output lands in device memory.
+  It is NOT a device-initiated DMA over the host buffer — no such
+  gather lowers on the installed JAX.  Byte parity with the
+  ``np.take`` path is exact — same rows, same dtype cast (applied once
+  at build instead of per batch) — pinned by
+  tests/test_pallas_sample.py and re-checked on a v5e (PR 21).
 
   Owns the ``pinned_host`` memaccount tier: the buffer is that
   tier's whole bill, so ``memory.tier_bytes{tier=pinned_host}``
   tracks it live on /metrics.
 
-  Roofline note (r19): the fill is bandwidth-bound on the host link
-  (PCIe gen3 ~12 GB/s practical per direction; ICI-attached hosts
-  more), so the ceiling is link bandwidth x batch cold bytes — the
-  ``np.take`` path it replaces was never near that line because the
-  per-batch host gather + staging copy are latency/dispatch-bound
-  (the r18 roofline's 1.355 GB/s untiered-XLA comparison point).
-  The guarded bench row (`benchmarks/bench_pallas_sample.py`,
-  ``pallas.feature_lookup_gbps``) holds the pinned path above that
-  line on hardware; CPU tier-1 pins byte parity only."""
+  Whether this beats ``np.take`` on a chip is not measured (ROADMAP
+  S5); the knob stays opt-in until it is."""
+
+  memory_kind = 'pinned_host'
 
   def __init__(self, rows_np: np.ndarray, dim: int, dtype,
                device: Optional[jax.Device] = None):
+    from jax.sharding import SingleDeviceSharding
     dev = device if device is not None else jax.devices()[0]
     arr = np.ascontiguousarray(rows_np)
     if dtype is not None:
@@ -490,16 +490,13 @@ class PinnedColdBuffer:
     if arr.ndim != 2 or arr.shape[1] != int(dim):
       raise ValueError(f'expected [rows, {dim}] cold block, got '
                        f'{arr.shape}')
-    sharding, self.memory_kind = _host_memory_sharding(dev)
-    self.rows = jax.device_put(arr, sharding)
-    from jax.sharding import SingleDeviceSharding
-    self._gather = jax.jit(
-        lambda rows, idx: jnp.take(rows, idx, axis=0),
-        out_shardings=SingleDeviceSharding(dev))
-    # capability probe: run one tiny gather end-to-end NOW so a
-    # backend that cannot lower host-memory gathers fails here, at
-    # build, where the caller can fall back — never per batch
-    np.asarray(self._gather(self.rows, jnp.zeros((1,), jnp.int32)))
+    self._host = SingleDeviceSharding(dev, memory_kind=self.memory_kind)
+    self.rows = jax.device_put(arr, self._host)
+    self._gather = jax.jit(_host_take)
+    # run one tiny gather end-to-end NOW so a backend that cannot
+    # lower it fails here, at build, with the compiler's message —
+    # never on some later batch
+    np.asarray(self.gather(np.zeros((1,), np.int32)))
     from ..telemetry.memaccount import register_tier
     register_tier('pinned_host',
                   lambda r=self.rows: int(getattr(r, 'nbytes', 0)))
@@ -507,32 +504,24 @@ class PinnedColdBuffer:
   def gather(self, idx: np.ndarray) -> jax.Array:
     """``[B] -> [B, D]`` device rows; indices are buffer-relative
     (caller subtracts the hot-row base) and must be in range."""
-    return self._gather(self.rows, jnp.asarray(
-        np.ascontiguousarray(idx, np.int32)))
+    return self._gather(self.rows, jax.device_put(
+        np.ascontiguousarray(idx, np.int32), self._host))
 
 
 def make_pinned_cold_buffer(rows_np, dim: int, dtype,
                             device=None) -> Optional[PinnedColdBuffer]:
-  """`PinnedColdBuffer` when ``GLT_PALLAS_COLD`` is on and the
-  backend can serve it, else None (the caller keeps the host
-  ``np.take`` path — transparent fallback, byte-identical output).
-  Emits the kernel dispatch/fallback event once, at build."""
+  """`PinnedColdBuffer` when ``GLT_PALLAS_COLD`` is on, else None (the
+  caller keeps the host ``np.take`` path).  An opted-in build that
+  fails RAISES: it never quietly becomes the host path.  Emits the
+  kernel dispatch event once, at build."""
   from ..telemetry.recorder import recorder
   if not pinned_cold_enabled():
     return None
-  try:
-    buf = PinnedColdBuffer(rows_np, dim, dtype, device=device)
-  except ValueError:
-    raise                          # contract errors surface as-is
-  except Exception as ex:
-    if recorder.enabled:
-      recorder.emit('pallas.fallback', kernel='cold_gather',
-                    reason=type(ex).__name__)
-    return None
+  buf = PinnedColdBuffer(rows_np, dim, dtype, device=device)
   if recorder.enabled:
     recorder.emit('pallas.dispatch', kernel='cold_gather',
                   rows=int(buf.rows.shape[0]),
-                  memory_kind=str(buf.memory_kind))
+                  memory_kind=buf.memory_kind)
   return buf
 
 

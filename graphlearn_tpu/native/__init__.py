@@ -2,9 +2,10 @@
 
 The reference exposes its C++/CUDA layer through a pybind11 module
 (`python/py_export.cc:46-216`); this build uses a plain C ABI + ctypes
-(no pybind11 in the image).  The library is auto-built with ``make`` on
-first import if missing or stale — the moral equivalent of the
-reference's build-on-install `setup.py` extension.
+(no pybind11 in the image).  No binary is checked in: the library is
+built with ``make`` from ``csrc/`` on first use (and rebuilt when a
+source is newer) — the moral equivalent of the reference's
+build-on-install `setup.py` extension.
 
 Everything here is *host* runtime: cross-process shm queues and
 serialization for the producer pipeline, and CPU twins of the sampling
@@ -53,19 +54,22 @@ def _build(force: bool = False):
     if all(os.path.getmtime(s) <= so_mtime for s in srcs if
            os.path.exists(s)):
       return
-  if force and os.path.exists(_SO):
-    # make's mtime check would skip the rebuild; the stale artifact
-    # must go first
-    os.unlink(_SO)
-  subprocess.run(['make', '-s', f'OUT={_SO}'], cwd=_CSRC, check=True)
+  # build beside the target and rename: producer subprocesses race to
+  # first use, and a half-written library must never be loadable
+  tmp = f'{_SO}.{os.getpid()}.tmp'
+  try:
+    subprocess.run(['make', '-s', f'OUT={tmp}'], cwd=_CSRC, check=True)
+    os.replace(tmp, _SO)
+  finally:
+    if os.path.exists(tmp):
+      os.unlink(tmp)
 
 
 def lib() -> ctypes.CDLL:
   """The loaded native library (built on first use).  A binary that
-  fails to *load* — typically an artifact carried over from a host
-  with a different libstdc++/glibc — is rebuilt in place from source
-  and retried once, instead of poisoning every native-dependent path
-  on this machine."""
+  fails to *load* — a built tree copied from a host with a different
+  libstdc++/glibc — is rebuilt from source and retried once, instead
+  of poisoning every native-dependent path on this machine."""
   global _lib
   if _lib is None:
     with _lock:

@@ -4,9 +4,7 @@
 W``) materializes a ``[B, W]`` gathered window, a ``[B, W]`` Gumbel
 tensor and a full ``top_k`` sort network per hop; the GNS twin
 (`ops/gns.py`) adds a ``[B, W]`` membership gather, a ``[B, W]``
-cumulative-weight vector and a vmapped ``searchsorted`` on top.  In
-the r5 FusedEpoch profile those intermediates are the bulk of the
-~104 ms/step sort-based sampling cost.  This module fuses the whole
+cumulative-weight vector and a vmapped ``searchsorted`` on top.  This module fuses the whole
 medium arm into ONE Pallas kernel:
 
   * the seed's CSR window arrives by aligned-overfetch DMA
@@ -42,21 +40,32 @@ and reproduces the XLA outputs bit-for-bit:
 against `sample_one_hop` / `sample_one_hop_gns` in interpret mode on
 CPU tier-1 for every arm.
 
+**On a chip (one v5e, PR 21 bring-up, nothing timed).**  The UNIFORM
+kernel compiles non-interpreted and is value-identical to
+`sample_one_hop` on the products-scale CSR at B=1024/k=15 and
+B=15360/k=10, called directly and through `sample_one_hop_auto` under
+`jit`.  The GNS kernel does NOT lower: Mosaic answers
+``Unimplemented primitive in Pallas TPU lowering for KernelType.TC:
+dynamic_slice`` (the per-requester row select,
+`dynamic_index_in_dim` over the bits block) — so on a TPU, a GNS
+sampler with ``GLT_PALLAS_SAMPLE=1`` raises that message.
+
 **Dispatch discipline** (the `pallas_gather.py` precedent): default
 OFF; ``GLT_PALLAS_SAMPLE`` is re-read at every dispatch (kill
-switch), `sample_one_hop_auto` falls back to the XLA kernels —
-transparently and at value parity — whenever the shape, dtype or
-backend disqualifies the kernel, and emits ``pallas.dispatch`` /
-``pallas.fallback`` events at trace time so the chosen path is
-visible in traces without taxing the steady state.
+switch), `sample_one_hop_auto` takes the XLA kernels — at value
+parity — whenever `fused_sample_supported` disqualifies the shape or
+dtype (a documented rule, stamped into a ``pallas.fallback`` event),
+and emits ``pallas.dispatch`` at trace time so the chosen path is
+visible in traces without taxing the steady state.  A qualified
+kernel that then fails to trace or compile raises; it never silently
+becomes the twin.
 
-**Roofline note (r19).**  The medium arm moves ``8 KB`` of window
-DMA + ``k`` compacted outputs per seed where the XLA path moves the
-``[B, W]`` window plus the sort's O(W log W) compare network through
-HBM; at the bench shapes (B=4096, k=8, W=64) that is ~6x less HBM
-traffic on the draw path.  Like r5's window verdict, the win must be
-re-measured on real hardware (`benchmarks/bench_pallas_sample.py`);
-CPU tier-1 only pins correctness.  The beyond-window hub arm and the
+**Roofline note (r19, from shapes — not a measurement).**  The medium
+arm moves ``8 KB`` of window DMA + ``k`` compacted outputs per seed
+where the XLA path moves the ``[B, W]`` window plus the sort's
+O(W log W) compare network through HBM; at the bench shapes (B=4096,
+k=8, W=64) that is ~6x less HBM traffic on the draw path.  Whether
+that is a win is for a chip measurement to say (ROADMAP S1).  The beyond-window hub arm and the
 O(E) `prepare_window_table` repack stay outside the kernel — pass a
 prebuilt ``table`` on repeated calls (the `NeighborSampler` caches
 one per graph version) or the repack lands on the per-call path.
@@ -82,6 +91,14 @@ from .pallas_window import (LANES, MAX_W, SUBLANES, UNIT, _TILE,
 SAMPLE_ENV = 'GLT_PALLAS_SAMPLE'
 
 #: scalar-prefetch budget — same bound as `pallas_gather._MAX_DMA_IDS`.
+#: At the flagship shape (batch 1024, fanout [15, 10, 5]) the third
+#: hop's frontier is 153,600 seeds, so the largest hop never takes the
+#: kernel.  The bound is also loose: this kernel prefetches 3 (uniform)
+#: or 4 (GNS) ``[B]`` int32 vectors into the v5e's 1 MB SMEM, and the
+#: compiler refused the 2-vector window kernel at B=153,600 ("Used
+#: 1.17M of 1.00M smem", PR 21 chip run) — a B between ~80k and this
+#: bound is expected to be refused the same way (it raises; it is not
+#: caught).  Left for ROADMAP S1 to settle with a measurement.
 _MAX_IDS = 1 << 17
 
 #: VMEM budget for the replicated per-requester bits block (the dedup
@@ -480,21 +497,19 @@ def sample_one_hop_auto(
         bits=bits, replace=replace,
         num_edges=int(indices.shape[0]))
     if reason is None:
-      try:
-        out = sample_one_hop_fused(
-            indptr, indices, seeds, k, key, edge_ids, bits=bits,
-            boost=bst, req=req, window=window,
-            with_edge_ids=with_edge_ids,
-            sort_locality=sort_locality, table=table)
-        _emit('pallas.dispatch', kernel='fused_sample',
-              mode=('gns' if gns else 'uniform'),
-              batch=int(seeds.shape[0]), k=int(k))
-        return out
-      except ValueError:
-        raise                      # contract errors surface as-is
-      except Exception as ex:      # pragma: no cover - lowering gap
-        reason = f'trace-error:{type(ex).__name__}'
-  if fused and reason is not None:
+      # an opted-in, shape-qualified kernel that fails to trace or
+      # compile RAISES: a caught failure would turn every
+      # kernel-vs-twin comparison into twin-vs-twin without a word
+      out = sample_one_hop_fused(
+          indptr, indices, seeds, k, key, edge_ids, bits=bits,
+          boost=bst, req=req, window=window,
+          with_edge_ids=with_edge_ids,
+          sort_locality=sort_locality, table=table)
+      _emit('pallas.dispatch', kernel='fused_sample',
+            mode=('gns' if gns else 'uniform'),
+            batch=int(seeds.shape[0]), k=int(k))
+      return out
+  if fused:
     _emit('pallas.fallback', kernel='fused_sample', reason=reason,
           batch=int(seeds.shape[0]), k=int(k))
   if gns:

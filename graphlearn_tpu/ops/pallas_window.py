@@ -12,9 +12,13 @@ view ``indices`` as ``[R, 128]`` lanes, DMA the TWO 4 KB-aligned
 and cut the exact ``[w]`` slice with lane+sublane rotates (dynamic
 slice does not lower in Mosaic; dynamic rotates do).
 
-Measured on the real chip by ``benchmarks/bench_pallas_window.py``;
-the verdict lives in `ops/pallas_gather.py`'s module notes.  The
-sampler keeps whichever path that measurement favors.
+On one v5e (PR 21 bring-up, nothing timed) the kernel compiles and
+matches `xla_window_gather` on the products-scale CSR at B=1024/w=120
+and B=15360/w=80; at B=153600/w=64 the compiler refuses it — its two
+``[B]`` scalar-prefetch vectors need 1.17 MB of the 1 MB SMEM — and
+this function has no guard for that, so the compiler's message is what
+the caller sees.  ``benchmarks/bench_pallas_window.py`` is the speed
+comparison; it has not been run on today's code (ROADMAP S1, D2).
 """
 from __future__ import annotations
 

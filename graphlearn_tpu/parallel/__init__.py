@@ -17,6 +17,6 @@ from .dist_sampler import (DistLinkNeighborLoader, DistLinkNeighborSampler,
                            DistSubGraphLoader, DistSubGraphSampler,
                            bucket_by_owner, dist_edge_exists, dist_gather,
                            dist_sample_negative)
-from .exchange import (ExchangeSpec, HAVE_RAGGED, capacity_spec,
+from .exchange import (ExchangeSpec, capacity_spec,
                        mesh_factors, plan_exchange, resolve_layout,
                        simulate_assignment)

@@ -600,8 +600,7 @@ def build_dist_feature(feats: np.ndarray, old2new: np.ndarray,
                        split_ratio: float = 1.0) -> DistFeature:
   """Shard a feature table by the relabeled ownership ranges.
 
-  ``split_ratio < 1`` builds the TIERED store (VERDICT r2 item 1 /
-  reference `data/feature.py:174-206` + `unified_tensor.cu:202+`):
+  ``split_ratio < 1`` builds the TIERED store (reference `data/feature.py:174-206` + `unified_tensor.cu:202+`):
   only the first ``ceil(split_ratio * rows)`` rows of each partition —
   the hottest, when the relabel was built with ``hotness`` — go to the
   HBM shard; the full table stays in host DRAM as the cold tier, so

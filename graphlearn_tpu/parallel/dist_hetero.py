@@ -744,8 +744,8 @@ class DistHeteroNeighborSampler(ExchangeTelemetry):
       emit_cache_events('hetero', 0, int(misses), 0, 0)
     hp = (self.ds.host_parts if self.ds.host_parts is not None
           else np.arange(self.num_parts))
-    # ONE capacity handshake for every owner-served type (ADVICE r4:
-    # a per-(type, batch) allgather dominates at large P x many
+    # ONE capacity handshake for every owner-served type (a
+    # per-(type, batch) allgather dominates at large P x many
     # types): plan all types first, agree on all capacities in a
     # single `_global_max_vec`, then execute each overlay
     from .dist_sampler import _global_max_vec, plan_cold_requests

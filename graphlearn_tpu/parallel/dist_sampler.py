@@ -1194,7 +1194,7 @@ def _make_dist_subgraph_step(mesh: Mesh, num_parts: int,
   scanned in chunks of that many closure nodes, so every all_to_all
   buffer is ``[P, chunk]`` requests / ``[P, chunk, max_degree]``
   replies instead of ``[P, node_cap]`` — the SEAL-at-scale envelope
-  (VERDICT r2 item 7): peak exchange width becomes
+: peak exchange width becomes
   ``chunk * P * max_degree`` regardless of closure size, at the cost
   of ``ceil(node_cap / chunk)`` serialized exchanges.  Results are
   EXACT either way (each chunk's window is still unsampled).
@@ -1772,7 +1772,7 @@ class DistNeighborSampler(ExchangeTelemetry):
         and self.collect_features and not self.collect_labels)
     # tiered store: HBM shards hold only each partition's hot rows;
     # cold rows live in host DRAM and are overlaid post-step
-    # (`_maybe_overlay_cold`) — VERDICT r2 item 1 / reference
+    # (`_maybe_overlay_cold`) — reference
     # `data/feature.py:174-206` + `csrc/cuda/unified_tensor.cu:202+`.
     self.tiered = (self.collect_features
                    and dataset.node_features.is_tiered)
@@ -2617,7 +2617,7 @@ def _global_max_vec(vs) -> list:
   """Vector form of `_global_max_int`: ONE allgather agrees on the
   element-wise max of a whole list — hetero batches with many tiered
   node types pay one DCN round trip instead of one per type
-  (ADVICE r4: the per-(type, batch) handshake can dominate batch time
+  (the per-(type, batch) handshake can dominate batch time
   at large P)."""
   if jax.process_count() == 1:
     return [int(v) for v in vs]
@@ -2662,7 +2662,7 @@ def plan_cold_requests(nodes, bounds, hot_counts, host_parts,
   engine) run this per store, agree on all capacities in ONE
   `_global_max_vec` handshake, then execute each overlay with
   ``agreed_capacity`` — one DCN round trip per batch instead of one
-  per store (ADVICE r4)."""
+  per store."""
   hp = [int(p) for p in host_parts]
   num_parts = len(hot_counts)
   nodes_l = (nodes_host if nodes_host is not None
@@ -2725,7 +2725,7 @@ def overlay_cold_owner(x, nodes, bounds, hot_counts, cold_local, mesh,
     return x, lookups, 0
   n_cold = int(cold.sum())
   c_pad = next_power_of_two(c_req)
-  # vectorized (requester, owner) bucketing (ADVICE r4: the nested
+  # vectorized (requester, owner) bucketing (the nested
   # pl x P python loops were per-batch host work): stable-sort the
   # cold rows by their (j, owner) group; slot-in-group = rank minus
   # the group's first rank

@@ -24,8 +24,15 @@ from ..models.train import TrainState, supervised_loss
 
 
 def make_mesh(n_devices: Optional[int] = None, axis: str = 'data') -> Mesh:
-  """1-D device mesh over the first ``n_devices`` devices."""
-  devs = jax.devices()[:n_devices] if n_devices else jax.devices()
+  """1-D device mesh over the first ``n_devices`` devices (all of them
+  when None).  Asking for more devices than exist is an error, never a
+  silently smaller mesh."""
+  devs = jax.devices()
+  if n_devices:
+    if n_devices > len(devs):
+      raise ValueError(f'make_mesh({n_devices}): only {len(devs)} '
+                       f'{devs[0].platform} device(s) visible')
+    devs = devs[:n_devices]
   return Mesh(np.asarray(devs), (axis,))
 
 

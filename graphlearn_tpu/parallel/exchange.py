@@ -43,10 +43,13 @@ This module makes the layout a pluggable choice behind one API
     overflow is never silent.
 
 ``ragged``
-    ``jax.lax.ragged_all_to_all`` (newer JAX, TPU): per-destination
-    send sizes are runtime values, so there is no capacity waste at
-    all.  Version-gated at import time (`HAVE_RAGGED`); on jax 0.4.37
-    or CPU `resolve_layout` falls back to ``compact``.
+    ``jax.lax.ragged_all_to_all``: per-destination send sizes are
+    runtime values, so there is no capacity waste at all.  NOT
+    SELECTABLE: `_RaggedPlan` has never executed on any device (and
+    carries a known receive-overflow hole), so `resolve_layout`
+    raises `NotImplementedError` for it rather than let a user be the
+    first to run it.  Whether it is qualified on a multi-chip slice
+    or deleted is ROADMAP D5's decision.
 
 Selection: pass ``exchange_layout=`` to the samplers/loaders, or set
 ``GLT_EXCHANGE_LAYOUT`` (wins over the built-in ``'auto'`` rule, loses
@@ -127,10 +130,6 @@ HIER_MIN_PARTS = 4
 
 LAYOUTS = ('dense', 'compact', 'hier', 'ragged')
 
-#: import-time version gate for the ragged backend (jax >= 0.5-era on
-#: TPU).  jax 0.4.37 / CPU: False, and 'ragged' resolves to 'compact'.
-HAVE_RAGGED = hasattr(jax.lax, 'ragged_all_to_all')
-
 _ENV_LAYOUT = 'GLT_EXCHANGE_LAYOUT'
 
 
@@ -139,9 +138,9 @@ def resolve_layout(layout: Optional[str], num_parts: int) -> str:
 
   ``None``/``'auto'`` consults ``GLT_EXCHANGE_LAYOUT`` then the
   built-in rule (dense below `AUTO_COMPACT_MIN_PARTS`, compact at or
-  above).  ``'ragged'`` falls back to ``'compact'`` when this jax has
-  no `ragged_all_to_all` (the import-time gate); ``'hier'`` falls back
-  to ``'dense'`` when the mesh is too small to factor.
+  above).  ``'ragged'`` raises `NotImplementedError` (module
+  docstring); ``'hier'`` falls back to ``'dense'`` when the mesh is
+  too small to factor.
   """
   name = layout or 'auto'
   if name == 'auto':
@@ -153,8 +152,10 @@ def resolve_layout(layout: Optional[str], num_parts: int) -> str:
     raise ValueError(
         f'unknown exchange layout {name!r}; expected one of '
         f"{LAYOUTS + ('auto',)}")
-  if name == 'ragged' and not HAVE_RAGGED:
-    name = 'compact'
+  if name == 'ragged':
+    raise NotImplementedError(
+        "exchange layout 'ragged' has never executed on a device and "
+        'is not selectable (parallel.exchange docstring; ROADMAP D5)')
   if name == 'hier':
     if num_parts < HIER_MIN_PARTS:
       name = 'dense'
@@ -257,8 +258,6 @@ def capacity_spec(n: int, num_parts: int, slack: Optional[float],
       capacity=int(round_up(min(n, max(int(np.ceil(lam)),
                                        int(floor))), 8)))
   if name in ('compact', 'ragged'):
-    # ('ragged' resolved but unsupported specs never reach here: the
-    # resolve above already mapped it to 'compact' when gated)
     if name == 'ragged':
       budget = int(round_up(max(n, 1), 8))
       return ExchangeSpec('ragged', num_parts, capacity=budget,
@@ -657,11 +656,11 @@ class _HierPlan:
                      jnp.asarray(fill, out.dtype))
 
 
-class _RaggedPlan:  # pragma: no cover — needs jax.lax.ragged_all_to_all
+class _RaggedPlan:  # pragma: no cover — unreachable, see below
   """`jax.lax.ragged_all_to_all` backend: runtime per-destination send
-  sizes, no capacity waste.  Reachable only when `HAVE_RAGGED` (newer
-  JAX on TPU) — on jax 0.4.37/CPU `resolve_layout` already fell back
-  to 'compact', so this class is validated on real slices only.
+  sizes, no capacity waste.  UNREACHABLE today: `resolve_layout`
+  refuses ``'ragged'`` because this class has never executed on a
+  device (ROADMAP D5 qualifies or deletes it).
 
   KNOWN LIMIT (pre-hardware-validation): the receive buffer is a
   static 2x the send budget, but total arrivals at one device are
@@ -762,12 +761,12 @@ def plan_exchange(ids: jax.Array, owner_fn: Callable, num_parts: int,
   if spec.layout == 'hier':
     return _HierPlan(ids, owner_fn, num_parts, axis, spec,
                      payload=payload)
-  if spec.layout == 'ragged':  # pragma: no cover — gated, TPU-only
+  if spec.layout == 'ragged':  # pragma: no cover — see _RaggedPlan
     if payload is not None:
       # the ragged backend has no forward-payload support yet: paired
       # exchanges (edge-existence tests shipping (row, col)) degrade
       # to the exact pool-only compact plan instead of crashing the
-      # step trace — same spirit as the import-time gate
+      # step trace
       fb = ExchangeSpec('compact', num_parts, capacity=0,
                         pool=int(round_up(max(ids.shape[0], 1), 8)))
       return _CompactPlan(ids, owner_fn, num_parts, axis, fb,

@@ -38,21 +38,6 @@ import numpy as np
 from jax.sharding import Mesh
 
 
-def _is_initialized() -> bool:
-  """Has the cross-host runtime come up?  `jax.distributed.
-  is_initialized` only exists on newer jax; older releases expose the
-  client on the private global state — check both without touching
-  the XLA backend."""
-  probe = getattr(jax.distributed, 'is_initialized', None)
-  if probe is not None:
-    return bool(probe())
-  try:
-    from jax._src import distributed
-    return distributed.global_state.client is not None
-  except Exception:             # noqa: BLE001 — can't tell: assume no
-    return False
-
-
 def initialize(coordinator_address: Optional[str] = None,
                num_processes: Optional[int] = None,
                process_id: Optional[int] = None) -> None:
@@ -64,7 +49,7 @@ def initialize(coordinator_address: Optional[str] = None,
   # NOTE: nothing here may touch the XLA backend (jax.devices(),
   # jax.process_count(), ...) before initialize() — backend init makes
   # distributed init impossible, and that failure must stay LOUD.
-  if _is_initialized():
+  if jax.distributed.is_initialized():
     return
   try:
     jax.distributed.initialize(coordinator_address, num_processes,

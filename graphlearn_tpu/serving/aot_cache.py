@@ -1,8 +1,8 @@
 """Persistent AOT executable cache for the serving bucket ladder
 (ISSUE 13 tentpole, ROADMAP item 2b).
 
-Fused serve programs compile in 60–70 s (BENCH_r04) and the bucket
-ladder holds several of them — so the dominant cost of replacing a
+Fused serve programs take tens of seconds each to compile and the
+bucket ladder holds several of them — so the dominant cost of replacing a
 lost replica, or scaling one out, is not process start but the warmup
 recompile of executables that are BYTE-IDENTICAL to what every other
 replica already runs.  This cache persists each bucket's compiled

@@ -10,7 +10,7 @@ seed capacities), each served by ONE warm fused sample+gather(+model-
 forward) executable; a coalesced batch pads its tail with INVALID_ID
 up to the smallest bucket that fits.  `warmup` AOT-compiles every
 bucket at server start, and after it NOTHING recompiles across the
-whole envelope (pinned by the `_uncached_jit` per-callable compile
+whole envelope (pinned by the `_counted_jit` per-callable compile
 counters — the zero-recompile acceptance assertion).
 
 **Per-seed determinism (the coalescing contract).**  A batch-keyed
@@ -31,9 +31,11 @@ gathered ``x`` are byte-identical across EVERY bucket shape and any
 co-batched traffic.  Fused-forward ``logits`` are byte-identical
 within a bucket shape whatever the request rode with (each row's
 matmul reads only its own row), and agree across DIFFERENT bucket
-shapes only to float tolerance (~1e-6 — XLA retiles the matmul
-reduction per shape; no compiler grants cross-shape bitwise
-equality).  Per-request answers are therefore bitwise-reproducible
+shapes only to float tolerance (XLA retiles the matmul reduction per
+shape; no compiler grants cross-shape bitwise equality): ~1e-6 on the
+CPU, and on a TPU — where f32 matmuls run as bf16 passes by default —
+2.4e-3 absolute at hidden 256 on one v5e (`chip_smoke.py`, PR 21),
+the same size as the error against a float64 forward.  Per-request answers are therefore bitwise-reproducible
 given (engine seed, bucket shape) — retries and replicas agree —
 while cross-bucket logit identity is numerical, not bitwise.
 
@@ -58,7 +60,7 @@ import numpy as np
 
 from ..data.dataset import Dataset
 from ..data.feature import _device_gather
-from ..loader.fused import _uncached_jit
+from ..loader.fused import _counted_jit
 from ..loader.fused_tree import expand_tree_levels
 from ..data.cold_cache import pinned_cold_enabled
 from ..ops.pallas_gather import pallas_enabled
@@ -205,20 +207,12 @@ class ServingEngine:
     self._aot_restores = 0
     #: bucket capacity -> True once `warmup` compiled it
     self.warm = {cap: False for cap in self.buckets}
-    # every program is chunk-bounded by construction (one bucket =
-    # one static shape), so all opt into the persistent compile
-    # cache under GLT_FUSED_COMPILE_CACHE=1 — ROADMAP item 6's
-    # cold-start story rides the same seam as the fused epochs
-    self._compiled_collect = _uncached_jit(self._collect_fn,
-                                           cacheable=True)
-    self._compiled_gather = _uncached_jit(self._gather_fn,
-                                          static_argnums=(2,),
-                                          cacheable=True)
-    self._compiled_forward = _uncached_jit(self._forward_fn,
-                                           static_argnums=(3,),
-                                           cacheable=True)
-    self._compiled_consume = _uncached_jit(self._consume_fn,
-                                           cacheable=True)
+    self._compiled_collect = _counted_jit(self._collect_fn)
+    self._compiled_gather = _counted_jit(self._gather_fn,
+                                         static_argnums=(2,))
+    self._compiled_forward = _counted_jit(self._forward_fn,
+                                          static_argnums=(3,))
+    self._compiled_consume = _counted_jit(self._consume_fn)
 
   # -- static layout --------------------------------------------------------
   def _level_widths(self) -> Tuple[int, ...]:
@@ -319,14 +313,14 @@ class ServingEngine:
   def _run_prog(self, name: str, cap: int, jit_fn, dyn_args,
                 call_args, statics=()):
     """Dispatch one bucket program: the AOT-restored executable when
-    `warmup` installed one, else the `_uncached_jit` path.  A restored
+    `warmup` installed one, else the `_counted_jit` path.  A restored
     executable that fails AT CALL TIME (foreign device set, moved jax
     internals) is dropped and the dispatch falls back to the compile
     path — skip-to-recompile extends to runtime, not just load.
     ``statics`` are the CURRENT static-arg values: an AOT executable
     baked different ones at warmup (GLT_PALLAS toggled since) is
     bypassed for this call — env knobs keep their documented
-    dispatch-time semantics (`_uncached_jit`)."""
+    dispatch-time semantics (`_counted_jit`)."""
     entry = self._aot.get((name, cap))
     if entry is not None:
       fn, baked = entry
@@ -670,7 +664,7 @@ class ServingEngine:
 
   def compile_count(self) -> int:
     """Total compiles across the engine's programs (the
-    `_uncached_jit` per-callable counters, plus AOT lower+compiles
+    `_counted_jit` per-callable counters, plus AOT lower+compiles
     the persistent cache could not serve) — snapshot before traffic,
     compare after: a nonzero delta after `warmup` means a shape
     escaped the bucket ladder.  Zero after a warmup that restored

@@ -290,25 +290,25 @@ class StreamingGraph:
   def _merge_device(self, prev: GraphView, seg: DeltaSegment):
     """The r19 Pallas merge path: ``GLT_PALLAS_DELTA`` gates the
     rank-kernel merge (`ops.pallas_delta`), byte-identical to
-    `merge_delta_csr` by contract; any disqualifying shape or
-    lowering gap falls back to the host merge (``None`` return) with
-    a ``pallas.fallback`` event — the fault-free default path never
-    imports jax from here."""
+    `merge_delta_csr` by contract; a disqualifying shape
+    (`DeltaMergeUnsupported`, a documented rule) falls back to the
+    host merge (``None`` return) with a ``pallas.fallback`` event,
+    any other kernel failure raises — the fault-free default path
+    never imports jax from here."""
     import os
     if os.environ.get('GLT_PALLAS_DELTA', '').strip().lower() not in (
         '1', 'true', 'on', 'yes'):
       return None
     from ..telemetry.recorder import recorder
+    from ..ops.pallas_delta import (DeltaMergeUnsupported,
+                                    merge_delta_csr_device)
     try:
-      from ..ops.pallas_delta import merge_delta_csr_device
       merged = merge_delta_csr_device(
           prev.indptr, prev.indices, prev.edge_ids, seg)
-    except ValueError:
-      raise                        # contract errors surface as-is
-    except Exception as ex:
+    except DeltaMergeUnsupported as ex:
       if recorder.enabled:
         recorder.emit('pallas.fallback', kernel='delta_merge',
-                      reason=type(ex).__name__, events=seg.count)
+                      reason=str(ex), events=seg.count)
       return None
     if recorder.enabled:
       recorder.emit('pallas.dispatch', kernel='delta_merge',

@@ -10,7 +10,7 @@ three pieces:
     :data:`recorder`).  Samplers, loaders, channels and the fused
     epochs emit structured events into it: per-hop frontier sizes and
     padding-fill ratios, slack-cap drops and `AdaptiveSlack` ladder
-    transitions, compile-cache hits/misses with `_uncached_jit` compile
+    transitions, compile-cache hits/misses with `_counted_jit` compile
     seconds, channel ring occupancy/stalls, and cold-tier hit/miss from
     tiered feature stores.  Recording is OFF by default (`emit` is a
     single attribute check); enable with
@@ -25,8 +25,8 @@ three pieces:
     artifact sink: the full artifact JSON goes to ``BENCH_ARTIFACT.json``
     (``GLT_BENCH_ARTIFACT`` overrides) and stdout carries only a short
     summary line, so a driver that tails the last 2000 characters can
-    never truncate the artifact again (the `BENCH_r05.json`
-    ``"parsed": null`` failure mode).
+    never truncate the artifact (the ``"parsed": null`` failure
+    mode).
 
 On top of the recorder sits the CAUSAL layer (this PR's tentpole):
 

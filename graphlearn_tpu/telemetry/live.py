@@ -2,7 +2,7 @@
 
 Everything the offline telemetry plane already measures ticks the
 process-global `Metrics` counter store (`utils.profiling.metrics`):
-cold-tier hit/miss, exchange padding counters, `_uncached_jit` compile
+cold-tier hit/miss, exchange padding counters, `_counted_jit` compile
 hit/miss, RPC retries, span histograms as flat ``span.<kind>.hist.*``
 keys.  What was missing (ISSUE 12) is a *live surface* over that
 store: a declared vocabulary, typed metric handles, gauges evaluated

@@ -57,7 +57,7 @@ EVENT_KINDS: Dict[str, str] = {
         'data.cold_cache consumers: residents displaced by this '
         "overlay's admissions (CLOCK second-chance victims)",
     'fused.compile':
-        'loader.fused._uncached_jit: fn, secs, persistent_cache',
+        'loader.fused._counted_jit: fn, secs',
     'span.begin':
         'telemetry.spans: name, trace_id, span_id, parent_id, pid, '
         'tid (+caller fields)',
@@ -234,11 +234,12 @@ EVENT_KINDS: Dict[str, str] = {
         'which arms actually ran the kernel out of the same stream '
         'as its step timings',
     'pallas.fallback':
-        'r19 kernel gates (same three sites): kernel, reason '
-        '(unsupported-shape strings or trace-error:<ExcType>) + the '
-        'same per-kernel fields — the knob was ON but this call '
-        'fell back to the XLA/host path at byte parity; contract '
-        'errors (ValueError) re-raise instead of landing here',
+        'r19 kernel gates (sample + delta sites): kernel, reason '
+        '(the unsupported-shape string) + the same per-kernel '
+        'fields — the knob was ON but a documented shape rule sent '
+        'this call to the XLA/host twin at byte parity; a qualified '
+        'kernel that fails to trace, compile or run raises instead '
+        'of landing here',
 }
 
 
@@ -416,11 +417,12 @@ METRIC_NAMES: Dict[str, str] = {
         'counters — the live padding-waste number the scale '
         'envelope tracks offline',
     'fused.compile.hits':
-        'counter: _uncached_jit dispatches served by a warm '
+        'counter: _counted_jit dispatches served by a warm '
         'in-memory executable',
     'fused.compile.misses':
-        'counter: _uncached_jit dispatches that paid an XLA compile '
-        '(nonzero after warmup = a shape escaped bucketing)',
+        'counter: _counted_jit dispatches that added an executable '
+        '(XLA compile or persistent-cache load; nonzero after '
+        'warmup = a shape escaped bucketing)',
     'gns.bias_steps_total':
         'counter: compiled GNS-biased sampler steps built '
         '(node + link modes)',

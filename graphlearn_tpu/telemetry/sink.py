@@ -1,8 +1,8 @@
 """File-based bench artifact sink.
 
-Round 5's evidence chain broke at the last hop: the aggregate JSON on
-stdout outgrew the driver's 2000-char tail and `BENCH_r05.json` shipped
-``"parsed": null``.  The permanent fix is structural: the FULL artifact
+An aggregate JSON printed on stdout outgrows a driver's 2000-char tail
+(it once shipped as ``"parsed": null``).  The fix is structural: the
+FULL artifact
 goes to a file (:func:`write_artifact`, atomic tmp+rename) and stdout
 carries only a short summary line (:func:`summary_line`) that is
 guaranteed to fit the tail — it degrades by dropping optional keys, and

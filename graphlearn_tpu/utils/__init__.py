@@ -1,7 +1,5 @@
 from .common import (format_hetero_sampler_output,
                      merge_hetero_sampler_output)
-from .device import (assign_device, ensure_device, get_available_devices,
-                     is_tpu_available)
 from .mixin import CastMixin
 from .padding import (INVALID_ID, bucket_size, max_sampled_edges,
                       max_sampled_nodes, next_power_of_two, pad_1d, round_up)

@@ -3,10 +3,10 @@
 The reference builds a torch cpp_extension wheel (`setup.py:26-74`
 there); here the native layer is a plain shared library (ctypes-bound,
 no torch/pybind11 dependency) built by `csrc/Makefile` and shipped as
-package data.  `pip install .` compiles it when a toolchain exists and
-falls back to the checked-in binary otherwise (the Python layer also
-degrades gracefully at runtime when the library is missing — device
-paths never need it).
+package data.  `pip install .` compiles it when a toolchain exists;
+without one the package installs without the library, and
+`graphlearn_tpu.native` builds it from `csrc/` on first use (device
+paths never need it).  No binary is checked in.
 """
 import subprocess
 from pathlib import Path
@@ -22,7 +22,7 @@ class BuildWithNative(build_py):
       subprocess.run(['make', '-C', str(root / 'csrc')], check=True)
     except (OSError, subprocess.CalledProcessError) as e:
       print(f'[graphlearn-tpu] native build skipped ({e}); '
-            'using the bundled libglt_native.so if present')
+            'graphlearn_tpu.native will build on first use')
     super().run()
 
 

@@ -4,7 +4,7 @@ stale entries skip to recompile (never a crash or a wrong
 executable), write faults are absorbed, publishes are atomic.
 
 A "second process" is simulated by a FRESH `ServingEngine` over the
-same cache dir: every engine builds fresh `_uncached_jit` wrappers
+same cache dir: every engine builds fresh `_counted_jit` wrappers
 (empty in-memory executable caches), so a zero compile-count warmup
 can only come from the disk restore.
 """

@@ -1,4 +1,4 @@
-"""The bench artifact contract, pinned (VERDICT r3 #1).
+"""The bench artifact contract, pinned.
 
 Round 3 shipped rc=124 with NO perf number because the aggregate JSON
 printed only once, at the very end.  The contract since r4: the FULL

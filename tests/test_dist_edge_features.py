@@ -1,6 +1,6 @@
 """Distributed edge-feature collection — both engines + offline layout.
 
-VERDICT-r1 missing #1: the reference serves edge features through the
+The reference serves edge features through the
 same distributed fan-out as node features
 (`distributed/dist_feature.py:39-48,122-269`, collation at
 `dist_neighbor_sampler.py:600-673`, separate ``edge_feat_pb`` at

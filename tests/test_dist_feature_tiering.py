@@ -1,5 +1,5 @@
 """Tiered distributed feature store: HBM hot shards + host-DRAM cold
-tier (VERDICT r2 item 1).
+tier.
 
 The scale claim under test: the mesh engine must serve feature tables
 LARGER than the per-device HBM shard budget.  On the virtual CPU mesh

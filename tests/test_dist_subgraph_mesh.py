@@ -1,6 +1,6 @@
 """Mesh-engine induced subgraph (SEAL on the ICI path).
 
-VERDICT-r1 missing #2: the reference samples induced subgraphs ACROSS
+The reference samples induced subgraphs ACROSS
 partitions (`distributed/dist_neighbor_sampler.py:456-516`); round 1
 only had the host-runtime arm.  The mesh step = collective closure +
 full-window hop + local membership/relabel; exactness is asserted

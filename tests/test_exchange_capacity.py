@@ -1,6 +1,6 @@
 """Capacity-bounded exchange: defaults, telemetry, and sampling bias.
 
-The VERDICT-r1 "#1 scaling risk" items: `exchange_slack` must be a
+The scaling-risk items: `exchange_slack` must be a
 defaulted, *measured* mechanism — shuffled loaders cap send buffers at
 2x the balanced share, overflow drops are counted (never invisible),
 and sampling statistics stay unbiased under the default cap.

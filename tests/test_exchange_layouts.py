@@ -8,7 +8,7 @@ engines rely on —
     the unbucketed reference for a deterministic reply function;
   * layout equivalence: dense / compacted / hierarchical deliver
     identical valid ids and masks for deterministic gathers;
-  * the ragged backend import-gates cleanly on jax 0.4.37.
+  * the never-executed ragged backend is not selectable.
 
 P in {2, 8} runs on the real 8-device test mesh; P in {16, 64} uses
 the host-simulated bucketing twin (`simulate_assignment`), which
@@ -22,7 +22,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from graphlearn_tpu.parallel.exchange import (
-    AUTO_COMPACT_MIN_PARTS, ExchangeSpec, HAVE_RAGGED, capacity_spec,
+    AUTO_COMPACT_MIN_PARTS, ExchangeSpec, capacity_spec,
     mesh_factors, plan_exchange, resolve_layout, simulate_assignment)
 from graphlearn_tpu.parallel.shard_map_compat import shard_map
 
@@ -204,18 +204,13 @@ def test_auto_and_env_resolution(monkeypatch):
     resolve_layout('mystery', 8)
 
 
-def test_ragged_import_gates_cleanly():
-  """jax 0.4.37 has no ragged_all_to_all: the gate must be closed and
-  'ragged' must fall back to the compacted dense layout rather than
-  crash at plan time."""
-  assert HAVE_RAGGED == hasattr(jax.lax, 'ragged_all_to_all')
-  resolved = resolve_layout('ragged', 16)
-  if not HAVE_RAGGED:
-    assert resolved == 'compact'
-    spec = capacity_spec(128, 16, 1.5, layout='ragged')
-    assert spec.layout == 'compact'
-  else:  # pragma: no cover — newer jax
-    assert resolved == 'ragged'
+def test_ragged_is_not_selectable():
+  """'ragged' has never executed on a device: naming it must raise,
+  not hand a user an unexercised plan (or silently another layout)."""
+  with pytest.raises(NotImplementedError):
+    resolve_layout('ragged', 16)
+  with pytest.raises(NotImplementedError):
+    capacity_spec(128, 16, 1.5, layout='ragged')
 
 
 def test_mesh_factors():

@@ -78,7 +78,7 @@ def test_fused_dist_epoch_trains():
   st = fused.sampler.exchange_stats(tick_metrics=False)
   assert st['dist.frontier.offered'] > 0
   # evaluate(): one SPMD scan program, same graph as the train split's
-  # accuracy (VERDICT r4 #5 — dist fused eval without leaving the
+  # accuracy (dist fused eval without leaving the
   # fused path).  Params are replicated; pass the replicated leaf tree.
   acc = fused.evaluate(state.params, np.arange(N))
   assert acc > 0.6
@@ -152,7 +152,7 @@ def test_fused_dist_link_epoch_trains():
   st = fused.sampler.exchange_stats(tick_metrics=False)
   assert st['dist.frontier.offered'] > 0
   # evaluate(): held-out link AUC as one SPMD scan program — trained
-  # positives must rank above fresh strict negatives (VERDICT r4 #5)
+  # positives must rank above fresh strict negatives
   auc = fused.evaluate(state.params, (rows[:128], cols[:128]))
   assert 0.6 < auc <= 1.0
 

@@ -373,14 +373,3 @@ def test_fused_link_evaluate_auc():
                        seed=0)
   with pytest.raises(ValueError, match='binary'):
     tri.evaluate(state.params, eval_edges)
-
-
-def test_fresh_compile_internals_present():
-  """`loader.fused._fresh_compile` leans on jax._src internals that
-  have no stability guarantee; this pin makes a jax upgrade that
-  moves them FAIL here instead of silently degrading the cache
-  bypass to its process-wide fallback (ADVICE r4)."""
-  from jax._src import compilation_cache as cc
-  from jax._src import config as cfg
-  assert callable(cc.reset_cache)
-  assert hasattr(cfg, 'enable_compilation_cache')

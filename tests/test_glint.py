@@ -49,7 +49,7 @@ def test_unknown_rule_is_an_error():
 HOT_SYNC_BAD = _src('''
     import jax
     import numpy as np
-    from graphlearn_tpu.loader.fused import _uncached_jit
+    from graphlearn_tpu.loader.fused import _counted_jit
 
     def _epoch_fn(state, seeds):
       def body(carry, s):
@@ -59,7 +59,7 @@ HOT_SYNC_BAD = _src('''
       np.asarray(out)                        # sync inside jitted fn
       return out
 
-    compiled = _uncached_jit(_epoch_fn)
+    compiled = _counted_jit(_epoch_fn)
 ''')
 
 HOT_SYNC_TRANSITIVE = _src('''
@@ -78,14 +78,14 @@ HOT_SYNC_OK = _src('''
     import jax
     import jax.numpy as jnp
     import numpy as np
-    from graphlearn_tpu.loader.fused import _uncached_jit
+    from graphlearn_tpu.loader.fused import _counted_jit
 
     def _epoch_fn(state, seeds):
       def body(carry, s):
         return carry + jnp.sum(s), s
       return jax.lax.scan(body, state, seeds)
 
-    compiled = _uncached_jit(_epoch_fn)
+    compiled = _counted_jit(_epoch_fn)
 
     def host_driver(batch):
       # host-side code may sync freely — it is not in the hot set

@@ -1,5 +1,5 @@
 """Cross-server host runtime: 2 partition services on localhost, real
-socket RPC, partition-encoded provenance (VERDICT r2 item 2).
+socket RPC, partition-encoded provenance.
 
 The SURVEY §4 pattern: a deterministic synthetic 2-partition dataset
 whose features encode node ids, every role a local process/thread, no

@@ -97,7 +97,7 @@ def _write_rich(root, split_feats: bool = True):
 
 
 def test_host_local_tiered_equals_full(tmp_path):
-  """Tiered host-local load (the IGBH-large enabler, VERDICT r3 #3):
+  """Tiered host-local load (the IGBH-large enabler):
   hot shards, hot counts, cache plan, and edge features must all
   match a single-controller load of the same (layout, split_ratio);
   the cold stack must hold exactly this host's partitions' rows of

@@ -1,4 +1,4 @@
-"""Distribution tests at hub degrees (VERDICT r3 weak #7).
+"""Distribution tests at hub degrees.
 
 `ops/neighbor.py::sample_one_hop` has three degree regimes:
 ``deg <= k`` takes every neighbor; ``k < deg <= W`` samples EXACTLY

@@ -1,4 +1,4 @@
-"""OGB on-disk layout ingestion (VERDICT r2 item 5): raw CSV + binary
+"""OGB on-disk layout ingestion: raw CSV + binary
 layouts round-trip into Dataset / partition layout; the accuracy
 harness' ingestion path learns on a synthetic OGB-layout dataset.
 Real ogbn-products accuracy asserts in `examples/acc_ogbn_products.py`

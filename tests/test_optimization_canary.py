@@ -4,7 +4,7 @@
 whole suite (compile-wall economics), which means every parity test
 normally runs a different pass pipeline than production — a fusion
 bug that changes masked-reduction numerics would be invisible
-(ADVICE r4).  This canary re-executes one fused-epoch parity test and
+.  This canary re-executes one fused-epoch parity test and
 one device-native loader parity test in a SUBPROCESS with
 ``GLT_TEST_NO_FAST_XLA=1``, i.e. with the full optimization pipeline
 on, so at least one representative of each family runs production

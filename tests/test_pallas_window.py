@@ -1,4 +1,4 @@
-"""CSR window-gather experiment kernel (VERDICT r2 item 6): the
+"""CSR window-gather experiment kernel: the
 aligned-overfetch DMA path must agree with the XLA window gather
 (interpret mode on the CPU mesh; the real-chip measurement lives in
 benchmarks/bench_pallas_window.py and the pallas_gather module notes).

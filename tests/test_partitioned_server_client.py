@@ -1,6 +1,6 @@
 """Partitioned server-client deployment: every sampling server owns ONE
 shard, producers fan each hop/feature lookup out to peer servers over
-RPC (VERDICT r2 item 2, full-stack arm).
+RPC.
 
 All roles are local processes (SURVEY §4: real RPC + shm + producer
 subprocesses, no mocks): 2 shard servers x 1 producer worker each, one

@@ -1,6 +1,6 @@
 """RDMA feature-exchange prototype vs the all_to_all reference path.
 
-Interpret-mode validation on the virtual CPU mesh (VERDICT-r1 next-7):
+Interpret-mode validation on the virtual CPU mesh:
 the per-row remote-DMA gather must return exactly what
 `dist_gather` returns for the same sharded table and id sets —
 including invalid ids and capacity-dropped slots.

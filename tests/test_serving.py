@@ -161,7 +161,7 @@ def test_tiered_matches_hot(engine):
 def test_warmup_zero_recompiles(engine):
   """THE serving acceptance pin: after warmup, the whole traffic
   envelope (every request size up to the top bucket, both arms) hits
-  warm executables — the `_uncached_jit` per-callable compile
+  warm executables — the `_counted_jit` per-callable compile
   counters must not move."""
   assert all(engine.warm.values())
   before = engine.compile_count()
@@ -174,19 +174,19 @@ def test_warmup_zero_recompiles(engine):
 
 
 def test_driver_compile_count_counters():
-  """The `_uncached_jit` per-callable counters behind the pin: a
+  """The `_counted_jit` per-callable counters behind the pin: a
   compile ticks, a warm executable hit does not, a new shape ticks
   again — and `driver_compile_count` sums them duck-typed (the same
   helper the mesh epoch drivers expose as `compile_count()`)."""
   import jax.numpy as jnp
-  from graphlearn_tpu.loader.fused import (_uncached_jit,
+  from graphlearn_tpu.loader.fused import (_counted_jit,
                                            driver_compile_count)
 
   class _D:
     pass
 
   d = _D()
-  d._compiled = _uncached_jit(lambda x: x * 2)
+  d._compiled = _counted_jit(lambda x: x * 2)
   d._compiled(jnp.ones((2,)))
   assert (d._compiled.calls, d._compiled.compiles) == (1, 1)
   d._compiled(jnp.ones((2,)))

@@ -435,24 +435,13 @@ def recorderless_events(path, kind):
 
 # -- compile-cache dispatch telemetry (satellite) ---------------------------
 
-def test_uncached_jit_dispatch_time_env(monkeypatch):
-  from graphlearn_tpu.loader.fused import _uncached_jit
-  calls = {'n': 0}
-
-  def f(x):
-    calls['n'] += 1
-    return x + 1
+def test_counted_jit_ticks_compile_metrics():
+  from graphlearn_tpu.loader.fused import _counted_jit
 
   base = metrics.snapshot()
-  wrapped = _uncached_jit(f, cacheable=True)
-  monkeypatch.delenv('GLT_FUSED_COMPILE_CACHE', raising=False)
+  wrapped = _counted_jit(lambda x: x + 1)
   out = wrapped(jnp.zeros((4,)))
   assert float(out.sum()) == 4.0
-  # env flipped AFTER construction must take effect (dispatch-time
-  # read): the cached path still executes correctly
-  monkeypatch.setenv('GLT_FUSED_COMPILE_CACHE', '1')
-  out = wrapped(jnp.ones((4,)))
-  assert float(out.sum()) == 8.0
   snap = metrics.snapshot()
   assert snap.get('fused.compile.misses', 0) > base.get(
       'fused.compile.misses', 0)
@@ -463,37 +452,19 @@ def test_uncached_jit_dispatch_time_env(monkeypatch):
   assert wrapped.jitted is not None
 
 
-def test_uncached_jit_not_cacheable_ignores_env(monkeypatch):
-  """Full-length programs must NEVER take the persistent-cache path,
-  even with the env var set (the r3 watchdog crash class)."""
-  from graphlearn_tpu.loader import fused as fused_mod
-  seen = []
-  orig = fused_mod._fresh_compile
-  monkeypatch.setattr(fused_mod, '_fresh_compile',
-                      lambda: (seen.append(1), orig())[1])
-  monkeypatch.setenv('GLT_FUSED_COMPILE_CACHE', '1')
-  wrapped = fused_mod._uncached_jit(lambda x: x * 2, cacheable=False)
-  wrapped(jnp.ones((2,)))
-  assert seen, 'cacheable=False must still route through _fresh_compile'
-  seen.clear()
-  cached = fused_mod._uncached_jit(lambda x: x * 3, cacheable=True)
-  cached(jnp.ones((2,)))
-  assert not seen, 'cacheable=True + env=1 must skip _fresh_compile'
-
-
 def test_fused_compile_event_emitted(tmp_path):
-  from graphlearn_tpu.loader.fused import _uncached_jit
+  from graphlearn_tpu.loader.fused import _counted_jit
   p = str(tmp_path / 'f.jsonl')
   recorder.enable(p)
   try:
-    wrapped = _uncached_jit(lambda x: x - 1)
+    wrapped = _counted_jit(lambda x: x - 1)
     wrapped(jnp.ones((3,)))
   finally:
     recorder.disable()
   evs = [json.loads(ln) for ln in open(p).read().splitlines()]
   comp = [e for e in evs if e['kind'] == 'fused.compile']
   assert comp and comp[0]['secs'] >= 0
-  assert comp[0]['persistent_cache'] is False
+  assert 'persistent_cache' not in comp[0]
 
 
 # -- channel stall telemetry ------------------------------------------------

@@ -11,7 +11,7 @@ PyTorch-Direct lineage in PAPERS.md depends on the same never-sync
 contract in its overlapped window).
 
 Hot scope = the transitive closure, within one file, of:
-  * functions handed to ``_uncached_jit(...)`` / ``jax.jit(...)``
+  * functions handed to ``_counted_jit(...)`` / ``jax.jit(...)``
     (by local name or ``self.<method>`` reference);
   * ``jax.lax.scan`` body callables (named or lambda);
   * functions decorated ``@jax.jit`` (bare or ``partial(jax.jit,..)``);
@@ -35,7 +35,7 @@ from ..registry import GlintPass, register
 
 #: calls that make a jitted/scanned function hot, keyed by the
 #: terminal segment of the callee's qualname
-_JIT_WRAPPERS = {'_uncached_jit', 'jit'}
+_JIT_WRAPPERS = {'_counted_jit', 'jit'}
 #: control-flow primitives -> positional indices of their traced
 #: callables (scan(body, ...); while_loop(cond, body, ...);
 #: fori_loop(lo, hi, body, ...))
@@ -87,7 +87,7 @@ class HostSyncPass(GlintPass):
         qn = ctx.qualname(node.func)
         term = _terminal(node.func)
         if (term in _JIT_WRAPPERS
-            and (term == '_uncached_jit' or qn in ('jax.jit', 'jit'))
+            and (term == '_counted_jit' or qn in ('jax.jit', 'jit'))
             and node.args):
           arg = node.args[0]
           if isinstance(arg, ast.Lambda):
