@@ -30,6 +30,7 @@ import numpy as np
 
 from ..ops.pallas_gather import gather_rows, pallas_enabled
 from ..utils.padding import next_power_of_two
+from ..utils.profiling import layer_scope
 from ..utils.tensor import convert_to_array
 
 
@@ -38,17 +39,18 @@ def _device_gather(hot: jax.Array, ids: jax.Array, id2index, *,
                    use_pallas: bool) -> jax.Array:
   # `use_pallas` is part of the jit cache key so the GLT_PALLAS
   # kill-switch keeps working mid-process (resolved per call outside).
-  valid = ids >= 0
-  idx = jnp.where(valid, ids, 0).astype(jnp.int32)
-  if id2index is not None:
-    idx = id2index[idx].astype(jnp.int32)
-    valid = valid & (idx >= 0)
-    idx = jnp.where(valid, idx, 0)
-  if use_pallas:
-    out = gather_rows(hot, idx)
-  else:
-    out = jnp.take(hot, idx, axis=0)
-  return jnp.where(valid[:, None], out, 0)
+  with layer_scope('gather'):
+    valid = ids >= 0
+    idx = jnp.where(valid, ids, 0).astype(jnp.int32)
+    if id2index is not None:
+      idx = id2index[idx].astype(jnp.int32)
+      valid = valid & (idx >= 0)
+      idx = jnp.where(valid, idx, 0)
+    if use_pallas:
+      out = gather_rows(hot, idx)
+    else:
+      out = jnp.take(hot, idx, axis=0)
+    return jnp.where(valid[:, None], out, 0)
 
 
 class _DeviceFeatsShim:

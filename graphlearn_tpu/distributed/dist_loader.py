@@ -20,7 +20,7 @@ from ..loader.transform import Batch, HeteroBatch
 from ..typing import as_str, reverse_edge_type
 from ..utils.padding import (INVALID_ID, max_sampled_nodes,
                              next_power_of_two, round_up)
-from ..utils.profiling import metrics, trace
+from ..utils.profiling import metrics
 from .dist_options import (CollocatedDistSamplingWorkerOptions,
                            HostSamplingConfig,
                            MpDistSamplingWorkerOptions,
@@ -235,8 +235,7 @@ class DistLoader:
       raise StopIteration
     with spans.span('batch', scope=type(self).__name__):
       with spans.span('recv'):
-        with trace('dist_loader.recv'):
-          msg = self._recv_current_epoch()
+        msg = self._recv_current_epoch()
       self._received += 1
       return self._collate_batch(msg)
 
@@ -251,8 +250,7 @@ class DistLoader:
     link = spans.link_fields(getattr(self.channel,
                                      'last_span_context', None))
     with spans.span('collate', **link):
-      with trace('dist_loader.collate'):
-        batch = self._collate_fn(msg)
+      batch = self._collate_fn(msg)
     metrics.inc('dist_loader.batches')
     return batch
 

@@ -432,10 +432,11 @@ class _SupervisedScanEpoch(_SnapshotHooks):
     single-program epoch, different stream."""
     from ..telemetry.spans import span
     from ..testing import chaos
-    seeds = np.stack(list(self._batcher))          # [S, B], host shuffle
-    self._epoch_idx += 1
-    key = jax.random.fold_in(self._base_key, self._epoch_idx)
-    parts = list(self._chunks(seeds))
+    with span('fused.seeds'):
+      seeds = np.stack(list(self._batcher))        # [S, B], host shuffle
+      parts = list(self._chunks(seeds))
+      self._epoch_idx += 1
+      key = jax.random.fold_in(self._base_key, self._epoch_idx)
     chunk_steps = parts[0][2].shape[0] if parts else 0
     # mid-epoch resume (attach_snapshots/restore_from_snapshot):
     # chunks before `skip` already ran pre-preemption — their stats

@@ -45,6 +45,7 @@ from ..data.feature import _device_gather
 from ..models.train import TrainState
 from ..ops.pallas_gather import pallas_enabled
 from ..ops.pallas_sample import sample_one_hop_auto
+from ..utils.profiling import layer_scope
 from .fused import _SupervisedScanEpoch, _counted_jit
 from .node_loader import SeedBatcher
 from .transform import _gather_labels
@@ -66,10 +67,11 @@ def expand_tree_levels(indptr, indices, seeds, key, fanouts, *,
     # `sample_one_hop_auto` re-reads GLT_PALLAS_SAMPLE at trace time;
     # the epoch drivers compile once per config so the choice is baked
     # per program (value-identical either way)
-    res = sample_one_hop_auto(indptr, indices, frontier, k,
-                              jax.random.fold_in(key, i),
-                              sort_locality=sort_locality)
-    nxt = jnp.where(res.mask, res.nbrs, -1).reshape(-1)
+    with layer_scope('sample', f'hop{i}'):
+      res = sample_one_hop_auto(indptr, indices, frontier, k,
+                                jax.random.fold_in(key, i),
+                                sort_locality=sort_locality)
+      nxt = jnp.where(res.mask, res.nbrs, -1).reshape(-1)
     levels.append(nxt)
     masks.append(nxt >= 0)
     frontier = nxt
@@ -182,9 +184,13 @@ class FusedTreeEpoch(_SupervisedScanEpoch):
     levels, masks = expand_tree_levels(dev['indptr'], dev['indices'],
                                        seeds, key, self.fanouts,
                                        sort_locality=False)
-    xs = [_device_gather(dev['hot'], lvl, dev['id2index'],
-                         use_pallas=use_pallas) for lvl in levels]
-    y = _gather_labels(dev['labels'], seeds)
+    xs = []
+    for t, lvl in enumerate(levels):
+      with layer_scope('gather', f'level{t}'):
+        xs.append(_device_gather(dev['hot'], lvl, dev['id2index'],
+                                 use_pallas=use_pallas))
+    with layer_scope('gather', 'labels'):
+      y = _gather_labels(dev['labels'], seeds)
     return xs, masks, y
 
   # -- the one program ------------------------------------------------------
@@ -195,33 +201,38 @@ class FusedTreeEpoch(_SupervisedScanEpoch):
 
     def body(state, xs_in):
       i, seeds = xs_in
-      xs, masks, y = self._expand(seeds, jax.random.fold_in(key, i),
-                                  dev, use_pallas)
+      with layer_scope('sample', 'key'):
+        step_key = jax.random.fold_in(key, i)
+      xs, masks, y = self._expand(seeds, step_key, dev, use_pallas)
 
       def loss_fn(params):
         logits = self._apply(params, xs, masks)
-        valid = (seeds >= 0).astype(logits.dtype)
-        ce = optax.softmax_cross_entropy_with_integer_labels(
-            logits, y.astype(jnp.int32))
-        return (ce * valid).sum() / jnp.maximum(valid.sum(), 1.0), \
-            logits
+        with layer_scope('model', 'loss'):
+          valid = (seeds >= 0).astype(logits.dtype)
+          ce = optax.softmax_cross_entropy_with_integer_labels(
+              logits, y.astype(jnp.int32))
+          return (ce * valid).sum() / jnp.maximum(valid.sum(), 1.0), \
+              logits
 
       (loss, logits), grads = jax.value_and_grad(
           loss_fn, has_aux=True)(state.params)
-      updates, opt_state = self.tx.update(grads, state.opt_state,
-                                          state.params)
-      params = optax.apply_updates(state.params, updates)
-      new_state = TrainState(params, opt_state, state.step + 1)
-      # fully-padded steps (epoch-length chunking) must be no-ops:
-      # zero grads still move adam's moments/bias correction
-      any_valid = jnp.any(seeds >= 0)
-      state = jax.tree_util.tree_map(
-          lambda new, old: jnp.where(any_valid, new, old),
-          new_state, state)
-      valid = seeds >= 0
-      correct = jnp.sum(
-          (jnp.argmax(logits, axis=-1) == y) & valid)
-      return state, (loss, correct, jnp.sum(valid))
+      with layer_scope('optimizer'):
+        updates, opt_state = self.tx.update(grads, state.opt_state,
+                                            state.params)
+        params = optax.apply_updates(state.params, updates)
+        new_state = TrainState(params, opt_state, state.step + 1)
+        # fully-padded steps (epoch-length chunking) must be no-ops:
+        # zero grads still move adam's moments/bias correction
+        any_valid = jnp.any(seeds >= 0)
+        state = jax.tree_util.tree_map(
+            lambda new, old: jnp.where(any_valid, new, old),
+            new_state, state)
+      with layer_scope('model', 'metrics'):
+        valid = seeds >= 0
+        correct = jnp.sum(
+            (jnp.argmax(logits, axis=-1) == y) & valid)
+        n_valid = jnp.sum(valid)
+      return state, (loss, correct, n_valid)
 
     steps = jnp.arange(seeds_all.shape[0], dtype=jnp.int32)
     state, (losses, corrects, valids) = jax.lax.scan(
@@ -232,12 +243,15 @@ class FusedTreeEpoch(_SupervisedScanEpoch):
                dev: dict, use_pallas: bool):
     def body(carry, xs_in):
       i, seeds = xs_in
-      xs, masks, y = self._expand(seeds, jax.random.fold_in(key, i),
-                                  dev, use_pallas)
+      with layer_scope('sample', 'key'):
+        step_key = jax.random.fold_in(key, i)
+      xs, masks, y = self._expand(seeds, step_key, dev, use_pallas)
       logits = self._eval_apply(params, xs, masks)
-      valid = seeds >= 0
-      correct = jnp.sum((jnp.argmax(logits, axis=-1) == y) & valid)
-      return carry, (correct, jnp.sum(valid))
+      with layer_scope('model', 'metrics'):
+        valid = seeds >= 0
+        correct = jnp.sum((jnp.argmax(logits, axis=-1) == y) & valid)
+        n_valid = jnp.sum(valid)
+      return carry, (correct, n_valid)
 
     steps = jnp.arange(seeds_all.shape[0], dtype=jnp.int32)
     _, (correct, total) = jax.lax.scan(body, 0, (steps, seeds_all))

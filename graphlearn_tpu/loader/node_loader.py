@@ -16,7 +16,8 @@ import numpy as np
 from ..data.dataset import Dataset
 from ..sampler.base import BaseSampler, NodeSamplerInput
 from ..utils.padding import INVALID_ID, pad_1d
-from ..utils.profiling import metrics, trace
+from ..telemetry.spans import span
+from ..utils.profiling import metrics
 from .prefetch import PrefetchingLoader
 from .transform import Batch, collate
 
@@ -145,10 +146,10 @@ class NodeLoader(PrefetchingLoader):
 
   def _produce(self, seed_iter) -> Batch:
     seeds = next(seed_iter)
-    with trace('loader.sample'):
+    with span('loader.sample'):
       out = self.sampler.sample_from_nodes(
           NodeSamplerInput(node=seeds, input_type=self.input_type))
-    with trace('loader.collate'):
+    with span('loader.collate'):
       batch = self._collate_fn(out)
     metrics.inc('loader.batches')
     metrics.inc('loader.seeds', int((seeds >= 0).sum()))

@@ -16,6 +16,8 @@ import jax.numpy as jnp
 
 from ..typing import EdgeType, NodeType
 from ..sampler.base import HeteroSamplerOutput, SamplerOutput
+from ..telemetry.spans import span
+from ..utils.profiling import layer_scope
 
 
 def _contains_array(v) -> bool:
@@ -149,11 +151,12 @@ jax.tree_util.register_pytree_node(
 
 @jax.jit
 def _gather_labels(labels: jax.Array, ids: jax.Array) -> jax.Array:
-  valid = ids >= 0
-  idx = jnp.where(valid, ids, 0)
-  out = labels[idx]
-  mask = valid.reshape(valid.shape + (1,) * (out.ndim - 1))
-  return jnp.where(mask, out, 0)
+  with layer_scope('gather', 'labels'):
+    valid = ids >= 0
+    idx = jnp.where(valid, ids, 0)
+    out = labels[idx]
+    mask = valid.reshape(valid.shape + (1,) * (out.ndim - 1))
+    return jnp.where(mask, out, 0)
 
 
 def to_data(
@@ -168,7 +171,10 @@ def to_data(
   feature/label tensors are indexed by the sampled global node ids;
   metadata (link labels) is forwarded.
   """
-  x = node_feature[out.node] if node_feature is not None else None
+  x = None
+  if node_feature is not None:
+    with span('feature.get'):
+      x = node_feature[out.node]
   y = None
   if node_label is not None:
     if isinstance(node_label, jax.Array) and isinstance(out.node,

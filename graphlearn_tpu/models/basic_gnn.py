@@ -14,6 +14,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from ..utils.profiling import layer_scope
 from .conv import GINConv, GATConv, GCNConv, SAGEConv
 
 
@@ -38,18 +39,19 @@ class BasicGNN(nn.Module):
       last = i == self.num_layers - 1
       out = self.out_features if last else self.hidden_features
       conv = self.make_conv(out, i)
-      if edge_weight is not None:
-        # GNS 1/q importance weights (Batch.metadata['edge_weight']):
-        # only convs that define an unbiased weighted aggregation
-        # accept them (SAGEConv) — passing to others raises loudly
-        # rather than silently dropping the correction
-        x = conv(x, edge_index, edge_mask, edge_weight=edge_weight)
-      else:
-        x = conv(x, edge_index, edge_mask)
-      if not last:
-        x = nn.relu(x)
-        if self.dropout > 0:
-          x = nn.Dropout(self.dropout, deterministic=not train)(x)
+      with layer_scope('model', f'layer{i}'):
+        if edge_weight is not None:
+          # GNS 1/q importance weights (Batch.metadata['edge_weight']):
+          # only convs that define an unbiased weighted aggregation
+          # accept them (SAGEConv) — passing to others raises loudly
+          # rather than silently dropping the correction
+          x = conv(x, edge_index, edge_mask, edge_weight=edge_weight)
+        else:
+          x = conv(x, edge_index, edge_mask)
+        if not last:
+          x = nn.relu(x)
+          if self.dropout > 0:
+            x = nn.Dropout(self.dropout, deterministic=not train)(x)
     return x.astype(jnp.float32) if self.dtype is not None else x
 
 

@@ -16,6 +16,8 @@ import jax
 import jax.numpy as jnp
 import optax
 
+from ..utils.profiling import layer_scope
+
 
 class TrainState(NamedTuple):
   params: Any
@@ -35,12 +37,13 @@ def create_train_state(model, rng, sample_batch, tx: optax.GradientTransformatio
 def supervised_loss(logits: jax.Array, y: jax.Array, batch_seeds: jax.Array,
                     batch_size: int) -> jax.Array:
   """Masked softmax CE over seed slots [0, batch_size)."""
-  seed_logits = logits[:batch_size]
-  seed_y = y[:batch_size]
-  valid = (batch_seeds >= 0).astype(seed_logits.dtype)
-  ce = optax.softmax_cross_entropy_with_integer_labels(
-      seed_logits, seed_y.astype(jnp.int32))
-  return (ce * valid).sum() / jnp.maximum(valid.sum(), 1.0)
+  with layer_scope('model', 'loss'):
+    seed_logits = logits[:batch_size]
+    seed_y = y[:batch_size]
+    valid = (batch_seeds >= 0).astype(seed_logits.dtype)
+    ce = optax.softmax_cross_entropy_with_integer_labels(
+        seed_logits, seed_y.astype(jnp.int32))
+    return (ce * valid).sum() / jnp.maximum(valid.sum(), 1.0)
 
 
 def make_extracted_supervised_step(extract: Callable,
@@ -52,7 +55,7 @@ def make_extracted_supervised_step(extract: Callable,
   count) shared by the homogeneous and hetero step builders and the
   fused epoch runners."""
 
-  def step(state: TrainState, batch):
+  def supervised_step(state: TrainState, batch):
     def loss_fn(params):
       logits, y, seeds = extract(params, batch)
       loss = supervised_loss(logits, y, seeds, batch_size)
@@ -60,14 +63,17 @@ def make_extracted_supervised_step(extract: Callable,
 
     (loss, (logits, y, seeds)), grads = jax.value_and_grad(
         loss_fn, has_aux=True)(state.params)
-    updates, opt_state = tx.update(grads, state.opt_state, state.params)
-    params = optax.apply_updates(state.params, updates)
-    valid = seeds >= 0
-    pred = jnp.argmax(logits[:batch_size], axis=-1)
-    correct = jnp.sum((pred == y[:batch_size]) & valid)
+    with layer_scope('optimizer'):
+      updates, opt_state = tx.update(grads, state.opt_state,
+                                     state.params)
+      params = optax.apply_updates(state.params, updates)
+    with layer_scope('model', 'metrics'):
+      valid = seeds >= 0
+      pred = jnp.argmax(logits[:batch_size], axis=-1)
+      correct = jnp.sum((pred == y[:batch_size]) & valid)
     return TrainState(params, opt_state, state.step + 1), loss, correct
 
-  return step
+  return supervised_step
 
 
 def _apply_with_weights(apply_fn, params, batch):
@@ -100,14 +106,15 @@ def make_extracted_eval_step(extract: Callable, batch_size: int):
   adapter `make_extracted_supervised_step` takes — ONE definition of
   the masked seed-slot accuracy."""
 
-  def step(params, batch):
+  def eval_step(params, batch):
     logits, y, seeds = extract(params, batch)
-    valid = seeds >= 0
-    pred = jnp.argmax(logits[:batch_size], axis=-1)
-    correct = jnp.sum((pred == y[:batch_size]) & valid)
-    return correct, jnp.sum(valid)
+    with layer_scope('model', 'metrics'):
+      valid = seeds >= 0
+      pred = jnp.argmax(logits[:batch_size], axis=-1)
+      correct = jnp.sum((pred == y[:batch_size]) & valid)
+      return correct, jnp.sum(valid)
 
-  return step
+  return eval_step
 
 
 def make_eval_step(apply_fn, batch_size: int):
@@ -178,14 +185,17 @@ def make_unsupervised_step(apply_fn, tx: optax.GradientTransformation):
   per-batch loaders and `loader.fused.FusedLinkEpoch`."""
 
   @jax.jit
-  def step(state: TrainState, batch):
+  def unsupervised_step(state: TrainState, batch):
     def loss_fn(params):
       emb = apply_fn(params, batch.x, batch.edge_index, batch.edge_mask)
-      return link_loss_from_metadata(emb, batch.metadata)
+      with layer_scope('model', 'loss'):
+        return link_loss_from_metadata(emb, batch.metadata)
 
     loss, grads = jax.value_and_grad(loss_fn)(state.params)
-    updates, opt_state = tx.update(grads, state.opt_state, state.params)
-    params = optax.apply_updates(state.params, updates)
+    with layer_scope('optimizer'):
+      updates, opt_state = tx.update(grads, state.opt_state,
+                                     state.params)
+      params = optax.apply_updates(state.params, updates)
     return TrainState(params, opt_state, state.step + 1), loss
 
-  return step
+  return unsupervised_step

@@ -34,6 +34,8 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from ..utils.profiling import layer_scope
+
 
 def tree_level_sizes(batch_size: int, fanouts: Sequence[int]
                      ) -> Tuple[int, ...]:
@@ -70,35 +72,37 @@ class TreeSAGE(nn.Module):
       raise ValueError(
           f'TreeSAGE(num_layers={self.num_layers}) needs '
           f'{self.num_layers + 1} levels, got {len(xs)}')
-    hs = [x.astype(self.dtype) if self.dtype is not None else x
-          for x in xs]
-    # zero out invalid slots once: they then contribute nothing as
-    # self terms of masked-out rows or as masked children
-    hs = [h * m[:, None].astype(h.dtype) for h, m in zip(hs, masks)]
+    with layer_scope('model', 'input'):
+      hs = [x.astype(self.dtype) if self.dtype is not None else x
+            for x in xs]
+      # zero out invalid slots once: they then contribute nothing as
+      # self terms of masked-out rows or as masked children
+      hs = [h * m[:, None].astype(h.dtype) for h, m in zip(hs, masks)]
     for layer in range(self.num_layers):
-      out = (self.hidden_features if layer < self.num_layers - 1
-             else self.out_features)
-      lin_self = nn.Dense(out, dtype=self.dtype,
-                          name=f'layer{layer}_self')
-      lin_neigh = nn.Dense(out, use_bias=False, dtype=self.dtype,
-                           name=f'layer{layer}_neigh')
-      new_hs = []
-      for t in range(self.num_layers - layer):
-        parent, child = hs[t], hs[t + 1]
-        k = child.shape[0] // parent.shape[0]
-        cm = masks[t + 1].reshape(parent.shape[0], k)
-        cd = child.reshape(parent.shape[0], k, child.shape[1])
-        # masked mean over the static child window — the whole
-        # aggregation.  The mask must gate the SUM too: past layer 0
-        # an invalid slot's activation is relu(bias) != 0 (the input
-        # zeroing above only cleans the leaves), and an unmasked sum
-        # would leak it into every window with degree < fanout.
-        cnt = jnp.maximum(cm.sum(axis=1, dtype=jnp.float32), 1.0)
-        mean = ((cd * cm[..., None].astype(cd.dtype)).sum(axis=1)
-                / cnt[:, None].astype(cd.dtype))
-        h = lin_self(parent) + lin_neigh(mean)
-        if layer < self.num_layers - 1:
-          h = nn.relu(h)
-        new_hs.append(h)
-      hs = new_hs
+      with layer_scope('model', f'layer{layer}'):
+        out = (self.hidden_features if layer < self.num_layers - 1
+               else self.out_features)
+        lin_self = nn.Dense(out, dtype=self.dtype,
+                            name=f'layer{layer}_self')
+        lin_neigh = nn.Dense(out, use_bias=False, dtype=self.dtype,
+                             name=f'layer{layer}_neigh')
+        new_hs = []
+        for t in range(self.num_layers - layer):
+          parent, child = hs[t], hs[t + 1]
+          k = child.shape[0] // parent.shape[0]
+          cm = masks[t + 1].reshape(parent.shape[0], k)
+          cd = child.reshape(parent.shape[0], k, child.shape[1])
+          # masked mean over the static child window — the whole
+          # aggregation.  The mask must gate the SUM too: past layer 0
+          # an invalid slot's activation is relu(bias) != 0 (the input
+          # zeroing above only cleans the leaves), and an unmasked sum
+          # would leak it into every window with degree < fanout.
+          cnt = jnp.maximum(cm.sum(axis=1, dtype=jnp.float32), 1.0)
+          mean = ((cd * cm[..., None].astype(cd.dtype)).sum(axis=1)
+                  / cnt[:, None].astype(cd.dtype))
+          h = lin_self(parent) + lin_neigh(mean)
+          if layer < self.num_layers - 1:
+            h = nn.relu(h)
+          new_hs.append(h)
+        hs = new_hs
     return hs[0].astype(jnp.float32)

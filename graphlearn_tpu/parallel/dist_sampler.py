@@ -40,9 +40,10 @@ from ..ops.neighbor import sample_one_hop
 from ..ops.pallas_sample import sample_one_hop_auto
 from ..ops.unique import init_node, induce_next
 from ..utils.padding import INVALID_ID, max_sampled_nodes, round_up
+from ..utils.profiling import layer_scope
 from .dist_data import DistDataset
 from .exchange import (MIN_EXCHANGE_CAP, capacity_spec, dest_histogram,
-                       plan_exchange, resolve_layout)
+                       plan_exchange, resolve_layout, scoped_plan)
 from .partition_book import (book_owner_fn, edge_book_owner_fn,
                              edge_local_rows, edge_owner_fn,
                              hot_split_host, range_owner_fn)
@@ -242,28 +243,31 @@ def dist_edge_exists(indptr_loc, indices_loc, bounds, rows, cols,
   """
   my_idx = jax.lax.axis_index(axis)
   if book_spec is not None:
-    plan = _BookPlan(rows, bounds, book_spec, axis, exchange_capacity,
-                     payload=cols)
+    plan = scoped_plan('pairs', lambda: _BookPlan(
+        rows, bounds, book_spec, axis, exchange_capacity, payload=cols))
     slot_ranges = jnp.asarray(book_spec.slot_ranges, jnp.int32)
     lanes_ex = []
-    for j in range(book_spec.num_lanes):
-      r_j = jnp.clip(slot_ranges[my_idx, j], 0, num_parts - 1)
-      flat_r = plan.recv_lanes[j]
-      local_r = jnp.where(flat_r >= 0, flat_r - bounds[r_j],
-                          INVALID_ID).astype(jnp.int32)
-      lanes_ex.append(edge_in_csr(
-          indptr_loc[j], indices_loc[j], local_r,
-          plan.recv_payload_lanes[j].astype(jnp.int32)))
-    return plan.reply(jnp.stack(lanes_ex), fill=True)
+    with layer_scope('sample', 'negative'):
+      for j in range(book_spec.num_lanes):
+        r_j = jnp.clip(slot_ranges[my_idx, j], 0, num_parts - 1)
+        flat_r = plan.recv_lanes[j]
+        local_r = jnp.where(flat_r >= 0, flat_r - bounds[r_j],
+                            INVALID_ID).astype(jnp.int32)
+        lanes_ex.append(edge_in_csr(
+            indptr_loc[j], indices_loc[j], local_r,
+            plan.recv_payload_lanes[j].astype(jnp.int32)))
+      ex = jnp.stack(lanes_ex)
+    return plan.reply(ex, fill=True)
   my_start = bounds[my_idx]
   owner_fn = range_owner_fn(bounds)
   plan = plan_exchange(rows, owner_fn, num_parts, axis,
-                       exchange_capacity, payload=cols)
-  flat_r = plan.recv
-  local_r = jnp.where(flat_r >= 0, flat_r - my_start,
-                      INVALID_ID).astype(jnp.int32)
-  ex = edge_in_csr(indptr_loc, indices_loc, local_r,
-                   plan.recv_payload.astype(jnp.int32))
+                       exchange_capacity, payload=cols, what='pairs')
+  with layer_scope('sample', 'negative'):
+    flat_r = plan.recv
+    local_r = jnp.where(flat_r >= 0, flat_r - my_start,
+                        INVALID_ID).astype(jnp.int32)
+    ex = edge_in_csr(indptr_loc, indices_loc, local_r,
+                     plan.recv_payload.astype(jnp.int32))
   # undelivered pairs fill True ("exists", so never a strict negative)
   return plan.reply(ex, fill=True)
 
@@ -321,45 +325,49 @@ def _dist_one_hop_book(indptr_l, indices_l, eids_l, bounds, frontier,
   contract.  Local arrays carry a leading lane axis (``[S, ...]``).
   """
   my_idx = jax.lax.axis_index(axis)
-  plan = _BookPlan(frontier, bounds, book_spec, axis,
-                   exchange_capacity)
+  plan = scoped_plan('frontier', lambda: _BookPlan(
+      frontier, bounds, book_spec, axis, exchange_capacity))
   slot_ranges = jnp.asarray(book_spec.slot_ranges, jnp.int32)
   outs_n, outs_m, outs_e, outs_w = [], [], [], []
-  for j in range(book_spec.num_lanes):
-    r_j = jnp.clip(slot_ranges[my_idx, j], 0, num_parts - 1)
-    flat = plan.recv_lanes[j]
-    local = jnp.where(flat >= 0, flat - bounds[r_j],
-                      INVALID_ID).astype(jnp.int32)
-    lane_key = jax.random.fold_in(key, r_j)
-    # sample_one_hop_auto resolves the GLT_PALLAS_SAMPLE dispatch at
-    # trace time (value-identical either way — the gns.bias build-
-    # time-event precedent); the dedup bits tuple flows as a pytree
-    if gns_bits is not None:
-      from ..ops.gns import is_per_requester
-      res = sample_one_hop_auto(
-          indptr_l[j], indices_l[j], local, k, lane_key,
-          eids_l[j] if eids_l is not None else None,
-          bits=gns_bits, boost=float(gns_boost),
-          req=(plan.req_of_lane_recv if is_per_requester(gns_bits)
-               else None),
-          with_edge_ids=with_edge, sort_locality=sort_locality)
-    else:
-      res = sample_one_hop_auto(
-          indptr_l[j], indices_l[j], local, k, lane_key,
-          eids_l[j] if eids_l is not None else None,
-          with_edge_ids=with_edge, sort_locality=sort_locality)
-    outs_n.append(res.nbrs)
-    outs_m.append(res.mask)
-    if with_edge:
-      outs_e.append(res.eids)
-    if res.weights is not None:
-      outs_w.append(res.weights)
-  out_nbrs = plan.reply(jnp.stack(outs_n), fill=INVALID_ID)
-  out_mask = plan.reply(jnp.stack(outs_m), fill=False)
-  out_eids = (plan.reply(jnp.stack(outs_e), fill=INVALID_ID)
-              if with_edge else None)
-  out_w = (plan.reply(jnp.stack(outs_w), fill=0.0)
-           if outs_w else None)
+  with layer_scope('sample', 'owner'):
+    for j in range(book_spec.num_lanes):
+      r_j = jnp.clip(slot_ranges[my_idx, j], 0, num_parts - 1)
+      flat = plan.recv_lanes[j]
+      local = jnp.where(flat >= 0, flat - bounds[r_j],
+                        INVALID_ID).astype(jnp.int32)
+      lane_key = jax.random.fold_in(key, r_j)
+      # sample_one_hop_auto resolves the GLT_PALLAS_SAMPLE dispatch at
+      # trace time (value-identical either way — the gns.bias build-
+      # time-event precedent); the dedup bits tuple flows as a pytree
+      if gns_bits is not None:
+        from ..ops.gns import is_per_requester
+        res = sample_one_hop_auto(
+            indptr_l[j], indices_l[j], local, k, lane_key,
+            eids_l[j] if eids_l is not None else None,
+            bits=gns_bits, boost=float(gns_boost),
+            req=(plan.req_of_lane_recv if is_per_requester(gns_bits)
+                 else None),
+            with_edge_ids=with_edge, sort_locality=sort_locality)
+      else:
+        res = sample_one_hop_auto(
+            indptr_l[j], indices_l[j], local, k, lane_key,
+            eids_l[j] if eids_l is not None else None,
+            with_edge_ids=with_edge, sort_locality=sort_locality)
+      outs_n.append(res.nbrs)
+      outs_m.append(res.mask)
+      if with_edge:
+        outs_e.append(res.eids)
+      if res.weights is not None:
+        outs_w.append(res.weights)
+    outs_n, outs_m = jnp.stack(outs_n), jnp.stack(outs_m)
+    outs_e = jnp.stack(outs_e) if with_edge else None
+    outs_w = jnp.stack(outs_w) if outs_w else None
+  out_nbrs = plan.reply(outs_n, fill=INVALID_ID)
+  out_mask = plan.reply(outs_m, fill=False)
+  out_eids = (plan.reply(outs_e, fill=INVALID_ID)
+              if outs_e is not None else None)
+  out_w = (plan.reply(outs_w, fill=0.0)
+           if outs_w is not None else None)
   return out_nbrs, out_mask, out_eids, out_w, plan.stats
 
 
@@ -393,34 +401,36 @@ def _dist_one_hop(indptr_loc, indices_loc, eids_loc, bounds, frontier,
   my_start = bounds[my_idx]
   owner_fn = range_owner_fn(bounds)
   plan = plan_exchange(frontier, owner_fn, num_parts, axis,
-                       exchange_capacity)
-  flat = plan.recv
-  local = jnp.where(flat >= 0, flat - my_start, INVALID_ID).astype(jnp.int32)
-  if gns_bits is not None:
-    from ..ops.gns import fallback_req_index, is_per_requester
-    req = None
-    if is_per_requester(gns_bits):
-      # per-requester masks (ISSUE 15): the plan attributes each recv
-      # row to its source device; layouts that cannot (hier's
-      # two-stage re-bucketing) fall back to the hot-split-only row —
-      # conservative (never over-boosts), still exactly corrected.
-      # r19 carries the masks as the dedup (table, row_index) tuple —
-      # O(distinct caches) VMEM instead of O(P) replication
-      req = getattr(plan, 'requester_of_recv', None)
-      if req is None:
-        req = jnp.full(flat.shape, fallback_req_index(gns_bits),
-                       jnp.int32)
-    res = sample_one_hop_auto(indptr_loc, indices_loc, local, k,
-                              jax.random.fold_in(key, my_idx),
-                              eids_loc, bits=gns_bits,
-                              boost=float(gns_boost), req=req,
-                              with_edge_ids=with_edge,
-                              sort_locality=sort_locality)
-  else:
-    res = sample_one_hop_auto(indptr_loc, indices_loc, local, k,
-                              jax.random.fold_in(key, my_idx),
-                              eids_loc, with_edge_ids=with_edge,
-                              sort_locality=sort_locality)
+                       exchange_capacity, what='frontier')
+  with layer_scope('sample', 'owner'):
+    flat = plan.recv
+    local = jnp.where(flat >= 0, flat - my_start,
+                      INVALID_ID).astype(jnp.int32)
+    if gns_bits is not None:
+      from ..ops.gns import fallback_req_index, is_per_requester
+      req = None
+      if is_per_requester(gns_bits):
+        # per-requester masks (ISSUE 15): the plan attributes each recv
+        # row to its source device; layouts that cannot (hier's
+        # two-stage re-bucketing) fall back to the hot-split-only row —
+        # conservative (never over-boosts), still exactly corrected.
+        # r19 carries the masks as the dedup (table, row_index) tuple —
+        # O(distinct caches) VMEM instead of O(P) replication
+        req = getattr(plan, 'requester_of_recv', None)
+        if req is None:
+          req = jnp.full(flat.shape, fallback_req_index(gns_bits),
+                         jnp.int32)
+      res = sample_one_hop_auto(indptr_loc, indices_loc, local, k,
+                                jax.random.fold_in(key, my_idx),
+                                eids_loc, bits=gns_bits,
+                                boost=float(gns_boost), req=req,
+                                with_edge_ids=with_edge,
+                                sort_locality=sort_locality)
+    else:
+      res = sample_one_hop_auto(indptr_loc, indices_loc, local, k,
+                                jax.random.fold_in(key, my_idx),
+                                eids_loc, with_edge_ids=with_edge,
+                                sort_locality=sort_locality)
   out_nbrs = plan.reply(res.nbrs, fill=INVALID_ID)
   out_mask = plan.reply(res.mask, fill=False)
   out_eids = plan.reply(res.eids, fill=INVALID_ID) if with_edge else None
@@ -439,36 +449,40 @@ def _dist_gather_multi_book(shard_locs, bounds, ids, axis: str,
   hot-tier gate keys on the RANGE's hot count (placement is frozen;
   only the serving device moved)."""
   my_idx = jax.lax.axis_index(axis)
-  plan = _BookPlan(ids, bounds, book_spec, axis, exchange_capacity,
-                   owner_mode=shard_mode)
+  plan = scoped_plan('feature', lambda: _BookPlan(
+      ids, bounds, book_spec, axis, exchange_capacity,
+      owner_mode=shard_mode))
   slot_ranges = jnp.asarray(book_spec.slot_ranges, jnp.int32)
   ok = (ids >= 0) & plan.delivered
   outs = []
   for t, shard_l in enumerate(shard_locs):
     lane_rows = []
-    for j in range(book_spec.num_lanes):
-      flat = plan.recv_lanes[j]
-      valid = flat >= 0
-      r_j = jnp.clip(slot_ranges[my_idx, j], 0, num_parts - 1)
-      if shard_mode == 'mod':
-        local = jnp.where(valid, edge_local_rows(flat, num_parts), 0)
+    with layer_scope('gather', 'owner'):
+      for j in range(book_spec.num_lanes):
+        flat = plan.recv_lanes[j]
+        valid = flat >= 0
+        r_j = jnp.clip(slot_ranges[my_idx, j], 0, num_parts - 1)
+        if shard_mode == 'mod':
+          local = jnp.where(valid, edge_local_rows(flat, num_parts), 0)
+        else:
+          local = jnp.where(valid, flat - bounds[r_j], 0)
+        row_valid = valid
+        if t == 0 and hot_counts is not None:
+          row_valid = valid & (local < hot_counts[r_j])
+        idx = jnp.clip(local, 0, shard_l.shape[1] - 1)
+        rows = shard_l[j][idx]
+        if rows.ndim == 1:
+          rows = jnp.where(row_valid, rows, 0)
+        else:
+          rows = jnp.where(row_valid[:, None], rows, 0)
+        lane_rows.append(rows)
+      lane_rows = jnp.stack(lane_rows)
+    out = plan.reply(lane_rows, fill=0)
+    with layer_scope('gather', 'mask'):
+      if out.ndim == 1:
+        outs.append(jnp.where(ok, out, 0))
       else:
-        local = jnp.where(valid, flat - bounds[r_j], 0)
-      row_valid = valid
-      if t == 0 and hot_counts is not None:
-        row_valid = valid & (local < hot_counts[r_j])
-      idx = jnp.clip(local, 0, shard_l.shape[1] - 1)
-      rows = shard_l[j][idx]
-      if rows.ndim == 1:
-        rows = jnp.where(row_valid, rows, 0)
-      else:
-        rows = jnp.where(row_valid[:, None], rows, 0)
-      lane_rows.append(rows)
-    out = plan.reply(jnp.stack(lane_rows), fill=0)
-    if out.ndim == 1:
-      outs.append(jnp.where(ok, out, 0))
-    else:
-      outs.append(jnp.where(ok[:, None], out, 0))
+        outs.append(jnp.where(ok[:, None], out, 0))
   return tuple(outs), plan.stats
 
 
@@ -508,30 +522,33 @@ def dist_gather_multi(shard_locs, bounds, ids, axis: str, num_parts: int,
     my_start = bounds[my_idx]
     owner_fn = range_owner_fn(bounds)
   plan = plan_exchange(ids, owner_fn, num_parts, axis,
-                       exchange_capacity)
-  flat = plan.recv
-  valid = flat >= 0
-  if shard_mode == 'mod':
-    local = jnp.where(valid, edge_local_rows(flat, num_parts), 0)
-  else:
-    local = jnp.where(valid, flat - my_start, 0)
+                       exchange_capacity, what='feature')
+  with layer_scope('gather', 'owner'):
+    flat = plan.recv
+    valid = flat >= 0
+    if shard_mode == 'mod':
+      local = jnp.where(valid, edge_local_rows(flat, num_parts), 0)
+    else:
+      local = jnp.where(valid, flat - my_start, 0)
   ok = (ids >= 0) & plan.delivered
   outs = []
   for t, shard_loc in enumerate(shard_locs):
-    row_valid = valid
-    if t == 0 and hot_counts is not None:
-      row_valid = valid & (local < hot_counts[my_idx])
-    idx = jnp.clip(local, 0, shard_loc.shape[0] - 1)
-    rows = shard_loc[idx]
-    if rows.ndim == 1:
-      rows = jnp.where(row_valid, rows, 0)
-    else:
-      rows = jnp.where(row_valid[:, None], rows, 0)
+    with layer_scope('gather', 'owner'):
+      row_valid = valid
+      if t == 0 and hot_counts is not None:
+        row_valid = valid & (local < hot_counts[my_idx])
+      idx = jnp.clip(local, 0, shard_loc.shape[0] - 1)
+      rows = shard_loc[idx]
+      if rows.ndim == 1:
+        rows = jnp.where(row_valid, rows, 0)
+      else:
+        rows = jnp.where(row_valid[:, None], rows, 0)
     out = plan.reply(rows, fill=0)
-    if out.ndim == 1:
-      outs.append(jnp.where(ok, out, 0))
-    else:
-      outs.append(jnp.where(ok[:, None], out, 0))
+    with layer_scope('gather', 'mask'):
+      if out.ndim == 1:
+        outs.append(jnp.where(ok, out, 0))
+      else:
+        outs.append(jnp.where(ok[:, None], out, 0))
   return tuple(outs), plan.stats
 
 

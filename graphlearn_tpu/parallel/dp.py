@@ -109,11 +109,11 @@ def make_dp_supervised_step(apply_fn: Callable,
       out_specs=(P(), P(), P()))
 
   @jax.jit
-  def step(state, stacked_batch):
+  def dp_supervised_step(state, stacked_batch):
     new_state, loss, correct = sharded(state, stacked_batch)
     return new_state, loss, correct
 
-  return step
+  return dp_supervised_step
 
 
 def make_dp_eval_step(apply_fn: Callable, batch_size: int, mesh: Mesh,
@@ -170,10 +170,10 @@ def make_dp_unsupervised_step(apply_fn: Callable,
       out_specs=(P(), P()))
 
   @jax.jit
-  def step(state, stacked_batch):
+  def dp_unsupervised_step(state, stacked_batch):
     return sharded(state, stacked_batch)
 
-  return step
+  return dp_unsupervised_step
 
 
 class DataParallelLoader:

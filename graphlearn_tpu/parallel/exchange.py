@@ -84,6 +84,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..utils.padding import INVALID_ID, round_up
+from ..utils.profiling import layer_scope
 
 #: per-destination capacity floor of the DENSE layout: exchanges this
 #: small gain nothing from capping (the buffer is a few KB) but would
@@ -728,8 +729,28 @@ class _RaggedPlan:  # pragma: no cover — unreachable, see below
     return back[inv]
 
 
+class scoped_plan:
+  """A plan under ``glt.exchange/<what>``: ``build()`` (pack + the
+  request collective) and every ``reply`` (the reply collective +
+  unpack) trace inside the scope, so a profiler trace books them to
+  the exchange and what the owners do in between to its own layer."""
+
+  def __init__(self, what: Optional[str], build: Callable):
+    self._what = what
+    with layer_scope('exchange', what):
+      self._plan = build()
+
+  def reply(self, values, fill=0):
+    with layer_scope('exchange', self._what):
+      return self._plan.reply(values, fill)
+
+  def __getattr__(self, name):
+    return getattr(self._plan, name)
+
+
 def plan_exchange(ids: jax.Array, owner_fn: Callable, num_parts: int,
-                  axis: str, spec=None, payload=None):
+                  axis: str, spec=None, payload=None,
+                  what: Optional[str] = None):
   """Build the exchange plan for one request vector.
 
   Args:
@@ -741,6 +762,8 @@ def plan_exchange(ids: jax.Array, owner_fn: Callable, num_parts: int,
       `ExchangeSpec` from `capacity_spec`.
     payload: optional [F] companion array delivered alongside each id
       (the (row, col) pair shipping of the distributed edge test).
+    what: what travels (``frontier`` / ``feature`` / ``pairs``) — the
+      part of the ``glt.exchange`` scope the plan traces under.
 
   Returns a plan with ``recv`` (flat ids this device must answer),
   ``recv_payload`` (when ``payload`` given), ``kept``/``delivered``
@@ -748,6 +771,11 @@ def plan_exchange(ids: jax.Array, owner_fn: Callable, num_parts: int,
   ``reply(values, fill)`` mapping owner-side [R, ...] results back to
   request order.
   """
+  return scoped_plan(what, lambda: _plan(ids, owner_fn, num_parts, axis,
+                                         spec, payload))
+
+
+def _plan(ids, owner_fn, num_parts: int, axis: str, spec, payload):
   if spec is None or isinstance(spec, (int, np.integer)):
     return _DensePlan(ids, owner_fn, num_parts, axis,
                       None if spec is None else int(spec),

@@ -54,6 +54,7 @@ from ..loader.fused import (_COMPILED_ATTRS, _SnapshotHooks,
                             _counted_jit, driver_compile_count,
                             resolve_cold_chunk)
 from ..models.train import TrainState
+from ..utils.profiling import layer_scope
 from .dist_data import DistDataset
 from .dist_sampler import (DistLinkNeighborSampler, DistNeighborSampler,
                            link_step_metadata, pack_link_seeds_relabeled,
@@ -189,10 +190,11 @@ class _MeshEpochDriver(_SnapshotHooks):
     from ..telemetry.spans import span
     from ..testing import chaos
     from ..utils.profiling import step_annotation
-    flat = np.stack(list(self._batcher))           # [S, P*B]
-    seeds = flat.reshape(-1, self.num_parts, self.batch_size)
-    s = seeds.shape[0]
-    key = self._next_epoch_key()
+    with span('fused.seeds'):
+      flat = np.stack(list(self._batcher))         # [S, P*B]
+      seeds = flat.reshape(-1, self.num_parts, self.batch_size)
+      s = seeds.shape[0]
+      key = self._next_epoch_key()
     with span('fused.epoch', scope=type(self).__name__,
               epoch=self._epoch_idx, steps=seeds.shape[0],
               tiered=self._tiered):
@@ -824,9 +826,13 @@ class FusedDistTreeEpoch(_MeshEpochDriver):
     # all-zero) on every tiered envelope epoch
     attr_owner = range_owner_fn(bounds)
     attr_fr = jnp.zeros((self.num_parts,), jnp.int32)
+    # the exchange and the owners' work carry their own scopes
+    # (`dist_sampler`); a scope around them here would claim them, so
+    # only what this function does itself is scoped
     for h, k in enumerate(self.fanouts):
-      attr_fr = attr_fr + dest_histogram(frontier, attr_owner,
-                                         self.num_parts)
+      with layer_scope('exchange', 'stats'):
+        attr_fr = attr_fr + dest_histogram(frontier, attr_owner,
+                                           self.num_parts)
       nbrs, mask, _, hw, st = _dist_one_hop(
           indptr_s, indices_s, None, bounds, frontier, int(k),
           jax.random.fold_in(key, h), self.axis, self.num_parts,
@@ -834,74 +840,84 @@ class FusedDistTreeEpoch(_MeshEpochDriver):
           exchange_capacity=_slack_cap(frontier.shape[0],
                                        self.num_parts, slack, layout),
           gns_bits=gns_bits, gns_boost=boost, book_spec=book_spec)
-      fstats = fstats + jnp.stack(st)
-      nxt = jnp.where(mask, nbrs, -1).reshape(-1)
-      levels.append(nxt)
-      if gns:
-        w_levels.append((w_levels[-1][:, None] * hw).reshape(-1))
-      frontier = nxt
-    all_ids = jnp.concatenate(levels)
+      with layer_scope('sample', f'hop{h}'):
+        fstats = fstats + jnp.stack(st)
+        nxt = jnp.where(mask, nbrs, -1).reshape(-1)
+        levels.append(nxt)
+        if gns:
+          w_levels.append((w_levels[-1][:, None] * hw).reshape(-1))
+        frontier = nxt
+    with layer_scope('gather', 'ids'):
+      all_ids = jnp.concatenate(levels)
     (feats, labels), gst = dist_gather_multi(
         (fshards_s, lshards_s), bounds, all_ids, self.axis,
         self.num_parts,
         exchange_capacity=_slack_cap(all_ids.shape[0], self.num_parts,
                                      slack, layout),
         hot_counts=hcounts, book_spec=book_spec)
-    attr_ft = dest_histogram(all_ids, attr_owner, self.num_parts)
-    stats7 = jnp.concatenate(
-        [fstats, jnp.stack(gst), jnp.zeros((1,), jnp.int32),
-         attr_fr, attr_ft, jnp.zeros((1,), jnp.int32)])
-    hop_counts = jnp.stack(
-        [jnp.sum((lvl >= 0).astype(jnp.int32)) for lvl in levels])
-    y = labels[:self.batch_size]
-    if concat:
-      out = (all_ids, feats, y, stats7, hop_counts)
-      return out + (jnp.concatenate(w_levels),) if gns else out
-    sizes = [lvl.shape[0] for lvl in levels]
-    xs, off = [], 0
-    for s in sizes:
-      xs.append(feats[off:off + s])
-      off += s
-    masks = [lvl >= 0 for lvl in levels]
+    with layer_scope('exchange', 'stats'):
+      attr_ft = dest_histogram(all_ids, attr_owner, self.num_parts)
+      stats7 = jnp.concatenate(
+          [fstats, jnp.stack(gst), jnp.zeros((1,), jnp.int32),
+           attr_fr, attr_ft, jnp.zeros((1,), jnp.int32)])
+      hop_counts = jnp.stack(
+          [jnp.sum((lvl >= 0).astype(jnp.int32)) for lvl in levels])
+    with layer_scope('gather', 'split'):
+      y = labels[:self.batch_size]
+      if concat:
+        out = (all_ids, feats, y, stats7, hop_counts)
+        return out + (jnp.concatenate(w_levels),) if gns else out
+      sizes = [lvl.shape[0] for lvl in levels]
+      xs, off = [], 0
+      for s in sizes:
+        xs.append(feats[off:off + s])
+        off += s
+      masks = [lvl >= 0 for lvl in levels]
     return xs, masks, y, stats7, hop_counts
 
   def _eval_tail(self, params, xs, masks, y, valid):
     axis = self.axis
     logits = self._eval_apply(params, xs, masks)
-    correct = jax.lax.psum(
-        jnp.sum((jnp.argmax(logits, -1) == y) & valid), axis)
-    total = jax.lax.psum(jnp.sum(valid), axis)
+    with layer_scope('model', 'metrics'):
+      correct = jax.lax.psum(
+          jnp.sum((jnp.argmax(logits, -1) == y) & valid), axis)
+      total = jax.lax.psum(jnp.sum(valid), axis)
     return correct, total
 
   def _train_tail(self, state, xs, masks, y, valid, hop_counts):
     """The DP update half of the tree step — shared by the fused
     single-program path and the tiered consume scan."""
     axis, b = self.axis, self.batch_size
-    hop_g = jax.lax.psum(hop_counts, axis)         # global [H+1]
+    with layer_scope('exchange', 'stats'):
+      hop_g = jax.lax.psum(hop_counts, axis)       # global [H+1]
+      n_valid = jax.lax.psum(jnp.sum(valid), axis)
 
     def loss_fn(params):
       logits = self._apply(params, xs, masks)
-      vf = valid.astype(logits.dtype)
-      ce = optax.softmax_cross_entropy_with_integer_labels(
-          logits, y.astype(jnp.int32))
-      return (ce * vf).sum() / jnp.maximum(vf.sum(), 1.0), logits
+      with layer_scope('model', 'loss'):
+        vf = valid.astype(logits.dtype)
+        ce = optax.softmax_cross_entropy_with_integer_labels(
+            logits, y.astype(jnp.int32))
+        return (ce * vf).sum() / jnp.maximum(vf.sum(), 1.0), logits
 
     (loss, logits), grads = jax.value_and_grad(
         loss_fn, has_aux=True)(state.params)
-    grads = jax.lax.pmean(grads, axis)
-    loss = jax.lax.pmean(loss, axis)
-    updates, opt_state = self.tx.update(grads, state.opt_state,
-                                        state.params)
-    params = optax.apply_updates(state.params, updates)
-    new_state = TrainState(params, opt_state, state.step + 1)
-    any_valid = jax.lax.psum(jnp.sum(valid), axis) > 0
-    state = jax.tree_util.tree_map(
-        lambda new, old: jnp.where(any_valid, new, old),
-        new_state, state)
-    correct = jax.lax.psum(
-        jnp.sum((jnp.argmax(logits[:b], -1) == y) & valid), axis)
-    return (state, loss, correct, jax.lax.psum(jnp.sum(valid), axis),
-            hop_g)
+    with layer_scope('exchange', 'grads'):
+      grads = jax.lax.pmean(grads, axis)
+      loss = jax.lax.pmean(loss, axis)
+    with layer_scope('optimizer'):
+      updates, opt_state = self.tx.update(grads, state.opt_state,
+                                          state.params)
+      params = optax.apply_updates(state.params, updates)
+      new_state = TrainState(params, opt_state, state.step + 1)
+      any_valid = n_valid > 0
+      state = jax.tree_util.tree_map(
+          lambda new, old: jnp.where(any_valid, new, old),
+          new_state, state)
+    with layer_scope('model', 'metrics'):
+      correct = jax.lax.psum(
+          jnp.sum((jnp.argmax(logits[:b], -1) == y) & valid), axis)
+    return state, loss, correct, n_valid, hop_g
 
   def _make_sharded(self, train: bool):
     from .shard_map_compat import shard_map

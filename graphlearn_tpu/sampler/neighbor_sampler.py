@@ -37,6 +37,7 @@ from ..ops.negative import edge_in_csr, sample_negative
 from ..ops.subgraph import induced_subgraph
 from ..ops.unique import InducerState, induce_next, init_node
 from ..utils.padding import INVALID_ID, max_sampled_nodes, round_up
+from ..utils.profiling import layer_scope
 from .base import (BaseSampler, EdgeSamplerInput, NegativeSampling,
                    NodeSamplerInput, SamplerOutput)
 
@@ -72,74 +73,85 @@ def _multihop_sample(
   # sort work for nothing.  Capacities are static per hop; the state
   # pads up right before each hop's insertion.
   cap = min(b, node_cap)
-  state, seed_local = init_node(seeds, cap)
+  with layer_scope('sample', 'dedup'):
+    state, seed_local = init_node(seeds, cap)
 
-  # hop-0 frontier: the deduped seeds occupy table slots [0, count).
-  f_cap = b
-  slots = jnp.arange(f_cap, dtype=jnp.int32)
-  fr_valid = slots < state.count
-  frontier = jnp.where(fr_valid, state.nodes[jnp.clip(slots, 0, cap - 1)],
-                       INVALID_ID)
-  frontier_local = jnp.where(fr_valid, slots, -1)
+    # hop-0 frontier: the deduped seeds occupy table slots [0, count).
+    f_cap = b
+    slots = jnp.arange(f_cap, dtype=jnp.int32)
+    fr_valid = slots < state.count
+    frontier = jnp.where(fr_valid,
+                         state.nodes[jnp.clip(slots, 0, cap - 1)],
+                         INVALID_ID)
+    frontier_local = jnp.where(fr_valid, slots, -1)
 
   rows_acc, cols_acc, eids_acc = [], [], []
   hop_node_counts = [state.count]
   hop_edge_counts = []
 
   for i, k in enumerate(fanouts):
-    hop_key = jax.random.fold_in(key, i)
     # dispatch resolves at trace time: use_fused is a static arg, so
     # flipping GLT_PALLAS_SAMPLE recompiles onto the Pallas kernel
     # (value-identical draws either way — see ops/pallas_sample.py)
-    res = sample_one_hop_auto(
-        indptr, indices, frontier, int(k), hop_key, edge_ids,
-        with_edge_ids=with_edge, sort_locality=sort_locality,
-        table=((win_table, win_e) if win_table is not None else None),
-        use_fused=use_fused)
-    new_cap = min(cap + f_cap * int(k), node_cap)
-    if new_cap > cap:
+    with layer_scope('sample', f'hop{i}'):
+      hop_key = jax.random.fold_in(key, i)
+      res = sample_one_hop_auto(
+          indptr, indices, frontier, int(k), hop_key, edge_ids,
+          with_edge_ids=with_edge, sort_locality=sort_locality,
+          table=((win_table, win_e) if win_table is not None else None),
+          use_fused=use_fused)
+    with layer_scope('sample', 'dedup'):
+      new_cap = min(cap + f_cap * int(k), node_cap)
+      if new_cap > cap:
+        state = InducerState(
+            nodes=jnp.concatenate([
+                state.nodes,
+                jnp.full((new_cap - cap,), INVALID_ID,
+                         state.nodes.dtype)]),
+            count=state.count)
+        cap = new_cap
+      state, rows, cols, prev_cnt = induce_next(
+          state, frontier_local, res.nbrs, res.mask)
+      rows_acc.append(rows)
+      cols_acc.append(cols)
+      if with_edge:
+        eids_acc.append(jnp.where(rows >= 0, res.eids.reshape(-1),
+                                  INVALID_ID))
+      hop_node_counts.append(state.count)
+      hop_edge_counts.append(jnp.sum(rows >= 0))
+
+      # next frontier = nodes appended this hop: table slots
+      # [prev, count).
+      f_cap = f_cap * int(k)
+      slots = prev_cnt + jnp.arange(f_cap, dtype=jnp.int32)
+      fr_valid = slots < state.count
+      frontier = jnp.where(
+          fr_valid, state.nodes[jnp.clip(slots, 0, cap - 1)], INVALID_ID)
+      frontier_local = jnp.where(fr_valid, slots, -1)
+
+  with layer_scope('sample', 'pack'):
+    if cap < node_cap:
+      # consumers expect the [node_cap] table shape
       state = InducerState(
           nodes=jnp.concatenate([
               state.nodes,
-              jnp.full((new_cap - cap,), INVALID_ID, state.nodes.dtype)]),
+              jnp.full((node_cap - cap,), INVALID_ID, state.nodes.dtype)]),
           count=state.count)
-      cap = new_cap
-    state, rows, cols, prev_cnt = induce_next(
-        state, frontier_local, res.nbrs, res.mask)
-    rows_acc.append(rows)
-    cols_acc.append(cols)
-    if with_edge:
-      eids_acc.append(jnp.where(rows >= 0, res.eids.reshape(-1), INVALID_ID))
-    hop_node_counts.append(state.count)
-    hop_edge_counts.append(jnp.sum(rows >= 0))
 
-    # next frontier = nodes appended this hop: table slots [prev, count).
-    f_cap = f_cap * int(k)
-    slots = prev_cnt + jnp.arange(f_cap, dtype=jnp.int32)
-    fr_valid = slots < state.count
-    frontier = jnp.where(
-        fr_valid, state.nodes[jnp.clip(slots, 0, cap - 1)], INVALID_ID)
-    frontier_local = jnp.where(fr_valid, slots, -1)
-
-  if cap < node_cap:
-    # consumers expect the [node_cap] table shape
-    state = InducerState(
-        nodes=jnp.concatenate([
-            state.nodes,
-            jnp.full((node_cap - cap,), INVALID_ID, state.nodes.dtype)]),
-        count=state.count)
-
-  row = jnp.concatenate(rows_acc) if rows_acc else jnp.zeros((0,), jnp.int32)
-  col = jnp.concatenate(cols_acc) if cols_acc else jnp.zeros((0,), jnp.int32)
-  edge = jnp.concatenate(eids_acc) if (with_edge and eids_acc) else None
-  # cumulative -> per-hop new-node counts.
-  cum = jnp.stack(hop_node_counts)
-  num_sampled_nodes = jnp.concatenate(
-      [cum[:1], cum[1:] - cum[:-1]]).astype(jnp.int32)
-  num_sampled_edges = (jnp.stack(hop_edge_counts).astype(jnp.int32)
-                       if hop_edge_counts else jnp.zeros((0,), jnp.int32))
-  return (state.nodes, state.count, row, col, edge, row >= 0, seed_local,
-          num_sampled_nodes, num_sampled_edges)
+    row = (jnp.concatenate(rows_acc) if rows_acc
+           else jnp.zeros((0,), jnp.int32))
+    col = (jnp.concatenate(cols_acc) if cols_acc
+           else jnp.zeros((0,), jnp.int32))
+    edge = jnp.concatenate(eids_acc) if (with_edge and eids_acc) else None
+    # cumulative -> per-hop new-node counts.
+    cum = jnp.stack(hop_node_counts)
+    num_sampled_nodes = jnp.concatenate(
+        [cum[:1], cum[1:] - cum[:-1]]).astype(jnp.int32)
+    num_sampled_edges = (jnp.stack(hop_edge_counts).astype(jnp.int32)
+                         if hop_edge_counts
+                         else jnp.zeros((0,), jnp.int32))
+    return (state.nodes, state.count, row, col, edge, row >= 0, seed_local,
+            num_sampled_nodes, num_sampled_edges)
 
 
 @functools.partial(jax.jit, static_argnames=('amount', 'num_nodes'))

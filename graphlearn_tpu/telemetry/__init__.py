@@ -1,5 +1,21 @@
 """Structured telemetry plane: flight recorder, mesh aggregation, sinks.
 
+What is on which clock:
+
+  * device scopes (`utils.profiling.layer_scope`: ``glt.sample`` /
+    ``gather`` / ``model`` / ``optimizer`` / ``exchange`` on every
+    device op of the jitted programs), `span()` and `step_annotation`
+    — the PROFILER's clock: they appear in a `jax.profiler` trace
+    beside the device ops;
+  * flight-recorder events (``span.begin`` / ``span.end`` with their
+    fields and ``dur``) — the host's monotonic clock, and only while
+    the recorder is on;
+  * the request `Tracer` — the host's clock; it records finished
+    spans and annotates nothing (serving only).
+
+The by-layer picture of a running program: ``with capture(dir):``
+around a few dispatches, open ``dir`` in xprof, filter ops by ``glt.``.
+
 The reference has NO tracing/profiling subsystem (SURVEY §5: wall-clock
 prints in benchmarks only); `utils/profiling.py` grew the first counters
 and xprof hooks, and this package grows them into a real layer with
@@ -31,7 +47,9 @@ three pieces:
 On top of the recorder sits the CAUSAL layer (this PR's tentpole):
 
   * :mod:`~graphlearn_tpu.telemetry.spans` — ``span()`` context
-    manager emitting paired ``span.begin``/``span.end`` events with
+    manager — the ONE host span primitive: a
+    `jax.profiler.TraceAnnotation` of its name always, and, with the
+    recorder on, paired ``span.begin``/``span.end`` events with
     ``trace_id``/``span_id``/``parent_id`` and monotonic-clock
     durations; the pipeline (channels, mesh samplers, loaders, the
     server/client runtime, fused epochs) opens sample → exchange →
@@ -105,13 +123,13 @@ Request-scoped fleet tracing (ISSUE 17) rides the live stack:
     `CapacityModel` EWMA cost model behind ``fleet.headroom_qps``.
 
 The low-level counter/timer registry (`Metrics`, the global
-:data:`metrics`, `trace`, `capture`) still lives in
-:mod:`graphlearn_tpu.utils.profiling` and is re-exported here.
+:data:`metrics`), `capture` and `step_annotation` still live in
+:mod:`graphlearn_tpu.utils.profiling` and are re-exported here.
 """
 from __future__ import annotations
 
 from ..utils.profiling import (Metrics, capture, metrics, start_trace,
-                               step_annotation, stop_trace, trace)
+                               step_annotation, stop_trace)
 from .aggregate import exchange_summary, gather_metrics, per_hop_padding
 from .federation import FleetScraper
 from .histogram import Histogram, from_snapshot
@@ -136,6 +154,6 @@ __all__ = [
     'maybe_start_from_env', 'metrics', 'parse_prometheus_text',
     'per_hop_padding', 'recorder', 'register_tier', 'span',
     'spans_to_events', 'split_exemplar', 'start_trace',
-    'step_annotation', 'stop_trace', 'summary_line', 'trace',
-    'tracer', 'write_artifact',
+    'step_annotation', 'stop_trace', 'summary_line', 'tracer',
+    'write_artifact',
 ]

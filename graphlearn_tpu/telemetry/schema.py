@@ -268,6 +268,19 @@ SPAN_NAMES: Dict[str, str] = {
         'DistServer: one blocking buffer pull for a client',
     'client.fetch':
         'DistClient: one RPC fetch round trip',
+    'loader.sample':
+        'NodeLoader._produce: one sample_from_nodes call (host '
+        'dispatch of the sampler program)',
+    'loader.collate':
+        'NodeLoader._produce: SamplerOutput -> Batch (feature and '
+        'label lookups, pytree assembly)',
+    'feature.get':
+        'loader.transform.to_data: the node-feature lookup inside '
+        'collate',
+    'fused.seeds':
+        'fused epoch drivers: host shuffle + stack (+ chunk padding) '
+        'of the epoch\'s seed set and the epoch key\'s fold-in (an '
+        'eager dispatch), before the first program dispatch',
     'fused.epoch':
         'fused epoch drivers: one whole run() call',
     'fused.dispatch':

@@ -28,8 +28,16 @@ channels inject the sender's context on ``send`` and strip it on
 work to the producer's trace (``producer_trace`` / ``producer_span``
 fields on the consumer's spans).
 
-Cost when the recorder is OFF: one context-manager allocation and one
-attribute check per ``span()`` block — safe for hot host paths.
+Every span is ALSO a `jax.profiler.TraceAnnotation` of its name,
+recorder on or off: inside a profiler session the span lands on the
+host plane, on the clock the device ops are on, so an idle gap of the
+device reads as the span the host was in (fields stay with the
+recorder; the annotation carries the name only).
+
+Cost when the recorder is OFF and no profiler session is open: one
+context-manager allocation, one attribute check and one inactive
+TraceAnnotation (a few hundred nanoseconds) per ``span()`` block —
+safe for hot host paths.
 """
 from __future__ import annotations
 
@@ -38,6 +46,8 @@ import json
 import os
 import time
 from typing import NamedTuple, Optional
+
+import jax
 
 from .recorder import recorder
 
@@ -84,12 +94,13 @@ class span:
   extracted from a channel message); extra keyword fields land on both
   the begin and end events (names colliding with the span machinery's
   own fields — `_RESERVED` — are suffixed with ``_``).  When the
-  flight recorder is off the whole block is a no-op (one attribute
-  check).  The yielded value is the span's `SpanContext` (None when
-  disabled).
+  flight recorder is off the block only annotates the profiler's
+  trace.  The yielded value is the span's `SpanContext` (None when
+  the recorder is off).
   """
 
-  __slots__ = ('kind', 'fields', 'parent', 'ctx', '_token', '_t0')
+  __slots__ = ('kind', 'fields', 'parent', 'ctx', '_token', '_t0',
+               '_annot')
 
   def __init__(self, kind: str, parent: Optional[SpanContext] = None,
                **fields):
@@ -99,16 +110,19 @@ class span:
     self.ctx = None
     self._token = None
     self._t0 = 0.0
+    self._annot = None
 
   def __enter__(self) -> Optional[SpanContext]:
-    if self.ctx is not None:
+    if self._annot is not None:
       # re-entrant reuse of ONE instance would clobber _token and
       # leak the contextvar on exit, phantom-parenting every later
-      # span on the thread; sequential reuse (ctx reset by __exit__)
-      # stays fine
+      # span on the thread (and leave the first annotation open);
+      # sequential reuse (reset by __exit__) stays fine
       raise RuntimeError(
           'span instance re-entered while open — construct a new '
           'span() for each nested block')
+    self._annot = jax.profiler.TraceAnnotation(self.kind)
+    self._annot.__enter__()
     if not recorder.enabled:
       return None
     # field normalization only on the enabled path — recorder-off cost
@@ -131,6 +145,9 @@ class span:
     return self.ctx
 
   def __exit__(self, exc_type, exc, tb) -> bool:
+    annot, self._annot = self._annot, None
+    if annot is not None:
+      annot.__exit__(exc_type, exc, tb)
     if self.ctx is None:
       return False
     dt = time.monotonic() - self._t0

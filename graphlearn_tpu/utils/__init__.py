@@ -3,8 +3,8 @@ from .common import (format_hetero_sampler_output,
 from .mixin import CastMixin
 from .padding import (INVALID_ID, bucket_size, max_sampled_edges,
                       max_sampled_nodes, next_power_of_two, pad_1d, round_up)
-from .profiling import (Metrics, capture, metrics, start_trace,
-                        step_annotation, stop_trace, trace)
+from .profiling import (LAYERS, Metrics, capture, layer_scope, metrics,
+                        start_trace, step_annotation, stop_trace)
 from .tensor import convert_to_array, id2idx, to_device, to_host
 
 

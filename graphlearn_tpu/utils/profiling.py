@@ -3,9 +3,10 @@
 The reference has NO tracing/profiling subsystem (SURVEY §5: wall-clock
 prints in benchmarks only) — this module is deliberately beyond parity:
 
-  * :func:`trace` — context manager emitting a `jax.profiler`
-    TraceAnnotation (visible in xprof/tensorboard timelines) and
-    feeding the wall-clock metrics registry;
+  * :func:`layer_scope` — the package's one named scope:
+    ``glt.<layer>[/<part>]`` on every device op a jitted program
+    traces under it, so a profiler trace of the timed program reads
+    by layer (:data:`LAYERS` is the whole vocabulary);
   * :func:`start_trace` / :func:`stop_trace` — capture an xprof trace
     directory viewable in TensorBoard's profile plugin;
   * :class:`Metrics` — process-local counters/timers the loaders and
@@ -18,7 +19,7 @@ import contextlib
 import json
 import threading
 import time
-from typing import Dict, Iterator, Optional
+from typing import Dict, Iterator, Optional, Tuple
 
 import jax
 
@@ -79,14 +80,23 @@ class Metrics:
 metrics = Metrics()
 
 
-@contextlib.contextmanager
-def trace(name: str, registry: Optional[Metrics] = None) -> Iterator[None]:
-  """Annotate a host-side region: shows up on the xprof timeline AND
-  accumulates wall-clock in the metrics registry."""
-  reg = registry if registry is not None else metrics
-  with jax.profiler.TraceAnnotation(name):
-    with reg.timer(name):
-      yield
+#: every layer a device op can belong to.  A trace is reduced by the
+#: first ``glt.<layer>`` token of an op's ``op_name`` (bare, or inside
+#: ``jvp(...)`` / ``transpose(jvp(...))`` for the backward pass), so a
+#: layer that is not here cannot be read back: `layer_scope` refuses it.
+LAYERS: Tuple[str, ...] = ('sample', 'gather', 'model', 'optimizer',
+                           'exchange')
+
+
+def layer_scope(layer: str, part: Optional[str] = None):
+  """``jax.named_scope('glt.<layer>[/<part>]')``: HLO metadata on the
+  ops traced inside, nothing at run time.  ``part`` is free text
+  (``hop0``, ``level2``, ``dedup``); ``layer`` is one of `LAYERS`."""
+  if layer not in LAYERS:
+    raise ValueError(f'unknown layer {layer!r}; the vocabulary is '
+                     f'{LAYERS}')
+  return jax.named_scope(f'glt.{layer}/{part}' if part
+                         else f'glt.{layer}')
 
 
 def start_trace(log_dir: str) -> None:

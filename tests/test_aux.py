@@ -8,7 +8,8 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from graphlearn_tpu.utils import Checkpointer, Metrics, metrics, trace
+from graphlearn_tpu.telemetry import recorder, span
+from graphlearn_tpu.utils import Checkpointer, Metrics, metrics
 
 
 def test_metrics_counts_and_timers():
@@ -25,11 +26,25 @@ def test_metrics_counts_and_timers():
   assert m.snapshot() == {}
 
 
-def test_trace_annotation_ticks_registry():
-  m = Metrics()
-  with trace('region', registry=m):
+def test_span_annotates_with_recorder_off_and_records_with_it_on(
+    tmp_path):
+  """`span` is the one host-span primitive: recorder off it yields no
+  context and emits nothing; recorder on, the same block leaves one
+  begin/end pair with a monotonic duration."""
+  assert not recorder.enabled
+  with span('region') as ctx:
     jnp.ones(4).block_until_ready()
-  assert m.snapshot()['region.calls'] == 1
+  assert ctx is None
+  recorder.enable(str(tmp_path / 'flight.jsonl'))
+  try:
+    with span('region') as ctx:
+      jnp.ones(4).block_until_ready()
+    events = [e for e in recorder.events() if e.get('name') == 'region']
+  finally:
+    recorder.disable()
+  assert ctx is not None
+  assert [e['kind'] for e in events] == ['span.begin', 'span.end']
+  assert events[1]['dur'] >= 0
 
 
 def test_loader_ticks_global_metrics():

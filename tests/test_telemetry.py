@@ -471,18 +471,26 @@ def test_fused_compile_event_emitted(tmp_path):
 
 def test_channel_stall_recorded(tmp_path):
   from graphlearn_tpu.channel import MpChannel
+  from graphlearn_tpu.channel.base import STALL_SECS
   p = str(tmp_path / 'f.jsonl')
   recorder.enable(p)
   ch = MpChannel()
+  receiving = threading.Event()
   try:
     def produce():
+      # the clock starts when the receiver is about to block, not when
+      # this thread happened to be scheduled: under a loaded machine
+      # the two are far apart
+      assert receiving.wait(timeout=60)
       time.sleep(0.15)
       ch.send({'a': np.arange(3)})
 
     t = threading.Thread(target=produce)
     t.start()
+    receiving.set()
     msg = ch.recv()                     # blocks ~0.15s -> stall
-    t.join()
+    t.join(timeout=60)
+    assert not t.is_alive()
   finally:
     recorder.disable()
     ch.close()
@@ -490,10 +498,10 @@ def test_channel_stall_recorded(tmp_path):
   snap = metrics.snapshot()
   assert snap.get('channel.recv.calls', 0) >= 1
   assert snap.get('channel.recv.stalls', 0) >= 1
-  evs = [json.loads(ln) for ln in open(p).read().splitlines()
-         if json.loads(ln)['kind'] == 'channel.stall']
-  assert evs and evs[0]['op'] == 'recv'
-  assert evs[0]['secs'] >= 0.1
+  # a slow send records a stall of its own, and may do so first
+  evs = [e for e in map(json.loads, open(p).read().splitlines())
+         if e['kind'] == 'channel.stall' and e['op'] == 'recv']
+  assert evs and evs[0]['secs'] > STALL_SECS
 
 
 # -- data satellites --------------------------------------------------------
