@@ -5,6 +5,14 @@ The TPU counterparts of the PyG models the reference's examples train
 `examples/`).  Each model is a flax module whose ``__call__`` takes
 ``(x, edge_index, edge_mask)`` — the `Batch` pytree fields — and
 returns per-node embeddings/logits over the static node table.
+
+A batch that states its sampler's hop layout (``metadata
+['hop_capacities']``, `sampler.neighbor_sampler.hop_capacities`) lets
+a stack of in-edge-local convs compute each layer only over the hops
+that layer feeds (PyG's ``trim_to_layer`` with static shapes); the
+result is then ``[C_0, out]``, the seed rows, instead of the whole
+table.  `models.train._apply_with_weights` is the seam that passes
+the layout on.
 """
 from __future__ import annotations
 
@@ -14,12 +22,42 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from ..telemetry.recorder import recorder
 from ..utils.profiling import layer_scope
 from .conv import GINConv, GATConv, GCNConv, SAGEConv
 
 
+def _layer_extent(hop_capacities, hop: int):
+  """``(rows in, rows out, edge slots)`` of the layer whose outputs
+  feed the nodes within ``hop`` hops of the seeds: it writes ``[0,
+  C_hop)`` from ``[0, C_{hop+1})`` over edge blocks ``0..hop``.  A
+  stack deeper than the sampler (``hop`` past its last) keeps whole
+  tables in its first layers."""
+  node_caps, edge_caps = hop_capacities
+  hops = len(edge_caps)
+  hop = min(hop, hops)
+  return (node_caps[min(hop + 1, hops)], node_caps[hop],
+          edge_caps[min(hop, hops - 1)])
+
+
 class BasicGNN(nn.Module):
-  """L-layer stack: conv → relu → dropout, last layer linear."""
+  """L-layer stack: conv → relu → dropout, last layer linear.
+
+  ``hop_capacities`` — static ``((C_0..C_H), (E_0..E_{H-1}))``, the
+  cumulative node and edge-slot capacities per hop of the batch's
+  sampler — trims the stack when its convs declare ``in_edge_local``
+  (`SAGEConv`; not `GCNConv`, whose normalisation reads the whole
+  subgraph, and not yet `GINConv` / `GATConv`): layer ``l`` of ``L``
+  reads rows ``[0, C_{L-l})`` and edge slots ``[:E_{L-1-l}]`` and
+  writes rows ``[0, C_{L-1-l})``, so a trimmed call returns ``[C_0,
+  out]`` — the seed rows, each valid seed's equal to the untrimmed
+  call's (a padded seed slot may hold a later hop's node, whose row is
+  then partial; loss and accuracy mask those slots) — and one
+  ``model.trim`` flight-recorder event per trace.  A
+  stack of other convs ignores the argument; without it every layer
+  runs over the whole table and the result is ``[n, out]``.  The
+  parameters are the same either way.
+  """
   hidden_features: int
   out_features: int
   num_layers: int = 2
@@ -29,29 +67,55 @@ class BasicGNN(nn.Module):
                                       # the matmuls on the MXU at half
                                       # width; params/outputs stay f32)
 
+  # `__call__` accepts ``hop_capacities`` (whether it then trims is
+  # the convs' say)
+  takes_hop_capacities = True
+
   def make_conv(self, out_features: int, idx: int) -> nn.Module:
     raise NotImplementedError
 
   @nn.compact
   def __call__(self, x, edge_index, edge_mask=None, *,
-               edge_weight=None, train: bool = False):
+               edge_weight=None, hop_capacities=None,
+               train: bool = False):
+    trim = []   # per trimmed layer: (rows in, rows out, edge slots)
     for i in range(self.num_layers):
       last = i == self.num_layers - 1
       out = self.out_features if last else self.hidden_features
       conv = self.make_conv(out, i)
       with layer_scope('model', f'layer{i}'):
+        kwargs = {}
+        if (hop_capacities is not None and hop_capacities[1]
+            and getattr(conv, 'in_edge_local', False)):
+          rows_in, rows_out, slots = _layer_extent(
+              hop_capacities, self.num_layers - 1 - i)
+          trim.append((rows_in, rows_out, slots))
+          x = x[:rows_in]
+          edge_index = edge_index[:, :slots]
+          if edge_mask is not None:
+            edge_mask = edge_mask[:slots]
+          if edge_weight is not None:
+            edge_weight = edge_weight[:slots]
+          kwargs['num_dst'] = rows_out
         if edge_weight is not None:
           # GNS 1/q importance weights (Batch.metadata['edge_weight']):
           # only convs that define an unbiased weighted aggregation
           # accept them (SAGEConv) — passing to others raises loudly
           # rather than silently dropping the correction
-          x = conv(x, edge_index, edge_mask, edge_weight=edge_weight)
-        else:
-          x = conv(x, edge_index, edge_mask)
+          kwargs['edge_weight'] = edge_weight
+        x = conv(x, edge_index, edge_mask, **kwargs)
         if not last:
           x = nn.relu(x)
           if self.dropout > 0:
             x = nn.Dropout(self.dropout, deterministic=not train)(x)
+    if trim and not self.is_initializing():
+      # trace time: one event per compiled program that trims
+      rows_in, rows_out, slots = zip(*trim)
+      recorder.emit('model.trim', layers=len(trim),
+                    rows_in=list(rows_in), rows_out=list(rows_out),
+                    edge_slots=list(slots),
+                    table_rows=hop_capacities[0][-1],
+                    table_slots=hop_capacities[1][-1])
     return x.astype(jnp.float32) if self.dtype is not None else x
 
 

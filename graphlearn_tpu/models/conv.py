@@ -6,8 +6,20 @@ The reference deliberately leaves model compute to PyG
 TPU framework has no PyG to lean on, so the model family lives here —
 designed for the padding contract: edges are ``[2, E]`` local COO with
 -1 masked slots, aggregation is `segment_sum` over static-size node
-tables (XLA lowers this to fused one-hot matmuls / scatter on the MXU;
-no atomics, no dynamic shapes).
+tables (no atomics, no dynamic shapes).  On a v5e XLA lowers it to a
+row gather and a scatter-add that the memory system paces, not the
+MXU: the flagship per-batch step read ``train_step_mfu`` 0.068 % with
+212.9 ms of its 350 ms in these convs (PERF_LEDGER.jsonl, PR 25), so
+what a conv costs is the rows and edge slots it is handed — which is
+why `BasicGNN` hands each layer only the hops that layer feeds.
+
+A conv whose output row depends on that row and its in-edges only
+declares ``in_edge_local = True`` and takes ``num_dst``: the bipartite
+form, messages gathered from all ``n_src`` input rows and aggregated
+into the first ``num_dst`` of them.  `SAGEConv` does.  `GCNConv` may
+not (its normalisation counts a source's out-edges over the whole
+subgraph); `GINConv` / `GATConv` could and have not been given the
+form yet.
 
 Edge direction follows the loader's transposed emission
 (reference `sampler/neighbor_sampler.py:159-166`): ``edge_index[0]`` is
@@ -107,22 +119,33 @@ class SAGEConv(nn.Module):
   just the estimator (mean: weighted numerator over valid-count
   denominator; sum: weighted sum).  None = the unweighted path,
   bit-identical to before.
+
+  ``num_dst`` selects the bipartite form: sources are all ``n_src``
+  rows of ``x``, targets its first ``num_dst`` rows (every valid
+  ``edge_index[1] < num_dst``), and the result is ``[num_dst, out]``
+  — each row what the square form gives it over the same edges.  None
+  is the square form, ``[n_src, out]``.  The parameters are the same
+  either way.
   """
   out_features: int
   use_bias: bool = True
   aggr: str = 'mean'
   dtype: Optional[jnp.dtype] = None   # compute dtype (e.g. bfloat16
                                       # for the MXU); params stay f32
+  # an output row reads its own row and its in-edges, nothing else
+  in_edge_local = True
 
   @nn.compact
   def __call__(self, x: jax.Array, edge_index: jax.Array,
                edge_mask: Optional[jax.Array] = None,
-               edge_weight: Optional[jax.Array] = None) -> jax.Array:
+               edge_weight: Optional[jax.Array] = None,
+               num_dst: Optional[int] = None) -> jax.Array:
     if self.dtype is not None:
       x = x.astype(self.dtype)
-    n = x.shape[0]
+    n_src = x.shape[0]
+    n = n_src if num_dst is None else num_dst
     src, dst = edge_index[0], edge_index[1]
-    msg = x[jnp.clip(src, 0, n - 1)]
+    msg = x[jnp.clip(src, 0, n_src - 1)]
     if self.aggr == 'mean':
       agg = segment_mean(msg, dst, n, edge_mask, weights=edge_weight)
     elif self.aggr == 'max':
@@ -139,7 +162,8 @@ class SAGEConv(nn.Module):
     else:
       raise ValueError(f'Unknown aggr {self.aggr!r}')
     out = (nn.Dense(self.out_features, use_bias=self.use_bias,
-                    dtype=self.dtype, name='lin_self')(x)
+                    dtype=self.dtype, name='lin_self')(
+                        x if num_dst is None else x[:num_dst])
            + nn.Dense(self.out_features, use_bias=False,
                       dtype=self.dtype, name='lin_neigh')(agg))
     return out

@@ -77,17 +77,32 @@ def make_extracted_supervised_step(extract: Callable,
 
 
 def _apply_with_weights(apply_fn, params, batch):
-  """One definition of "apply the model to a Batch": when the sampler
-  attached GNS 1/q importance weights (``metadata['edge_weight']``,
-  PR 10), thread them into the aggregation so biased sampling stays
-  unbiased at the model (the presence check is static per pytree
-  structure — no retrace churn)."""
+  """One definition of "apply the model to a Batch": what the sampler
+  stated about the batch in its metadata goes on to the model (the
+  presence checks are static per pytree structure — no retrace churn).
+
+  * ``edge_weight``, the GNS 1/q importance weights (PR 10), threads
+    into the aggregation so biased sampling stays unbiased at the
+    model.
+  * ``hop_capacities``, the static hop layout `NeighborSampler` states
+    (`sampler.neighbor_sampler.hop_capacities`), goes to a model that
+    declares ``takes_hop_capacities`` (`BasicGNN`, which trims each
+    layer to the hops it feeds when its convs allow it); the logits
+    are then the seed rows ``[C_0, out]``, which is all the loss and
+    the accuracy read.  Any other ``apply_fn`` and any batch without
+    the entry get the whole table, as before.
+  """
   md = getattr(batch, 'metadata', None) or {}
-  ew = md.get('edge_weight') if isinstance(md, dict) else None
-  if ew is not None:
-    return apply_fn(params, batch.x, batch.edge_index, batch.edge_mask,
-                    edge_weight=ew)
-  return apply_fn(params, batch.x, batch.edge_index, batch.edge_mask)
+  kwargs = {}
+  if isinstance(md, dict):
+    if md.get('edge_weight') is not None:
+      kwargs['edge_weight'] = md['edge_weight']
+    model = getattr(apply_fn, '__self__', None)
+    if (md.get('hop_capacities') is not None
+        and getattr(model, 'takes_hop_capacities', False)):
+      kwargs['hop_capacities'] = md['hop_capacities']
+  return apply_fn(params, batch.x, batch.edge_index, batch.edge_mask,
+                  **kwargs)
 
 
 def make_supervised_step(apply_fn, tx: optax.GradientTransformation,

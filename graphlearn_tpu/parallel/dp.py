@@ -86,7 +86,9 @@ def make_dp_supervised_step(apply_fn: Callable,
       from ..models.train import _apply_with_weights
       # the example SAGE path: GNS batches carry metadata
       # ['edge_weight'] (PR 10 1/q weights) — threaded into the
-      # aggregation so GNS-on DP training is unbiased at the model
+      # aggregation so GNS-on DP training is unbiased at the model;
+      # stacked `NeighborLoader` batches carry ['hop_capacities'], and
+      # each device trims its own layers to the hops they feed
       logits = _apply_with_weights(apply_fn, params, batch)
       loss = supervised_loss(logits, batch.y, batch.batch, batch_size)
       return loss, logits

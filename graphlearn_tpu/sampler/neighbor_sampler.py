@@ -42,6 +42,36 @@ from .base import (BaseSampler, EdgeSamplerInput, NegativeSampling,
                    NodeSamplerInput, SamplerOutput)
 
 
+def hop_capacities(batch_size: int, fanouts: Sequence[int],
+                   node_cap: int) -> Tuple[Tuple[int, ...], Tuple[int, ...]]:
+  """The static layout of one `_multihop_sample` output:
+  ``((C_0..C_L), (E_0..E_{L-1}))``, cumulative node and edge-slot
+  capacities per hop.
+
+  Hop ``h`` appends its new nodes to table slots ``[count_{h-1},
+  count_h)`` with ``count_h <= C_h``, and emits its edges as block
+  ``[E_{h-1}, E_h)`` of ``row``/``col``: targets are that hop's
+  frontier (``col < C_h``), sources anything found so far
+  (``row < C_{h+1}``), and a node's sampled in-edges all sit in the
+  block of the hop that discovered it.  So everything within ``h``
+  hops of the seeds lives in the prefixes ``[0, C_h)`` / ``[:E_h]``
+  (what `models.BasicGNN` trims its layers to).  ``C_L`` is the
+  table's shape ``node_cap``, ``E_{L-1}`` the shape of ``row``.
+  """
+  f_cap = int(batch_size)
+  cap, edges = min(f_cap, node_cap), 0
+  node_caps, edge_caps = [cap], []
+  for k in fanouts:
+    f_cap *= int(k)
+    edges += f_cap
+    cap = min(cap + f_cap, node_cap)
+    node_caps.append(cap)
+    edge_caps.append(edges)
+  if edge_caps:
+    node_caps[-1] = node_cap
+  return tuple(node_caps), tuple(edge_caps)
+
+
 @functools.partial(
     jax.jit,
     static_argnames=('fanouts', 'node_cap', 'with_edge', 'sort_locality',
@@ -71,8 +101,9 @@ def _multihop_sample(
   # hop, so an early hop carrying the full multi-hop capacity (~60x
   # the live entries at hop 1 for fanout [15,10,5]) triples the total
   # sort work for nothing.  Capacities are static per hop; the state
-  # pads up right before each hop's insertion.
-  cap = min(b, node_cap)
+  # pads up right before each hop's insertion (`hop_capacities`).
+  node_caps, _ = hop_capacities(b, fanouts, node_cap)
+  cap = node_caps[0]
   with layer_scope('sample', 'dedup'):
     state, seed_local = init_node(seeds, cap)
 
@@ -101,7 +132,7 @@ def _multihop_sample(
           table=((win_table, win_e) if win_table is not None else None),
           use_fused=use_fused)
     with layer_scope('sample', 'dedup'):
-      new_cap = min(cap + f_cap * int(k), node_cap)
+      new_cap = node_caps[i + 1]
       if new_cap > cap:
         state = InducerState(
             nodes=jnp.concatenate([
@@ -256,7 +287,11 @@ class NeighborSampler(BaseSampler):
         node=nodes, node_count=count, row=row, col=col, edge=edge,
         edge_mask=emask, batch=seeds,
         num_sampled_nodes=nsn, num_sampled_edges=nse,
-        metadata={'seed_local': seed_local})
+        # static ints, the same for every batch of a loader: `Batch`
+        # carries them as pytree aux data, not as arrays
+        metadata={'seed_local': seed_local,
+                  'hop_capacities': hop_capacities(
+                      b, self.num_neighbors, node_cap)})
 
   # -- link sampling --------------------------------------------------------
 

@@ -58,6 +58,11 @@ EVENT_KINDS: Dict[str, str] = {
         "overlay's admissions (CLOCK second-chance victims)",
     'fused.compile':
         'loader.fused._counted_jit: fn, secs',
+    'model.trim':
+        'models.BasicGNN at trace time, once per compiled program '
+        'that trims its layers to the hops they feed: layers, and per '
+        'layer rows_in, rows_out, edge_slots computed, beside the '
+        "batch's table_rows and table_slots (absent = untrimmed)",
     'span.begin':
         'telemetry.spans: name, trace_id, span_id, parent_id, pid, '
         'tid (+caller fields)',

@@ -295,3 +295,284 @@ def test_gin_and_gatv2_convs_mask_and_learn():
     correct += int(c)
     total += int(t)
   assert correct / total > 0.8, correct / total
+
+
+# -- hop-prefix trimming (BasicGNN.hop_capacities) ---------------------------
+
+def _skewed_dataset(n=400, d=12, classes=5, seed=0):
+  """Half of all edges point at eight hubs: dedup packs most of a hop's
+  draws into few slots, so nodes of later hops sit inside the earlier
+  hops' static prefixes."""
+  rng = np.random.default_rng(seed)
+  e = n * 8
+  rows = rng.integers(0, n, e)
+  cols = rng.integers(0, n, e)
+  cols[:e // 2] = rng.integers(0, 8, e // 2)
+  return (Dataset()
+          .init_graph((rows, cols), layout='COO', num_nodes=n)
+          .init_node_features(
+              rng.normal(size=(n, d)).astype(np.float32), split_ratio=1.0)
+          .init_node_labels(rng.integers(0, classes, n).astype(np.int32)))
+
+
+def _without_capacities(batch):
+  """The same batch as a loader that states no layout would hand it."""
+  from graphlearn_tpu.loader.transform import Batch
+  md = {k: v for k, v in batch.metadata.items() if k != 'hop_capacities'}
+  return Batch(batch.x, batch.y, batch.edge_index, batch.edge_attr,
+               batch.node, batch.node_mask, batch.edge_mask, batch.edge,
+               batch.batch, batch.batch_size, batch.num_sampled_nodes,
+               batch.num_sampled_edges, md)
+
+
+_TRIM_CASES = {
+    # name: (nodes, fanouts, layers, aggr, batch, which batch, weights)
+    'f44-mean': (400, [4, 4], 2, 'mean', 16, 'first', False),
+    'f44-sum': (400, [4, 4], 2, 'sum', 16, 'first', False),
+    'f44-max': (400, [4, 4], 2, 'max', 16, 'first', False),
+    'f543-mean': (400, [5, 4, 3], 3, 'mean', 32, 'first', False),
+    'f543-sum': (400, [5, 4, 3], 3, 'sum', 32, 'first', False),
+    'f543-max': (400, [5, 4, 3], 3, 'max', 32, 'first', False),
+    # 16 + 24 < 16 + 80 + 320 + 960: node_capacity clamps every hop
+    'clamped-table': (24, [5, 4, 3], 3, 'mean', 16, 'first', False),
+    # 100 seeds in batches of 32: the last has 4 seeds and 28 pads
+    'short-last-batch': (400, [5, 4, 3], 3, 'mean', 32, 'last', False),
+    'gns-edge-weight-mean': (400, [5, 4, 3], 3, 'mean', 32, 'first', True),
+    'gns-edge-weight-sum': (400, [4, 4], 2, 'sum', 16, 'first', True),
+    # a stack shallower / deeper than the sampler
+    'two-layers-three-hops': (400, [5, 4, 3], 2, 'mean', 32, 'first', False),
+    'three-layers-two-hops': (400, [4, 4], 3, 'mean', 16, 'first', False),
+}
+
+
+@pytest.mark.parametrize('case', sorted(_TRIM_CASES))
+def test_trimmed_sage_matches_untrimmed(case):
+  """Each layer computed only over the hops it feeds gives the seeds
+  the logits, the loss and the gradients of the whole-table stack."""
+  from graphlearn_tpu.models.train import (_apply_with_weights,
+                                           supervised_loss)
+  n, fanouts, layers, aggr, bs, which, weighted = _TRIM_CASES[case]
+  loader = NeighborLoader(_skewed_dataset(n), fanouts, np.arange(min(n, 100)),
+                          batch_size=bs, shuffle=True, seed=1)
+  batches = list(loader)
+  batch = batches[0] if which == 'first' else batches[-1]
+  valid = np.asarray(batch.batch) >= 0
+  assert valid.all() == (which == 'first')
+  node_caps, edge_caps = batch.metadata['hop_capacities']
+  counts = np.cumsum(np.asarray(batch.num_sampled_nodes))
+  # later hops did land inside earlier prefixes (what trimming must
+  # survive), and the small graph did clamp
+  assert (counts[1:-1] < np.asarray(node_caps[1:-1])).any()
+  if case == 'clamped-table':
+    assert node_caps[1] == node_caps[-1] == batch.x.shape[0]
+  if weighted:
+    rng = np.random.default_rng(5)
+    batch.metadata['edge_weight'] = jnp.asarray(
+        rng.uniform(0.5, 2.0, batch.edge_index.shape[1]), jnp.float32)
+  full = _without_capacities(batch)
+  model = GraphSAGE(hidden_features=16, out_features=5, num_layers=layers,
+                    aggr=aggr)
+  params = model.init(jax.random.key(0), batch.x, batch.edge_index,
+                      batch.edge_mask)
+
+  def loss_fn(p, b):
+    logits = _apply_with_weights(model.apply, p, b)
+    return supervised_loss(logits, b.y, b.batch, bs), logits
+
+  (loss_t, logits_t), grads_t = jax.value_and_grad(
+      loss_fn, has_aux=True)(params, batch)
+  (loss_f, logits_f), grads_f = jax.value_and_grad(
+      loss_fn, has_aux=True)(params, full)
+  assert logits_f.shape == (batch.x.shape[0], 5)
+  assert logits_t.shape == (node_caps[0], 5) == (bs, 5)
+  np.testing.assert_array_equal(np.asarray(logits_t)[valid],
+                                np.asarray(logits_f)[:bs][valid])
+  np.testing.assert_allclose(float(loss_t), float(loss_f), rtol=1e-5)
+  flat_t = jax.tree_util.tree_leaves_with_path(grads_t)
+  flat_f = jax.tree_util.tree_leaves_with_path(grads_f)
+  assert [p for p, _ in flat_t] == [p for p, _ in flat_f]
+  for (_, gt), (_, gf) in zip(flat_t, flat_f):
+    scale = max(float(jnp.abs(gf).max()), 1e-6)
+    np.testing.assert_allclose(np.asarray(gt), np.asarray(gf),
+                               atol=1e-5 * scale)
+
+
+def test_trimmed_step_one_compile_same_losses():
+  """An epoch through `make_supervised_step`: the static capacities are
+  the same on every batch (the short last one too), so one program;
+  and it trains as the whole-table step does."""
+  bs = 32
+  loader = NeighborLoader(_skewed_dataset(), [5, 4, 3], np.arange(100),
+                          batch_size=bs, shuffle=True, seed=2)
+  model = GraphSAGE(hidden_features=16, out_features=5, num_layers=3)
+  tx = optax.adam(1e-2)
+  batches = list(loader)
+  assert len(batches) == 4
+  state_t, apply_fn = create_train_state(model, jax.random.key(0),
+                                         batches[0], tx)
+  # committed like the loader's batches, so the state a step returns
+  # has the avals of the state it was given
+  state_t = state_f = jax.device_put(state_t, jax.devices()[0])
+  step_t = make_supervised_step(apply_fn, tx, bs)
+  step_f = make_supervised_step(apply_fn, tx, bs)
+  for batch in batches:
+    state_t, loss_t, correct_t = step_t(state_t, batch)
+    state_f, loss_f, correct_f = step_f(state_f, _without_capacities(batch))
+    np.testing.assert_allclose(float(loss_t), float(loss_f), rtol=1e-5)
+    assert int(correct_t) == int(correct_f)
+  assert step_t._cache_size() == 1 and step_f._cache_size() == 1
+  jax.tree_util.tree_map(
+      lambda a, b: np.testing.assert_allclose(
+          np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-6),
+      state_t.params, state_f.params)
+  eval_step = make_eval_step(apply_fn, bs)
+  assert ([int(v) for v in eval_step(state_t.params, batches[-1])]
+          == [int(v) for v in eval_step(
+              state_t.params, _without_capacities(batches[-1]))])
+
+
+def _trim_events(fn, *args, **kwargs):
+  """`model.trim` events of tracing ``fn`` (nothing is computed)."""
+  from graphlearn_tpu.telemetry.recorder import recorder
+  recorder.enable()
+  recorder.clear()
+  try:
+    jax.eval_shape(fn, *args, **kwargs)
+    return recorder.events('model.trim')
+  finally:
+    recorder.disable()
+    recorder.clear()
+
+
+def _flagship_shapes():
+  from graphlearn_tpu.sampler.neighbor_sampler import hop_capacities
+  caps = hop_capacities(1024, (15, 10, 5), 937984)
+  f32, i32 = jnp.float32, jnp.int32
+  return caps, (jax.ShapeDtypeStruct((caps[0][-1], 100), f32),
+                jax.ShapeDtypeStruct((2, caps[1][-1]), i32),
+                jax.ShapeDtypeStruct((caps[1][-1],), jnp.bool_))
+
+
+def test_trim_record_names_rows_and_slots_per_layer():
+  """The trace-time record of the flagship shape (batch 1024, fanout
+  [15, 10, 5]): what each layer computes over, beside the table."""
+  caps, (x, ei, em) = _flagship_shapes()
+  assert caps == ((1024, 16384, 169984, 937984), (15360, 168960, 936960))
+  model = GraphSAGE(hidden_features=256, out_features=47, num_layers=3)
+  params = jax.eval_shape(model.init, jax.random.key(0), x, ei, em)
+  events = _trim_events(
+      lambda p, *a: model.apply(p, *a, hop_capacities=caps), params,
+      x, ei, em)
+  assert len(events) == 1
+  ev = events[0]
+  assert ev['layers'] == 3
+  assert ev['rows_in'] == [937984, 169984, 16384]
+  assert ev['rows_out'] == [169984, 16384, 1024]
+  assert ev['edge_slots'] == [936960, 168960, 15360]
+  assert (ev['table_rows'], ev['table_slots']) == (937984, 936960)
+
+
+@pytest.mark.parametrize('who', ['sage-no-capacities', 'gcn-declines',
+                                 'init-is-not-a-step'])
+def test_trim_record_absent_on_an_untrimmed_trace(who):
+  caps, (x, ei, em) = _flagship_shapes()
+  cls = GCN if who == 'gcn-declines' else GraphSAGE
+  model = cls(hidden_features=256, out_features=47, num_layers=3)
+  kwargs = {} if who == 'sage-no-capacities' else {'hop_capacities': caps}
+  if who == 'init-is-not-a-step':
+    assert not _trim_events(
+        lambda *a: model.init(jax.random.key(0), *a, **kwargs), x, ei, em)
+    return
+  params = jax.eval_shape(model.init, jax.random.key(0), x, ei, em)
+  out = jax.eval_shape(lambda p, *a: model.apply(p, *a, **kwargs),
+                       params, x, ei, em)
+  assert out.shape == (937984, 47)
+  assert not _trim_events(lambda p, *a: model.apply(p, *a, **kwargs),
+                          params, x, ei, em)
+
+
+def test_gcn_declines_the_capacities():
+  """GCN's normalisation counts a source's out-edges over the whole
+  subgraph: it is handed the capacities by the seam and computes what
+  it computes without them, over the whole table."""
+  from graphlearn_tpu.models.train import _apply_with_weights
+  loader = NeighborLoader(_skewed_dataset(), [5, 4, 3], np.arange(100),
+                          batch_size=32, shuffle=True, seed=1)
+  batch = next(iter(loader))
+  assert 'hop_capacities' in batch.metadata
+  model = GCN(hidden_features=16, out_features=5, num_layers=3)
+  params = model.init(jax.random.key(0), batch.x, batch.edge_index,
+                      batch.edge_mask)
+  stated = _apply_with_weights(model.apply, params, batch)
+  plain = model.apply(params, batch.x, batch.edge_index, batch.edge_mask)
+  assert stated.shape == (batch.x.shape[0], 5)
+  np.testing.assert_array_equal(np.asarray(stated), np.asarray(plain))
+
+
+@pytest.mark.parametrize('source', ['hand-built', 'dist-loader',
+                                    'link-loader', 'plain-apply-fn'])
+def test_batches_without_capacities_run_the_whole_table(source):
+  """Only `NeighborSampler.sample_from_nodes` states the layout; every
+  other batch, and every ``apply_fn`` that is not a model's own
+  ``apply``, gets the whole-table stack."""
+  from graphlearn_tpu.loader.transform import Batch
+  from graphlearn_tpu.models.train import _apply_with_weights
+  model = GraphSAGE(hidden_features=8, out_features=5, num_layers=2)
+  apply_fn = model.apply
+  if source == 'hand-built':
+    rng = np.random.default_rng(0)
+    ei = jnp.asarray(rng.integers(0, 20, (2, 50)), jnp.int32)
+    batch = Batch(x=jnp.asarray(rng.normal(size=(20, 4)), jnp.float32),
+                  edge_index=ei, edge_mask=jnp.ones((50,), bool),
+                  batch=jnp.arange(4), batch_size=4)
+  elif source == 'dist-loader':
+    from graphlearn_tpu.parallel import (DistDataset, DistNeighborLoader,
+                                         make_mesh)
+    n = 64
+    rows = np.concatenate([np.arange(n), np.arange(n)])
+    cols = np.concatenate([(np.arange(n) + 1) % n, (np.arange(n) + 2) % n])
+    ds = DistDataset.from_full_graph(
+        4, rows, cols, node_feat=np.ones((n, 4), np.float32),
+        node_label=(np.arange(n) % 5).astype(np.int32), num_nodes=n)
+    stacked = next(iter(DistNeighborLoader(
+        ds, [2, 2], np.arange(n), batch_size=4, mesh=make_mesh(4), seed=0)))
+    batch = jax.tree_util.tree_map(lambda v: v[0], stacked)
+  elif source == 'link-loader':
+    from graphlearn_tpu.loader import LinkNeighborLoader
+    rng = np.random.default_rng(0)
+    pairs = np.stack([rng.integers(0, 400, 32), rng.integers(0, 400, 32)])
+    batch = next(iter(LinkNeighborLoader(_skewed_dataset(), [4, 4], pairs,
+                                         batch_size=16)))
+  else:
+    batch = next(iter(NeighborLoader(_skewed_dataset(), [4, 4],
+                                     np.arange(64), batch_size=16)))
+    assert 'hop_capacities' in batch.metadata
+    apply_fn = lambda p, *a, **kw: model.apply(p, *a, **kw)  # noqa: E731
+  if source != 'plain-apply-fn':
+    assert 'hop_capacities' not in batch.metadata
+  params = model.init(jax.random.key(0), batch.x, batch.edge_index,
+                      batch.edge_mask)
+  out = _apply_with_weights(apply_fn, params, batch)
+  assert out.shape == (batch.x.shape[0], 5)
+  np.testing.assert_array_equal(
+      np.asarray(out),
+      np.asarray(model.apply(params, batch.x, batch.edge_index,
+                             batch.edge_mask)))
+
+
+def test_init_with_capacities_yields_the_same_parameter_tree():
+  batch = next(iter(NeighborLoader(_skewed_dataset(), [5, 4, 3],
+                                   np.arange(64), batch_size=16)))
+  model = GraphSAGE(hidden_features=16, out_features=5, num_layers=3)
+  args = (jax.random.key(0), batch.x, batch.edge_index, batch.edge_mask)
+  plain = model.init(*args)
+  stated = model.init(*args,
+                      hop_capacities=batch.metadata['hop_capacities'])
+  assert (jax.tree_util.tree_structure(plain)
+          == jax.tree_util.tree_structure(stated))
+  assert sorted(plain['params']) == ['conv0', 'conv1', 'conv2']
+  assert sorted(plain['params']['conv0']) == ['lin_neigh', 'lin_self']
+  jax.tree_util.tree_map(
+      lambda a, b: np.testing.assert_array_equal(np.asarray(a),
+                                                 np.asarray(b)),
+      plain, stated)

@@ -184,3 +184,61 @@ def test_sample_prob(graph):
   # nodes 1..4 are reachable within 2 hops of node 0; far nodes are not
   assert (prob[1:5] > 0).all()
   assert (prob[10:30] == 0).all()
+
+
+def _skewed_graph(n, seed=0):
+  """Half of all edges point at eight hubs, so dedup packs most of a
+  hop's draws into a few slots (later hops land in earlier prefixes)."""
+  rng = np.random.default_rng(seed)
+  e = n * 8
+  rows = rng.integers(0, n, e)
+  cols = rng.integers(0, n, e)
+  cols[:e // 2] = rng.integers(0, 8, e // 2)
+  return Graph(CSRTopo((rows, cols), layout='COO', num_nodes=n),
+               mode='device')
+
+
+@pytest.mark.parametrize('n,fanouts,batch,clamps', [
+    (400, [4, 4], 16, False),
+    (400, [5, 4, 3], 32, True),     # the last hop clamps: 32 + 400
+    (24, [5, 4, 3], 16, True),      # every hop clamps: 16 + 24
+])
+def test_hop_prefix_layout(n, fanouts, batch, clamps):
+  """The static layout `models.BasicGNN` trims by: hop ``h``'s nodes
+  end inside ``[0, C_h)``, its edges are block ``h`` with targets below
+  ``C_h`` and sources below ``C_{h+1}``, and every in-edge of a node
+  sits in the block of the hop that discovered it."""
+  from graphlearn_tpu.sampler.neighbor_sampler import hop_capacities
+  sampler = NeighborSampler(_skewed_graph(n), fanouts, seed=3)
+  rng = np.random.default_rng(1)
+  clamped = packed = False
+  for trial in range(4):
+    seeds = rng.choice(n, batch, replace=False).astype(np.int32)
+    if trial == 3:
+      seeds[batch // 2:] = -1           # a short last batch
+    out = sampler.sample_from_nodes(NodeSamplerInput(node=seeds))
+    node_caps, edge_caps = out.metadata['hop_capacities']
+    assert (node_caps, edge_caps) == hop_capacities(
+        batch, fanouts, sampler.node_capacity(batch))
+    # the stated capacities are the arrays' shapes
+    assert node_caps[-1] == out.node.shape[0]
+    assert edge_caps[-1] == out.row.shape[0] == out.col.shape[0]
+    assert len(node_caps) == len(fanouts) + 1
+    assert len(edge_caps) == len(fanouts)
+    clamped |= node_caps[-1] < batch + sum(
+        batch * int(np.prod(fanouts[:i + 1])) for i in range(len(fanouts)))
+    counts = np.cumsum(np.asarray(out.num_sampled_nodes))
+    assert (counts <= np.asarray(node_caps)).all()
+    packed |= bool((counts[1:-1] < np.asarray(node_caps[1:-1])).any())
+    # the hop that discovered each local slot
+    found_at = np.searchsorted(counts, np.arange(counts[-1]), 'right')
+    row, col = np.asarray(out.row), np.asarray(out.col)
+    mask = np.asarray(out.edge_mask)
+    starts = (0,) + edge_caps[:-1]
+    for h, (lo, hi) in enumerate(zip(starts, edge_caps)):
+      r, c = row[lo:hi][mask[lo:hi]], col[lo:hi][mask[lo:hi]]
+      assert (c < node_caps[h]).all() and (r < node_caps[h + 1]).all()
+      # targets of block h are exactly the nodes found at hop h
+      assert (found_at[c] == h).all()
+  assert packed                         # the case the prefixes must survive
+  assert clamped == clamps
