@@ -10,6 +10,10 @@ computes a metric; `chipbench.run` does.
   loader      `NeighborLoader` -> `GraphSAGE` -> `make_supervised_step`
   mesh_fused  `FusedDistTreeEpoch.run` on a 4-device mesh
 
+A configuration whose model, graph or path is none of these brings a
+driver of its own as a new file (`make`), held to the same protocol
+(`_Driver`).
+
 The fused programs keep their trees inside the scan, so `first_steps`
 feeds the compiled ``[steps, B]`` program one, then two, valid batches
 (the rest of the dispatch is padding, which the program treats as
@@ -19,11 +23,12 @@ program computed, ties the two together (`PERF.md`, "correct").
 """
 from __future__ import annotations
 
+import os
 import time
 
 import numpy as np
 
-from . import build, reference
+from . import build, load_file, reference
 
 
 def _annot(name):
@@ -55,7 +60,48 @@ def _host_layers(kind, state):
 
 
 class _Driver:
-  """What every driver knows of its configuration and its mix."""
+  """The protocol `chipbench.run` and `chipbench.limits` hold a driver
+  to; they read nothing else of one.  The methods below carry the
+  GraphSAGE cells' answers as defaults; a configuration's own driver
+  (`make`) overrides what differs and may subclass this or not.
+
+  Built as ``cls(cfg, traffic, seed, model_dtype=None, data=None)``:
+  data and weights from the seed, ONE program object with its state.
+  ``model_dtype`` switches on the model's own lower-precision path and
+  ``data`` is another driver's ``.data`` (the same seed's tables, made
+  once); only `limits` passes either.
+
+  Set-up, in this order:
+    first_steps()      the first three steps through the window's own
+                       call and feed -> ``dict(steps=, prog=)``: what
+                       was drawn (a list over steps of a list over
+                       devices) and the record of what the program
+                       made of it, both the driver's own to read
+    warm()             whatever else the window would compile
+  The window:
+    compile_count()    executables the program holds, before and after
+    exchange_counts()  ``{counter: n}``, before and after ({} here)
+    window(seconds)    -> ``dict(seeds=, failed=, wall_s=, steps=,
+                       ...)``; lists in it (per-step times) reach the
+                       readers and stay off the result line
+  A traced run only:
+    work(steps)        ``{name: count}`` for the readers ({} here)
+    probes()           ``{name: call}``, each timed alone ({} here)
+  The comparison, after the window, in this order:
+    exchange_checks()  exact counts only the live program can give
+    free()             drop the program and its state
+    draw_counts(steps) exact counts of what was drawn against the data
+    follow(steps, **control)
+                       the plain reference following ``steps``, in the
+                       record's form; with a control's keywords, that
+                       control or fault put in the program's place
+    gaps(record, ref)  ``{name: number}``, each held to the limit of
+                       its name in `cells/<cell>.json`
+  `limits` only:
+    data               (see above)
+    controls()         ``{name: follow's keywords}``
+    unchanged(record)  the record of a state that no step moved
+  """
 
   def __init__(self, cfg, traffic, seed, model_dtype):
     self.cfg, self.traffic = cfg, traffic
@@ -64,6 +110,7 @@ class _Driver:
     self.fanout = tuple(cfg['fanout'])
     self.hyper = _hyper(cfg)
     self.model_dtype = model_dtype
+    self._tables = None
 
   def model_kwargs(self):
     kw = dict(hidden_features=self.cfg['hidden'],
@@ -84,6 +131,55 @@ class _Driver:
 
   def exchange_checks(self):
     return {}
+
+  def work(self, steps):
+    del steps
+    return {}
+
+  def probes(self):
+    return {}
+
+  def _held(self):
+    """`tables()`, asked for once: the mesh driver's puts them on the
+    device."""
+    if self._tables is None:
+      self._tables = self.tables()
+    return self._tables
+
+  def draw_counts(self, steps):
+    """Every tree of the first steps against the CSR, which nothing
+    reads after this."""
+    indptr, indices, feats, labels = self._held()
+    self._tables = (None, None, feats, labels)
+    return _tree_draw_counts(steps, indptr, indices, self.fanout)
+
+  def follow(self, steps, rnd=None, half=False, local_only=False):
+    """`reference.follow` from the seed's weights on the seed's table;
+    ``rnd`` rounds every matmul operand (the controls), ``half`` leaves
+    half of each batch out, ``local_only`` the exchange (the faults)."""
+    import jax
+    import jax.numpy as jnp
+    _, _, feats, labels = self._held()
+    steps = jax.tree_util.tree_map(jnp.asarray, steps)
+    return reference.follow(self.kind, self.layers0, steps, feats, labels,
+                            self.hyper, rnd=rnd, half=half,
+                            local_only=local_only)
+
+  def gaps(self, record, ref):
+    return reference.gaps(record, ref)
+
+  def controls(self):
+    """The reference with its matmul operands rounded to bfloat16 and
+    to float8 put in the program's place, and the fault a reference
+    can plant on one chip."""
+    return dict(reference_bfloat16=dict(rnd='bfloat16'),
+                reference_float8_e4m3=dict(rnd='float8_e4m3'),
+                fault_half_batch=dict(half=True))
+
+  def unchanged(self, record):
+    losses, g1, delta = record
+    return (losses, [np.zeros_like(a) for a in g1],
+            [np.zeros_like(a) for a in delta])
 
 
 class _Single(_Driver):
@@ -180,9 +276,6 @@ class FusedDriver(_Single):
         prog=reference.program_record(
             list(loss1) + list(loss23), self.layers0, layers1, mu1,
             layers3, self.hyper))
-
-  def draw_counts(self, steps, indptr, indices):
-    return _tree_draw_counts(steps, indptr, indices, self.fanout)
 
   def warm(self):
     """One whole dispatch over the window's own seed set."""
@@ -298,6 +391,43 @@ def _tree_work(drv, steps):
                                           drv.row_bytes()))
 
 
+def next_batch(drv):
+  """The next batch of ``drv.loader``, epoch after epoch (``drv.it`` is
+  the epoch's iterator)."""
+  try:
+    return next(drv.it)
+  except StopIteration:
+    drv.it = iter(drv.loader)
+    return next(drv.it)
+
+
+def per_batch_window(drv, seconds):
+  """One batch per step (`next_batch`), each step ended by a value
+  pull, until the step in which ``seconds`` passes.
+  ``drv.step(drv.state, batch)`` returns ``(state, loss, ...)`` and
+  every batch trains ``drv.batch`` seeds."""
+  t0 = time.perf_counter()
+  waits, step_s = [], []
+  seeds = failed = 0
+  while True:
+    t1 = time.perf_counter()
+    with _annot('chipbench.next_loader'):
+      b = next_batch(drv)
+    t2 = time.perf_counter()
+    with _annot('chipbench.step'):
+      drv.state, loss, _ = drv.step(drv.state, b)
+      loss = float(loss)
+    t3 = time.perf_counter()
+    failed += int(not np.isfinite(loss))
+    seeds += drv.batch
+    waits.append(t2 - t1)
+    step_s.append(t3 - t1)
+    if t3 - t0 >= seconds:
+      break
+  return dict(seeds=seeds, failed=failed, wall_s=t3 - t0,
+              steps=len(step_s), loader_wait_s=waits, step_s=step_s)
+
+
 class LoaderDriver(_Single):
   kind = 'subgraph'
 
@@ -322,17 +452,10 @@ class LoaderDriver(_Single):
   def compile_count(self):
     return self.step._cache_size()
 
-  def _next(self):
-    try:
-      return next(self.it)
-    except StopIteration:
-      self.it = iter(self.loader)
-      return next(self.it)
-
   def first_steps(self):
     losses, steps, drawn = [], [], []
     for i in range(3):
-      b = self._next()
+      b = next_batch(self)
       shard = dict(seeds=b.batch, node=b.node, src=b.edge_index[0],
                    dst=b.edge_index[1], edge_ok=b.edge_mask)
       # the gathered rows are checked here, while the batch is alive;
@@ -354,8 +477,9 @@ class LoaderDriver(_Single):
         prog=reference.program_record(losses, self.layers0, layers1, mu1,
                                       layers3, self.hyper))
 
-  def draw_counts(self, steps, indptr, indices):
-    del steps, indptr, indices
+  def draw_counts(self, steps):
+    """Counted in `first_steps`, while each batch was alive."""
+    del steps
     bad = {}
     for counts in self._drawn:
       for k, v in counts.items():
@@ -365,7 +489,7 @@ class LoaderDriver(_Single):
   def warm(self):
     """The first steps warmed every program; one more step shows a
     second compile, if there is one, before the window."""
-    self.state, loss, _ = self.step(self.state, self._next())
+    self.state, loss, _ = self.step(self.state, next_batch(self))
     float(loss)
 
   def work(self, steps):
@@ -392,27 +516,7 @@ class LoaderDriver(_Single):
         model=lambda: self.step(state, b)[1])
 
   def window(self, seconds):
-    """One batch per step, each step ended by a value pull."""
-    t0 = time.perf_counter()
-    waits, step_s = [], []
-    seeds = failed = 0
-    while True:
-      t1 = time.perf_counter()
-      with _annot('chipbench.next_loader'):
-        b = self._next()
-      t2 = time.perf_counter()
-      with _annot('chipbench.step'):
-        self.state, loss, _ = self.step(self.state, b)
-        loss = float(loss)
-      t3 = time.perf_counter()
-      failed += int(not np.isfinite(loss))
-      seeds += self.batch
-      waits.append(t2 - t1)
-      step_s.append(t3 - t1)
-      if t3 - t0 >= seconds:
-        break
-    return dict(seeds=seeds, failed=failed, wall_s=t3 - t0,
-                steps=len(step_s), loader_wait_s=waits, step_s=step_s)
+    return per_batch_window(self, seconds)
 
   def free(self):
     self.loader = self.it = self.state = self.ds = self.step = None
@@ -565,9 +669,6 @@ class MeshFusedDriver(_Driver):
               for s in shards] for shards in steps]
     return _tree_work(self, steps)
 
-  def probes(self):
-    return {}
-
   def free(self):
     import gc
     self.epoch = self.state = self.dds = self.mesh = self.data = None
@@ -582,8 +683,9 @@ class MeshFusedDriver(_Driver):
     return (indptr, indices, jnp.asarray(self.feats),
             jnp.asarray(self.labels))
 
-  def draw_counts(self, steps, indptr, indices):
-    return _tree_draw_counts(steps, indptr, indices, self.fanout)
+  def controls(self):
+    return dict(super().controls(),
+                fault_no_exchange=dict(local_only=True))
 
 
 DRIVERS = {('single', 'fused'): FusedDriver,
@@ -591,8 +693,25 @@ DRIVERS = {('single', 'fused'): FusedDriver,
            ('mesh', 'fused'): MeshFusedDriver}
 
 
-def make(cfg, traffic, seed, **kw):
+def make(cfg, traffic, seed, builders_dir=None, **kw):
+  """The driver of a configuration's ``builder`` under a mix's
+  ``driver``: one of `DRIVERS`, or — for a configuration that brings
+  its own — ``DRIVERS[<driver>]`` of `<builders_dir>/<builder>.py`
+  (`chipbench/builders/` of the root that holds `BENCHMARK.json`),
+  which keeps its data builder and its copy of the plain reference
+  beside it (`chipbench.beside`)."""
   key = (cfg['builder'], traffic['driver'])
-  if key not in DRIVERS:
-    raise SystemExit(f'chipbench: no driver for builder/driver {key}')
-  return DRIVERS[key](cfg, traffic, seed, **kw)
+  cls = DRIVERS.get(key)
+  if cls is None:
+    path = os.path.join(
+        builders_dir or os.path.join(os.path.dirname(__file__), 'builders'),
+        key[0] + '.py')
+    if not os.path.exists(path):
+      raise SystemExit(f'chipbench: no driver for builder/driver {key}: '
+                       f'not in drivers.DRIVERS, and no file {path}')
+    found = getattr(load_file(path), 'DRIVERS', {})
+    if key[1] not in found:
+      raise SystemExit(f'chipbench: no driver for builder/driver {key}: '
+                       f'{path} has DRIVERS {sorted(found)}')
+    cls = found[key[1]]
+  return cls(cfg, traffic, seed, **kw)

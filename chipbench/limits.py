@@ -6,10 +6,12 @@ set from (`PERF.md`, "correct"):
 
 For every seed: the program's first three steps against the plain
 reference (the lower reading).  For the first ``--controls`` seeds
-also, on the very same drawn steps: the reference with its matmul
-operands rounded to bfloat16 and to float8_e4m3 put in the program's
-place (the controls), the reference with half of every batch left out
-and the program with its state left unchanged (the faults); and, per
+also, on the very same drawn steps, whatever the cell's driver names
+under `controls()` (`drivers._Driver`) — for the GraphSAGE cells the
+reference with its matmul operands rounded to bfloat16 and to
+float8_e4m3 put in the program's place (the controls) and the
+reference with half of every batch left out (a fault) — and the
+program with its state left unchanged (a fault); and, per
 ``--variants``, the program itself rebuilt under another matmul
 precision (``high``, ``default``) or with its own bfloat16 path
 (``bfloat16``: the model's `dtype=bfloat16`).  No window
@@ -20,6 +22,7 @@ from __future__ import annotations
 
 import argparse
 import contextlib
+import functools
 import json
 import os
 import time
@@ -30,11 +33,11 @@ from . import run
 def read_seed(spec, seed, controls: bool, variants):
   import jax
   import jax.numpy as jnp
-  import numpy as np
-  from . import drivers, reference
-  cfg, traffic = spec['cfg'], spec['traffic']
+  from . import drivers
+  make = functools.partial(drivers.make, spec['cfg'], spec['traffic'],
+                           seed, builders_dir=spec['builders_dir'])
   out = {}
-  drv = drivers.make(cfg, traffic, seed)
+  drv = make()
   data = drv.data
   first = drv.first_steps()
   records = {}
@@ -42,28 +45,21 @@ def read_seed(spec, seed, controls: bool, variants):
     ctx = (contextlib.nullcontext() if v == 'bfloat16'
            else jax.default_matmul_precision(v))
     with ctx:
-      alt = drivers.make(cfg, traffic, seed, data=data,
-                         model_dtype=jnp.bfloat16 if v == 'bfloat16'
-                         else None)
+      alt = make(data=data,
+                 model_dtype=jnp.bfloat16 if v == 'bfloat16' else None)
       records[f'program_{v}'] = alt.first_steps()['prog']
       alt.free()
   del data
-  numbers, follow = run.reference_of(drv, first)
-  ref = follow()
-  out['program'] = dict(numbers, **reference.gaps(first['prog'], ref))
+  numbers = run.exact_counts(drv, first)
+  ref = drv.follow(first['steps'])
+  out['program'] = dict(numbers, **drv.gaps(first['prog'], ref))
   if controls:
     for name, rec in records.items():
-      out[name] = reference.gaps(rec, ref)
-    for rnd in ('bfloat16', 'float8_e4m3'):
-      out[f'reference_{rnd}'] = reference.gaps(follow(rnd=rnd), ref)
-    out['fault_half_batch'] = reference.gaps(follow(half=True), ref)
-    if 'owned' in first['steps'][0][0]:
-      out['fault_no_exchange'] = reference.gaps(follow(local_only=True),
-                                                ref)
-    losses, g1, delta = first['prog']
-    out['fault_state_unchanged'] = reference.gaps(
-        (losses, [np.zeros_like(a) for a in g1],
-         [np.zeros_like(a) for a in delta]), ref)
+      out[name] = drv.gaps(rec, ref)
+    for name, control in drv.controls().items():
+      out[name] = drv.gaps(drv.follow(first['steps'], **control), ref)
+    out['fault_state_unchanged'] = drv.gaps(
+        drv.unchanged(first['prog']), ref)
   return out
 
 

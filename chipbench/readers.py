@@ -12,11 +12,10 @@ adding a file.
 """
 from __future__ import annotations
 
-import importlib.util
 import os
 import statistics
 
-from . import yardstick
+from . import load_file, yardstick
 
 
 def window_ms_per_step(ctx, key):
@@ -94,8 +93,4 @@ def resolve(name: str, metrics_dir: str):
   if not os.path.exists(path):
     raise KeyError(f'chipbench: no reader {name!r} in readers.py or '
                    f'{metrics_dir}')
-  spec = importlib.util.spec_from_file_location(
-      f'chipbench_reader_{name}', path)
-  mod = importlib.util.module_from_spec(spec)
-  spec.loader.exec_module(mod)
-  return mod.read
+  return load_file(path).read

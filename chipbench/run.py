@@ -19,6 +19,7 @@ import time
 T_START = time.perf_counter()
 
 import argparse
+import contextlib
 import json
 import os
 import shutil
@@ -54,7 +55,8 @@ def load_cell(root: str, workload: str) -> dict:
   # a configuration may size a mix for itself (its "traffic" key)
   traffic.update(cfg.get('traffic', {}).get(cell['traffic'], {}))
   return dict(
-      bench=bench, cell=cell, metrics_dir=metrics_dir, cfg=cfg,
+      bench=bench, cell=cell, metrics_dir=metrics_dir,
+      builders_dir=os.path.join(home, 'builders'), cfg=cfg,
       traffic=traffic,
       limits=_load(os.path.join(home, 'cells',
                                 workload + '.json'))['limits'],
@@ -128,13 +130,14 @@ def memory_peak_bytes() -> int:
   return peak
 
 
-def traced(fn):
+def traced(fn, where=None):
   """Run ``fn`` in a profiler session of its own; returns
-  ``(fn's result, the loaded profile)``.  The trace goes under
-  TMPDIR and is deleted once read."""
+  ``(fn's result, the loaded profile)``.  The trace goes to ``where``
+  and stays there, or under TMPDIR and is deleted once read."""
   import jax
   from . import trace
-  where = tempfile.mkdtemp(prefix='chipbench_trace_')
+  keep = where is not None
+  where = where or tempfile.mkdtemp(prefix='chipbench_trace_')
   try:
     jax.profiler.start_trace(where)
     try:
@@ -143,7 +146,18 @@ def traced(fn):
       jax.profiler.stop_trace()
     return out, trace.load(where)
   finally:
-    shutil.rmtree(where, ignore_errors=True)
+    if not keep:
+      shutil.rmtree(where, ignore_errors=True)
+
+
+def window_trace_dir(trace_on: bool):
+  """A context: where a traced run keeps its window's trace until the
+  readers have run (``ctx['trace_dir']``: a reader that wants more
+  than the reduction, the ops' scopes say, opens it); ``None`` without
+  ``--trace 1``."""
+  if trace_on:
+    return tempfile.TemporaryDirectory(prefix='chipbench_trace_')
+  return contextlib.nullcontext()
 
 
 def run_probes(drv, reps: int) -> dict:
@@ -166,33 +180,21 @@ def run_probes(drv, reps: int) -> dict:
   return out
 
 
-def reference_of(drv, first):
-  """Free the program, then hand back what the comparison needs:
-  ``(numbers, follow)`` — the exchange's and the draw's counts
-  against the CSR and the table, and ``follow(**kw)``, the plain
-  reference (or, with ``rnd=`` / ``half=`` / ``local_only=``, a control
-  or a fault) following the first steps on the ids the program drew."""
-  import jax
-  import jax.numpy as jnp
-  from . import reference
+def exact_counts(drv, first) -> dict:
+  """The exchange's counts, which only the live program can give; then,
+  the program freed, what the first steps drew against the data."""
   numbers = dict(drv.exchange_checks())
   drv.free()
-  indptr, indices, feats, labels = drv.tables()
-  numbers.update(drv.draw_counts(first['steps'], indptr, indices))
-  del indptr, indices
-  steps = jax.tree_util.tree_map(jnp.asarray, first['steps'])
-  follow = lambda **kw: reference.follow(
-      drv.kind, drv.layers0, steps, feats, labels, drv.hyper, **kw)
-  return numbers, follow
+  numbers.update(drv.draw_counts(first['steps']))
+  return numbers
 
 
 def compare(drv, first) -> dict:
-  """The numbers compared: what the first steps drew against the CSR
-  and the table, then — with the program freed — loss, first gradient
-  and parameter change against the plain reference."""
-  from . import reference
-  numbers, follow = reference_of(drv, first)
-  numbers.update(reference.gaps(first['prog'], follow()))
+  """The numbers compared: the exact counts, then — with the program
+  freed — what the driver's plain reference, following the first
+  steps, says of the record the program left (`drivers._Driver`)."""
+  numbers = exact_counts(drv, first)
+  numbers.update(drv.gaps(first['prog'], drv.follow(first['steps'])))
   return numbers
 
 
@@ -219,19 +221,22 @@ def matmul_precision(cfg: dict):
 def run_cell(root: str, workload: str, seed: int, seconds: float,
              trace_on: bool, device: dict, t_start: float) -> dict:
   spec = load_cell(root, workload)
-  with matmul_precision(spec['cfg']):
-    return _run_cell(spec, seed, seconds, trace_on, device, t_start)
+  with matmul_precision(spec['cfg']), \
+      window_trace_dir(trace_on) as trace_dir:
+    return _run_cell(spec, seed, seconds, trace_dir, device, t_start)
 
 
-def _run_cell(spec: dict, seed: int, seconds: float, trace_on: bool,
+def _run_cell(spec: dict, seed: int, seconds: float, trace_dir,
               device: dict, t_start: float) -> dict:
   from . import drivers, readers, yardstick
+  trace_on = trace_dir is not None
   cfg, traffic = spec['cfg'], spec['traffic']
   peaks = yardstick.peaks(device['kind'])
   events = compile_events()
   phases = [('start', time.perf_counter() - t_start)]
   mark = lambda name: phases.append((name, time.perf_counter() - t_start))
-  drv = drivers.make(cfg, traffic, seed)
+  drv = drivers.make(cfg, traffic, seed,
+                     builders_dir=spec['builders_dir'])
   mark('built')
   first = drv.first_steps()
   mark('first_steps')
@@ -249,7 +254,8 @@ def _run_cell(spec: dict, seed: int, seconds: float, trace_on: bool,
   ctx = dict(peaks=peaks, chips=int(spec['cell']['chips']))
   if trace_on:
     res, prof = traced(
-        lambda: drv.window(min(seconds, traffic['trace_seconds'])))
+        lambda: drv.window(min(seconds, traffic['trace_seconds'])),
+        trace_dir)
   else:
     res, prof = drv.window(seconds), None
   in_window = max(drv.compile_count() - compiles0, events.n - events0)
@@ -265,7 +271,8 @@ def _run_cell(spec: dict, seed: int, seconds: float, trace_on: bool,
     breakdown = red.pop('breakdown')
     out_device.update(busy_s=red['busy_s'], window_s=res['wall_s'])
     ctx.update(
-        window=res, trace=red, memory_peak_bytes=peak,
+        window=res, trace=red, trace_dir=trace_dir,
+        memory_peak_bytes=peak,
         work=drv.work(first['steps']),
         probes=run_probes(drv, int(traffic['probe_reps'])),
         counters=dict(

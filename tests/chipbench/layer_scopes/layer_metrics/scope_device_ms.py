@@ -31,19 +31,21 @@ this file, reads the ``.xplane.pb`` with the metadata's stats behind
 each event's own; this reader walks either.  On a `ProfileData` of a
 TPU trace it finds no scope and returns ``None``.
 
-This reader WAITS in `tests/chipbench/layer_scopes/`: it reads
-``ctx['profile']`` (the window's trace, loaded by `xspace.load`) and
-`chipbench.run` hands a reader only the reduced trace.  The
-`benchmark` issue that passes the profile moves these files into
-`chipbench/layer_metrics/` and the entries of `entries.json` into
-`BENCHMARK.json`.
+This reader WAITS in `tests/chipbench/layer_scopes/` (ROADMAP S0): it
+reads the window's own trace, which `chipbench.run` keeps until the
+readers have run and names in ``ctx['trace_dir']`` (PR 27), through
+`xspace.load` beside this file; a test may hand it a loaded profile as
+``ctx['profile']``.  The `benchmark` issue that retires the probes
+moves these files into `chipbench/layer_metrics/` and the entries of
+`entries.json` into `BENCHMARK.json`.
 """
 from __future__ import annotations
 
+import functools
 import re
 import sys
 
-from chipbench import trace
+from chipbench import beside, trace
 
 MODULES_LINE = 'XLA Modules'
 UNATTRIBUTED = 'unattributed'
@@ -141,6 +143,18 @@ def by_module(profile, device=None) -> dict:
   return out
 
 
+@functools.lru_cache(maxsize=1)
+def _load(trace_dir: str):
+  """One parse for all the metrics of a run."""
+  return beside(__file__, 'xspace').load(trace_dir)
+
+
+def _profile(ctx):
+  if ctx.get('profile') is not None:
+    return ctx['profile']
+  return _load(ctx['trace_dir']) if ctx.get('trace_dir') else None
+
+
 def _pick(totals: dict, layer: str) -> float:
   """``model`` takes both directions, ``model.bwd`` one."""
   return sum(ns for key, ns in totals.items()
@@ -159,7 +173,7 @@ def read(ctx, layer, per='step', by='op'):
   there is no profile, or the trace holds no scoped op at all: a
   program without scopes has no by-layer time, which is not a time of
   0."""
-  profile = ctx.get('profile')
+  profile = _profile(ctx)
   if profile is None:
     return None
   if by == 'module':

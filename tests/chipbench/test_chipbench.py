@@ -21,32 +21,28 @@ if REPO not in sys.path:
   sys.path.insert(0, REPO)
 
 import cellroot
-from chipbench import build, readers, reference, run, trace, yardstick
+from chipbench import (build, drivers, limits, readers, reference, run,
+                       trace, yardstick)
 
 FAKE_TPU = dict(platform='cpu', kind='TPU v5 lite', count=1)
-TINY = dict(num_nodes=3000, avg_degree=6, feature_dim=12, hidden=16,
-            classes=5, fanout=[3, 2, 2])
 CELLS = ['sage-products.train-fused', 'sage-products.train-loader',
          'sage-products-p4.train-fused']
+#: a configuration the harness has never heard of (two node types,
+#: three relations, RGCN), with a driver, a data builder and a plain
+#: reference of its own in `foreign_cell/builders/`
+FOREIGN = 'rgcn-toy.train-typed'
 
 
 def tiny_root(tmp_path) -> str:
-  """The tests' root (`cellroot.make_root`), cut to a tiny size."""
-  root = cellroot.make_root(str(tmp_path / 'root'))
-  for name in os.listdir(os.path.join(root, 'chipbench', 'configs')):
-    path = os.path.join(root, 'chipbench', 'configs', name)
-    cfg = json.load(open(path))
-    cfg.update(TINY)
-    if 'traffic' in cfg:
-      cfg['traffic'] = {'train-fused': {'steps_per_dispatch': 3}}
-    json.dump(cfg, open(path, 'w'))
-  for name in os.listdir(os.path.join(root, 'chipbench', 'traffic')):
-    path = os.path.join(root, 'chipbench', 'traffic', name)
-    t = json.load(open(path))
-    t.update(batch=16, trace_seconds=0.3, probe_reps=1)
-    t.update({k: 4 for k in ('steps_per_dispatch',) if k in t})
-    t.update({k: 6 for k in ('steps_per_epoch',) if k in t})
-    json.dump(t, open(path, 'w'))
+  return cellroot.make_tiny_root(str(tmp_path / 'root'))
+
+
+def cell_root(tmp_path, workload) -> str:
+  """`tiny_root`, with the foreign cell added where it is asked for
+  (its files are toy-sized as they wait)."""
+  root = tiny_root(tmp_path)
+  if workload == FOREIGN:
+    cellroot.add_cell(root, cellroot.FOREIGN_CELL)
   return root
 
 
@@ -79,10 +75,11 @@ def hand_profile():
 # -- the last line -----------------------------------------------------------
 
 @pytest.mark.parametrize('workload,seed', [
-    (CELLS[0], 3), (CELLS[1], 2 ** 31 + 7), (CELLS[2], 12345)])
+    (CELLS[0], 3), (CELLS[1], 2 ** 31 + 7), (CELLS[2], 12345),
+    (FOREIGN, 2 ** 31 + 11)])
 def test_driver_prints_a_well_formed_last_line(tmp_path, capsys,
                                                workload, seed):
-  line = drive(tiny_root(tmp_path), workload, seed=seed)
+  line = drive(cell_root(tmp_path, workload), workload, seed=seed)
   run.report(line)
   out, err = capsys.readouterr()
   last = json.loads(out.strip().splitlines()[-1])
@@ -102,8 +99,10 @@ def test_driver_prints_a_well_formed_last_line(tmp_path, capsys,
   assert err.strip().splitlines()[-1].startswith('chipbench check:')
 
 
-def test_traced_run_reports_the_per_layer_metrics(tmp_path, monkeypatch):
-  def fake_traced(fn):
+@pytest.mark.parametrize('workload', [CELLS[1], FOREIGN])
+def test_traced_run_reports_the_per_layer_metrics(tmp_path, monkeypatch,
+                                                  workload):
+  def fake_traced(fn, where=None):
     t0 = time.perf_counter_ns()
     out = fn()
     span = time.perf_counter_ns() - t0
@@ -111,10 +110,11 @@ def test_traced_run_reports_the_per_layer_metrics(tmp_path, monkeypatch):
            Ev('all-to-all.2', span * 0.6, span * 0.1)]
     return out, Pr([Pl('/device:TPU:0', [Ln('XLA Ops', ops)])])
   monkeypatch.setattr(run, 'traced', fake_traced)
-  line = drive(tiny_root(tmp_path), CELLS[1], trace_on=True)
+  root = cell_root(tmp_path, workload)
+  line = drive(root, workload, trace_on=True)
   want = {m['name'] for m in json.load(open(os.path.join(
-      REPO, 'BENCHMARK.json')))['per_layer']
-      if CELLS[1] in m.get('workloads', [CELLS[1]])}
+      root, 'BENCHMARK.json')))['per_layer'] if workload in m['workloads']}
+  assert len(want) >= 5
   # the CPU reports no memory peak, so that reader finds nothing and
   # the metric is left out rather than read as 0
   assert set(line['metrics']) == want - {'peak_hbm_gb'}
@@ -211,59 +211,108 @@ def test_every_layer_metric_names_a_reader_and_a_reported_metric(
                                    'delta_gap'}
 
 
-def test_a_cell_is_added_by_new_files_and_entries_only(tmp_path):
-  root = tiny_root(tmp_path)
+def _same_model_cell(waiting: str, root: str) -> str:
+  """A cell of the model the harness knows, at other numbers, with a
+  reader of its own: the files and entries a PR would bring."""
   home = os.path.join(root, 'chipbench')
-  before = {}
-  for d, _, files in os.walk(root):
-    for f in files:
-      p = os.path.join(d, f)
-      if f != 'BENCHMARK.json':
-        before[p] = open(p, 'rb').read()
+  for sub in ('configs', 'traffic', 'cells', 'layer_metrics'):
+    os.makedirs(os.path.join(waiting, sub))
   cfg = json.load(open(os.path.join(home, 'configs',
                                     'sage-products.json')))
   cfg.update(name='sage-small', hidden=8, fanout=[2, 2, 2])
-  json.dump(cfg, open(os.path.join(home, 'configs', 'sage-small.json'),
+  json.dump(cfg, open(os.path.join(waiting, 'configs', 'sage-small.json'),
                       'w'))
   json.dump(dict(driver='fused', batch=8, steps_per_dispatch=3,
                  trace_seconds=0.2, probe_reps=1),
-            open(os.path.join(home, 'traffic', 'train-short.json'), 'w'))
+            open(os.path.join(waiting, 'traffic', 'train-short.json'),
+                 'w'))
   json.dump(dict(limits=dict(loss_gap=1e-4, loss1_gap=1e-4, grad_gap=1e-4,
                              delta_gap=1e-3,
                              bad_edges=0, bad_fanout=0)),
-            open(os.path.join(home, 'cells', 'sage-small.train-short.json'),
-                 'w'))
-  with open(os.path.join(home, 'layer_metrics', 'dispatches.py'),
+            open(os.path.join(waiting, 'cells',
+                              'sage-small.train-short.json'), 'w'))
+  with open(os.path.join(waiting, 'layer_metrics', 'dispatches.py'),
             'w') as f:
     f.write('def read(ctx, scale):\n'
             '  return scale * ctx["window"]["dispatches"]\n')
   json.dump(dict(layer='loader', unit='count', better='higher',
                  source='program_counter', moves='train_seeds_per_s',
                  reader='dispatches', params=dict(scale=2)),
-            open(os.path.join(home, 'layer_metrics',
+            open(os.path.join(waiting, 'layer_metrics',
                               'dispatches_x2.json'), 'w'))
-  bench = json.load(open(os.path.join(root, 'BENCHMARK.json')))
-  bench['configs'].append(dict(
-      name='sage-small', source='test', reduced=[], why='test',
-      file='chipbench/configs/sage-small.json'))
-  bench['workloads'].append(dict(
-      name='sage-small.train-short', config='sage-small',
-      traffic='train-short', chips=1, why='test'))
-  bench['per_layer'].append(dict(
-      name='dispatches_x2', unit='count', better='higher',
-      source='program_counter', layer='loader',
-      moves='train_seeds_per_s', workloads=['sage-small.train-short']))
-  json.dump(bench, open(os.path.join(root, 'BENCHMARK.json'), 'w'))
-  line = drive(root, 'sage-small.train-short')
+  cell = 'sage-small.train-short'
+  json.dump(dict(
+      configs=[dict(name='sage-small', source='test', reduced=[],
+                    why='test', file='chipbench/configs/sage-small.json')],
+      workloads=[dict(name=cell, config='sage-small',
+                      traffic='train-short', chips=1, why='test')],
+      per_layer=[dict(name='dispatches_x2', unit='count', better='higher',
+                      source='program_counter', layer='loader',
+                      moves='train_seeds_per_s', workloads=[cell])],
+      reports={cell: ['train_step_mfu']}),
+      open(os.path.join(waiting, 'entries.json'), 'w'))
+  return cell
+
+
+def _files_under(*dirs):
+  return {os.path.join(d, f): open(os.path.join(d, f), 'rb').read()
+          for top in dirs for d, _, files in os.walk(top) for f in files
+          if f != 'BENCHMARK.json' and not f.endswith('.pyc')}
+
+
+@pytest.mark.parametrize('kind', ['same_model', 'foreign'])
+def test_a_cell_is_added_by_new_files_and_entries_only(tmp_path, kind):
+  """A PR adds a cell by files that were not there and entries
+  appended to `BENCHMARK.json` (`cellroot.add_cell` does nothing else)
+  — the model the harness knows at other numbers with a reader of its
+  own, or a configuration with a driver, a data builder and a plain
+  reference of its own — and the cell runs and proves correct with
+  no byte of any data file of the root or of any python file of the
+  harness changed."""
+  root = tiny_root(tmp_path)
+  before = _files_under(root, os.path.join(REPO, 'chipbench'))
+  assert any(p.endswith('drivers.py') for p in before)
+  if kind == 'foreign':
+    waiting, cell = cellroot.FOREIGN_CELL, FOREIGN
+  else:
+    waiting = str(tmp_path / 'waiting')
+    cell = _same_model_cell(waiting, root)
+  cellroot.add_cell(root, waiting)
+  line = drive(root, cell)
   assert line['correct'] is True
   assert line['metrics']['train_seeds_per_s']['value'] > 0
-  spec = run.load_cell(root, 'sage-small.train-short')
+  spec = run.load_cell(root, cell)
   names = [m['name'] for m in spec['per_layer']]
-  assert 'dispatches_x2' in names and 'train_step_mfu' in names
-  read = readers.resolve('dispatches', spec['metrics_dir'])
-  assert read(dict(window=dict(dispatches=7)), scale=2) == 14
+  assert 'train_step_mfu' in names
+  if kind == 'same_model':
+    assert 'dispatches_x2' in names
+    read = readers.resolve('dispatches', spec['metrics_dir'])
+    assert read(dict(window=dict(dispatches=7)), scale=2) == 14
+  after = _files_under(root, os.path.join(REPO, 'chipbench'))
   for p, data in before.items():
-    assert open(p, 'rb').read() == data, f'{p} was edited'
+    assert after[p] == data, f'{p} was edited'
+  brought = sorted(os.path.relpath(p, root) for p in set(after) - set(before))
+  assert brought and all(p.startswith('chipbench' + os.sep)
+                         for p in brought), brought
+  if kind == 'foreign':
+    assert [p for p in brought if p.endswith('.py')] == [
+        f'chipbench/builders/{f}.py'
+        for f in ('typed', 'typed_build', 'typed_reference')]
+  with pytest.raises(FileExistsError):
+    cellroot.add_cell(root, waiting)
+
+
+def test_an_unknown_builder_names_the_file_it_looked_for(tmp_path):
+  root = cell_root(tmp_path, FOREIGN)
+  spec = run.load_cell(root, FOREIGN)
+  make = lambda cfg, traffic: drivers.make(
+      cfg, traffic, 1, builders_dir=spec['builders_dir'])
+  with pytest.raises(SystemExit) as e:
+    make(dict(spec['cfg'], builder='nowhere'), spec['traffic'])
+  assert os.path.join(spec['builders_dir'], 'nowhere.py') in str(e.value)
+  with pytest.raises(SystemExit) as e:
+    make(spec['cfg'], dict(spec['traffic'], driver='fused'))
+  assert 'typed.py' in str(e.value) and "['loader']" in str(e.value)
 
 
 # -- the work counts ---------------------------------------------------------
@@ -371,38 +420,25 @@ def test_reference_agrees_with_the_programs_models_and_optax():
     assert gaps['delta_gap'] < 1e-4, (kind, gaps)
 
 
-def _cell_gaps(root, workload, **driver_kw):
-  """First steps of a tiny cell against the reference, the way
-  `chipbench.limits` reads them."""
-  from chipbench import drivers
-  spec = run.load_cell(root, workload)
-  drv = drivers.make(spec['cfg'], spec['traffic'], 9, **driver_kw)
-  first = drv.first_steps()
-  _, follow = run.reference_of(drv, first)
-  return spec['limits'], first['prog'], follow
-
-
-@pytest.mark.parametrize('workload', CELLS[:2])
+@pytest.mark.parametrize('workload', CELLS[:2] + [FOREIGN])
 def test_controls_and_faults_fail_the_shipped_limits(tmp_path, workload):
-  """The limits the cells ship with pass the program and fail (a) the
+  """The limits the cells ship with, read the way `chipbench.limits`
+  reads them on the chip: they pass the program and fail (a) the
   program's own bfloat16 path, (b) the reference in float8 put in the
   program's place, (c) half of the batch left out, (d) a state left
   unchanged."""
-  import jax.numpy as jnp
-  root = tiny_root(tmp_path)
-  limits, prog, follow = _cell_gaps(root, workload)
-  ref = follow()
-  fails = lambda gaps: [k for k, v in gaps.items() if v > limits[k]]
-  assert fails(reference.gaps(prog, ref)) == []
-  _, prog16, _ = _cell_gaps(root, workload, model_dtype=jnp.bfloat16)
-  assert fails(reference.gaps(prog16, ref))
-  assert fails(reference.gaps(follow(rnd='float8_e4m3'), ref))
-  assert fails(reference.gaps(follow(half=True), ref))
-  losses, g1, delta = prog
-  still = (losses, [np.zeros_like(a) for a in g1],
-           [np.zeros_like(a) for a in delta])
-  assert set(fails(reference.gaps(still, ref))) == {'grad_gap',
-                                                    'delta_gap'}
+  spec = run.load_cell(cell_root(tmp_path, workload), workload)
+  with run.matmul_precision(spec['cfg']):
+    got = limits.read_seed(spec, 9, True, ['bfloat16'])
+  fails = lambda gaps: [k for k, v in gaps.items()
+                        if v > spec['limits'][k]]
+  assert set(got['program']) == set(spec['limits'])
+  assert fails(got['program']) == []
+  assert fails(got['program_bfloat16'])
+  assert fails(got['reference_float8_e4m3'])
+  assert fails(got['fault_half_batch'])
+  assert set(fails(got['fault_state_unchanged'])) == {'grad_gap',
+                                                      'delta_gap'}
 
 
 # -- the timed path broken underneath ----------------------------------------
@@ -431,10 +467,11 @@ def _break(monkeypatch, fault, workload):
   from graphlearn_tpu.models import train
   from graphlearn_tpu.parallel import fused as pfused
   mesh = 'p4' in workload
+  per_batch = 'loader' in workload or workload == FOREIGN
   cls = (pfused._MeshEpochDriver if mesh
          else lfused._SupervisedScanEpoch)
   real_run = cls.run
-  if fault == 'state_unchanged' and 'loader' in workload:
+  if fault == 'state_unchanged' and per_batch:
     real = train.make_extracted_supervised_step
 
     def make(extract, tx, batch_size):
@@ -447,7 +484,7 @@ def _break(monkeypatch, fault, workload):
       kept = jax.tree_util.tree_map(lambda a: a + 0, state)
       return kept, real_run(self, state)[1]
     monkeypatch.setattr(cls, 'run', run_)
-  elif fault == 'half_batch' and 'loader' in workload:
+  elif fault == 'half_batch' and per_batch:
     real_loss = train.supervised_loss
     monkeypatch.setattr(
         train, 'supervised_loss',
@@ -474,11 +511,12 @@ def _break(monkeypatch, fault, workload):
 @pytest.mark.parametrize('workload,fault', [
     (CELLS[0], 'state_unchanged'), (CELLS[0], 'half_batch'),
     (CELLS[1], 'state_unchanged'), (CELLS[1], 'half_batch'),
-    (CELLS[2], 'half_batch'), (CELLS[2], 'no_exchange')])
+    (CELLS[2], 'half_batch'), (CELLS[2], 'no_exchange'),
+    (FOREIGN, 'state_unchanged'), (FOREIGN, 'half_batch')])
 def test_a_broken_timed_path_comes_out_not_correct(tmp_path, monkeypatch,
                                                    workload, fault):
   _break(monkeypatch, fault, workload)
-  line = drive(tiny_root(tmp_path), workload)
+  line = drive(cell_root(tmp_path, workload), workload)
   assert line['correct'] is False
   failed = [k for k, (v, lim) in line['checks'].items() if not v <= lim]
   assert failed, line['checks']

@@ -1,12 +1,13 @@
 """The by-layer reader that waits in `tests/chipbench/layer_scopes/`
-(ISSUE 25): its rule of attribution on hand-made planes, and that it
-joins the benchmark by new files and new entries only."""
+(ISSUE 25): its rule of attribution on hand-made planes, that it
+joins the benchmark by new files and new entries only, and that a
+traced run hands it the window's trace (ISSUE 27)."""
 import collections
-import importlib.util
 import json
 import os
-import shutil
+import re
 import sys
+import time
 
 import pytest
 
@@ -16,7 +17,7 @@ if REPO not in sys.path:
   sys.path.insert(0, REPO)
 
 import cellroot
-from chipbench import readers, run, trace
+from chipbench import load_file, readers, run, trace
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 WAITING = os.path.join(HERE, 'layer_scopes')
@@ -29,11 +30,7 @@ Pr = collections.namedtuple('Pr', 'planes')
 
 
 def _waiting_module(name):
-  spec = importlib.util.spec_from_file_location(
-      name, os.path.join(WAITING, 'layer_metrics', name + '.py'))
-  mod = importlib.util.module_from_spec(spec)
-  spec.loader.exec_module(mod)
-  return mod
+  return load_file(os.path.join(WAITING, 'layer_metrics', name + '.py'))
 
 
 @pytest.fixture(scope='module')
@@ -167,21 +164,11 @@ def test_none_without_scopes_or_profile(reader):
                      'sample') is None
 
 
-def merged_root(tmp_path) -> str:
-  """`cellroot.make_root`, then `layer_scopes/` laid over it the way
-  the `benchmark` issue will: files copied, entries appended."""
-  root = cellroot.make_root(str(tmp_path / 'root'))
-  shutil.copytree(os.path.join(WAITING, 'layer_metrics'),
-                  os.path.join(root, 'chipbench', 'layer_metrics'),
-                  dirs_exist_ok=True)
-  path = os.path.join(root, 'BENCHMARK.json')
-  with open(path) as f:
-    bench = json.load(f)
-  with open(os.path.join(WAITING, 'entries.json')) as f:
-    for key, entries in json.load(f).items():
-      bench[key].extend(entries)
-  with open(path, 'w') as f:
-    json.dump(bench, f)
+def merged_root(tmp_path, make=cellroot.make_root) -> str:
+  """The tests' root, then `layer_scopes/` laid over it the way the
+  `benchmark` issue will: files copied, entries appended."""
+  root = make(str(tmp_path / 'root'))
+  cellroot.add_cell(root, WAITING)
   return root
 
 
@@ -320,3 +307,80 @@ def test_xspace_agrees_with_profiledata_on_a_real_trace(xspace,
   assert seen > 10
   names = {e.name for p in mine.planes for l in p.lines for e in l.events}
   assert 'loader.sample' in names
+
+
+# -- through the harness: a traced run hands the reader the trace ------------
+
+def as_the_runtime_writes_it(profile) -> bytes:
+  """``profile`` (hand-made planes) as a serialized ``XSpace`` laid
+  out as the TPU runtime lays it out: an event is named by its HLO
+  line without the metadata, and the ``op_name`` sits in the stat
+  ``tf_op`` of the event's METADATA."""
+  tf_op = 1
+  planes = []
+  for plane in profile.planes:
+    ids, metas, lines = {}, [], []
+    for line in plane.lines:
+      events = []
+      for e in line.events:
+        if e.name not in ids:
+          ids[e.name] = len(ids) + 1
+          scope = re.search(r', metadata=\{op_name="([^"]*)"\}', e.name)
+          scope = scope.group(1) if scope else dict(e.stats).get('tf_op')
+          meta = [_field(1, ids[e.name]),
+                  _field(2, re.sub(r', metadata=\{[^}]*\}', '', e.name))]
+          if scope:
+            meta.append(_field(5, [_field(1, tf_op), _field(5, scope)]))
+          metas.append(_field(4, [_field(1, ids[e.name]),
+                                  _field(2, meta)]))
+        events.append(_field(4, [
+            _field(1, ids[e.name]), _field(2, int(e.start_ns * 1000)),
+            _field(3, int(e.duration_ns * 1000))]))
+      lines.append(_field(3, [_field(2, line.name), _field(3, 0)]
+                          + events))
+    stat_meta = _field(5, [_field(1, tf_op), _field(
+        2, [_field(1, tf_op), _field(2, 'tf_op')])])
+    planes.append(_field(1, [_field(2, plane.name)] + lines + metas
+                         + [stat_meta]))
+  return b''.join(planes)
+
+
+def test_a_traced_run_hands_the_reader_the_windows_trace(
+    tmp_path, monkeypatch, xspace):
+  """`chipbench.run` keeps the window's trace until the readers have
+  run and names it in ``ctx['trace_dir']``; the waiting reader opens
+  it there, with the scopes `ProfileData` leaves out, and its metrics
+  come out on the line.  The profiler is stood in for (the CPU writes
+  no device plane); everything from the file on is the real path."""
+  data = as_the_runtime_writes_it(scoped_profile())
+  seen = []
+
+  def fake_traced(fn, where=None):
+    out = fn()
+    if where is not None:
+      sub = os.path.join(where, 'plugins', 'profile', 'run')
+      os.makedirs(sub)
+      with open(os.path.join(sub, 'host.xplane.pb'), 'wb') as f:
+        f.write(data)
+      seen.append(where)
+    return out, xspace.parse(data)
+  monkeypatch.setattr(run, 'traced', fake_traced)
+  workload = 'sage-products.train-loader'
+  root = merged_root(tmp_path, cellroot.make_tiny_root)
+  line = run.run_cell(root, workload, 5, 0.3, True,
+                      dict(platform='cpu', kind='TPU v5 lite', count=1),
+                      time.perf_counter())
+  steps = line['window']['steps']
+  got = {k: v['value'] for k, v in line['metrics'].items()}
+  ms = lambda ns: ns / 1e6
+  assert got['sample_device_ms_per_step'] == ms(340) / steps
+  assert got['gather_device_ms_per_step'] == ms(250) / steps
+  assert got['model_device_ms_per_step'] == ms(310) / steps
+  assert got['optimizer_device_ms_per_step'] == ms(70) / steps
+  assert got['unattributed_device_share'] == pytest.approx(100 * 60 / 1055)
+  # the metrics the benchmark has are on the line beside them
+  assert {'device_idle_share', 'train_step_mfu'} <= set(got)
+  assert line['correct'] is True
+  # one window, one trace directory, gone once the run is over
+  (where,) = seen
+  assert not os.path.exists(where)
