@@ -58,7 +58,7 @@ def main():
   import flax.linen as nn
   import optax
   from jax.sharding import NamedSharding, PartitionSpec
-  from graphlearn_tpu.models import GATConv, HeteroConv, SAGEConv
+  from graphlearn_tpu.models import RGAT, SAGEConv
   from graphlearn_tpu.parallel import (DistHeteroDataset,
                                        DistHeteroNeighborLoader, make_mesh,
                                        replicate)
@@ -118,22 +118,18 @@ def main():
 
   batch0 = next(iter(loader))
   etypes = tuple(batch0.edge_index_dict.keys())
-  assert args.hidden % args.heads == 0
-  mk = (lambda: GATConv(args.hidden // args.heads, heads=args.heads)) \
-      if args.model == 'rgat' else (lambda: SAGEConv(args.hidden))
 
-  class RGNN(nn.Module):
-    @nn.compact
-    def __call__(self, x_dict, ei_dict, em_dict):
-      h = {nt: nn.Dense(args.hidden)(x) for nt, x in x_dict.items()}
-      for li in range(2):
-        conv = HeteroConv(etypes, args.hidden, make_conv=mk,
-                          name=f'conv{li}')
-        h = conv(h, ei_dict, em_dict)
-        h = {nt: nn.relu(v) for nt, v in h.items()}
-      return nn.Dense(classes)(h[PAPER])
+  class RSAGE(RGAT):
+    """`RGAT`'s stack with a per-relation `SAGEConv`."""
 
-  model = RGNN()
+    @nn.nowrap
+    def make_conv(self):
+      return SAGEConv(self.hidden_features)
+
+  # the mesh loader's batches state no hop layout: whole tables
+  model = (RGAT if args.model == 'rgat' else RSAGE)(
+      etypes=etypes, hidden_features=args.hidden, out_features=classes,
+      num_layers=2, heads=args.heads, target_ntype=PAPER)
   tx = optax.adam(1e-3)
   single = jax.tree_util.tree_map(lambda v: v[0], batch0)
   params = model.init(jax.random.key(0), single.x_dict,

@@ -4,8 +4,12 @@ TPU counterpart of reference `examples/igbh/{dataset,rgnn,train_rgnn}.py`
 — the BASELINE scaling workload: 4 node types (paper, author,
 institute, fos), 4 relation types + reversed, hetero neighbor sampling
 with per-hop fanouts, and a relational GNN classifying papers.
-``--model rgat`` composes per-edge-type GAT attention via `HeteroConv`
-(the reference's RGAT); ``--model rsage`` uses per-etype SAGE convs.
+``--model rgat`` is the package's `graphlearn_tpu.models.RGAT`
+(per-relation GAT attention summed into the target type, the
+reference's RGAT); ``--model rsage`` is the same stack with its
+per-relation conv swapped for `SAGEConv`.  Both train through
+`make_supervised_step`, so each layer runs only over the hops it feeds
+(the typed loader's batches state their hop layout).
 Zero-egress stand-in for IGBH-tiny: a synthetic academic graph whose
 paper topic is encoded in its fos (field-of-study) links.
 
@@ -91,12 +95,12 @@ def main():
   import jax
   if args.cpu:
     jax.config.update('jax_platforms', 'cpu')
-  import jax.numpy as jnp
   import optax
   import flax.linen as nn
   from graphlearn_tpu.data import Dataset
   from graphlearn_tpu.loader import NeighborLoader
-  from graphlearn_tpu.models import GATConv, HeteroConv, SAGEConv
+  from graphlearn_tpu.models import (RGAT, SAGEConv, TrainState,
+                                     make_eval_step, make_supervised_step)
 
   edges, feats, nnodes, topic = synthetic()
   npaper, classes = len(topic), int(topic.max()) + 1
@@ -113,68 +117,38 @@ def main():
   test_loader = NeighborLoader(ds, args.fanout, (P, test_idx),
                                batch_size=bs)
   batch0 = next(iter(loader))
-  etypes = tuple(batch0.edge_index_dict.keys())
 
-  assert args.hidden % args.heads == 0
-  mk_gat = lambda: GATConv(args.hidden // args.heads,     # noqa: E731
-                           heads=args.heads)              # concat -> hidden
-  mk_sage = lambda: SAGEConv(args.hidden)                 # noqa: E731
-  make_conv = mk_gat if args.model == 'rgat' else mk_sage
+  class RSAGE(RGAT):
+    """`RGAT`'s stack with a per-relation `SAGEConv`."""
 
-  class RGNN(nn.Module):
-    """Reference `examples/igbh/rgnn.py` — per-etype convs merged
-    per node type, stacked num_layers deep."""
+    @nn.nowrap
+    def make_conv(self):
+      return SAGEConv(self.hidden_features)
 
-    @nn.compact
-    def __call__(self, x_dict, edge_index_dict, edge_mask_dict):
-      h = {nt: nn.Dense(args.hidden)(x) for nt, x in x_dict.items()}
-      for li in range(2):
-        conv = HeteroConv(etypes, args.hidden,
-                          make_conv=make_conv, name=f'conv{li}')
-        h = conv(h, edge_index_dict, edge_mask_dict)
-        h = {nt: nn.relu(v) for nt, v in h.items()}
-      return nn.Dense(classes)(h[P])
-
-  model = RGNN()
+  model = (RGAT if args.model == 'rgat' else RSAGE)(
+      etypes=tuple(batch0.edge_index_dict), hidden_features=args.hidden,
+      out_features=classes, num_layers=len(args.fanout),
+      heads=args.heads, target_ntype=P)
   tx = optax.adam(1e-3)
   params = model.init(jax.random.key(0), batch0.x_dict,
                       batch0.edge_index_dict, batch0.edge_mask_dict)
-  opt = tx.init(params)
-
-  @jax.jit
-  def step(params, opt, batch):
-    def loss_fn(p):
-      logits = model.apply(p, batch.x_dict, batch.edge_index_dict,
-                           batch.edge_mask_dict)
-      y = batch.y_dict[P][:bs]
-      valid = (batch.batch_dict[P] >= 0).astype(logits.dtype)
-      ce = optax.softmax_cross_entropy_with_integer_labels(logits[:bs], y)
-      return (ce * valid).sum() / jnp.maximum(valid.sum(), 1.0)
-    loss, g = jax.value_and_grad(loss_fn)(params)
-    upd, opt = tx.update(g, opt, params)
-    return optax.apply_updates(params, upd), opt, loss
-
-  @jax.jit
-  def logits_fn(params, batch):
-    return model.apply(params, batch.x_dict, batch.edge_index_dict,
-                       batch.edge_mask_dict)
+  state = TrainState(params, tx.init(params), jax.numpy.zeros((), 'int32'))
+  step = make_supervised_step(model.apply, tx, bs, target_ntype=P)
+  eval_step = make_eval_step(model.apply, bs, target_ntype=P)
 
   for epoch in range(args.epochs):
     tot = cnt = 0
     for batch in loader:
-      params, opt, loss = step(params, opt, batch)
+      state, loss, _ = step(state, batch)
       tot += float(loss)
       cnt += 1
     print(f'epoch {epoch}: loss {tot / max(cnt, 1):.4f}')
 
   correct = total = 0
   for batch in test_loader:
-    pred = np.argmax(np.asarray(logits_fn(params, batch))[:bs], axis=1)
-    seeds = np.asarray(batch.batch_dict[P])
-    valid = seeds >= 0
-    correct += int((pred[valid] == np.asarray(batch.y_dict[P][:bs])[valid])
-                   .sum())
-    total += int(valid.sum())
+    c, t = eval_step(state.params, batch)
+    correct += int(c)
+    total += int(t)
   print(f'{args.model} test acc: {correct / max(total, 1):.4f}')
 
 

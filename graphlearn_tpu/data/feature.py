@@ -34,12 +34,15 @@ from ..utils.profiling import layer_scope
 from ..utils.tensor import convert_to_array
 
 
-@functools.partial(jax.jit, static_argnames=('use_pallas',))
+@functools.partial(jax.jit, static_argnames=('use_pallas', 'part'))
 def _device_gather(hot: jax.Array, ids: jax.Array, id2index, *,
-                   use_pallas: bool) -> jax.Array:
+                   use_pallas: bool, part: Optional[str] = None
+                   ) -> jax.Array:
   # `use_pallas` is part of the jit cache key so the GLT_PALLAS
   # kill-switch keeps working mid-process (resolved per call outside).
-  with layer_scope('gather'):
+  # ``part``: which table this is where a dataset has several (the
+  # node type), in the ops' scope and nowhere else.
+  with layer_scope('gather', part):
     valid = ids >= 0
     idx = jnp.where(valid, ids, 0).astype(jnp.int32)
     if id2index is not None:
@@ -211,7 +214,8 @@ class Feature:
     """Gather rows by global id onto the device (see :meth:`get`)."""
     return self.get(ids)
 
-  def get(self, ids, scope: str = 'feature') -> jax.Array:
+  def get(self, ids, scope: str = 'feature',
+          part: Optional[str] = None) -> jax.Array:
     """Gather rows by global id onto the device.
 
     Counterpart of reference `Feature.__getitem__`
@@ -229,18 +233,22 @@ class Feature:
     tiered path passes ``'serving'`` so a dashboard can split
     training-epoch from inference-traffic cache behavior out of one
     event stream.  Values are scope-independent (byte-identical).
+
+    ``part`` names this table among a dataset's (a typed loader passes
+    the node type): the all-device gather's ops then carry
+    ``glt.gather/<part>``.
     """
     self.lazy_init()
     if (isinstance(ids, jax.Array)
         and self.hot_rows >= self._host_feats.shape[0]):
-      return self._device_get(ids)
+      return self._device_get(ids, part)
     if self._id2index_dev is not None and self._id2index_host is None:
       # device-native table with a device-only id2index: the host
       # remap below would silently SKIP the mapping — route host ids
       # through the all-device path instead (table is fully hot by
       # the device-native constructor's contract)
       return self._device_get(jnp.asarray(np.asarray(ids),
-                                          dtype=jnp.int32))
+                                          dtype=jnp.int32), part)
     ids_host = np.asarray(ids)
     valid = ids_host >= 0
     idx = np.where(valid, ids_host, 0)
@@ -325,10 +333,11 @@ class Feature:
                         evicts)
     return x
 
-  def _device_get(self, ids: jax.Array) -> jax.Array:
+  def _device_get(self, ids: jax.Array,
+                  part: Optional[str] = None) -> jax.Array:
     """All-device gather (fully-hot tables, device ids): no host sync."""
     return _device_gather(self._hot, ids, self._id2index_dev,
-                          use_pallas=pallas_enabled())
+                          use_pallas=pallas_enabled(), part=part)
 
   def _pinned_buffer(self):
     """The lazily built `data.cold_cache.PinnedColdBuffer` over the

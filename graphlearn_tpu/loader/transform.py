@@ -238,7 +238,8 @@ def to_hetero_data(
   for ntype, ids in out.node.items():
     nm_dict[ntype] = ids >= 0
     if node_feature_dict and ntype in node_feature_dict:
-      x_dict[ntype] = node_feature_dict[ntype][ids]
+      with span('feature.get', ntype=ntype):
+        x_dict[ntype] = node_feature_dict[ntype].get(ids, part=ntype)
     if node_label_dict and ntype in node_label_dict:
       lab = node_label_dict[ntype]
       if isinstance(lab, jax.Array) and isinstance(ids, jax.Array):

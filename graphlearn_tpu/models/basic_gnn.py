@@ -11,7 +11,7 @@ A batch that states its sampler's hop layout (``metadata
 a stack of in-edge-local convs compute each layer only over the hops
 that layer feeds (PyG's ``trim_to_layer`` with static shapes); the
 result is then ``[C_0, out]``, the seed rows, instead of the whole
-table.  `models.train._apply_with_weights` is the seam that passes
+table.  `models.train.apply_to_batch` is the seam that passes
 the layout on.
 """
 from __future__ import annotations
@@ -46,8 +46,8 @@ class BasicGNN(nn.Module):
   ``hop_capacities`` — static ``((C_0..C_H), (E_0..E_{H-1}))``, the
   cumulative node and edge-slot capacities per hop of the batch's
   sampler — trims the stack when its convs declare ``in_edge_local``
-  (`SAGEConv`; not `GCNConv`, whose normalisation reads the whole
-  subgraph, and not yet `GINConv` / `GATConv`): layer ``l`` of ``L``
+  (`SAGEConv`, `GATConv`; not `GCNConv`, whose normalisation reads the
+  whole subgraph, and not yet `GINConv`): layer ``l`` of ``L``
   reads rows ``[0, C_{L-l})`` and edge slots ``[:E_{L-1-l}]`` and
   writes rows ``[0, C_{L-1-l})``, so a trimmed call returns ``[C_0,
   out]`` — the seed rows, each valid seed's equal to the untrimmed

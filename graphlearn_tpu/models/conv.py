@@ -14,12 +14,14 @@ what a conv costs is the rows and edge slots it is handed — which is
 why `BasicGNN` hands each layer only the hops that layer feeds.
 
 A conv whose output row depends on that row and its in-edges only
-declares ``in_edge_local = True`` and takes ``num_dst``: the bipartite
-form, messages gathered from all ``n_src`` input rows and aggregated
-into the first ``num_dst`` of them.  `SAGEConv` does.  `GCNConv` may
-not (its normalisation counts a source's out-edges over the whole
-subgraph); `GINConv` / `GATConv` could and have not been given the
-form yet.
+declares ``in_edge_local = True`` and takes the bipartite form:
+``num_dst`` — messages gathered from all ``n_src`` input rows and
+aggregated into the first ``num_dst`` of them — or ``x`` as a pair
+``(x_src, x_dst)`` of separate source and target tables (a relation
+between two node types, `models.hetero.HeteroConv`).  `SAGEConv` and
+`GATConv` do.  `GCNConv` may not (its normalisation counts a source's
+out-edges over the whole subgraph); `GINConv` / `GATv2Conv` could and
+have not been given the form yet.
 
 Edge direction follows the loader's transposed emission
 (reference `sampler/neighbor_sampler.py:159-166`): ``edge_index[0]`` is
@@ -91,6 +93,19 @@ def segment_softmax(e: jax.Array, dst: jax.Array, num_segments: int,
   return ex / jnp.maximum(denom[dc], 1e-16)
 
 
+def _bipartite(x, num_dst):
+  """``(x_src, x_dst or None, n_dst)`` of a conv's ``x`` argument: one
+  table (targets are its first ``num_dst`` rows, all of them when
+  ``None``) or a ``(x_src, x_dst)`` pair."""
+  if isinstance(x, (tuple, list)):
+    x_src, x_dst = x
+    if num_dst is not None and num_dst != x_dst.shape[0]:
+      raise ValueError(f'num_dst={num_dst} with {x_dst.shape[0]} target '
+                       'rows: a (x_src, x_dst) pair states its own')
+    return x_src, x_dst, x_dst.shape[0]
+  return x, None, x.shape[0] if num_dst is None else num_dst
+
+
 def _attention_aggregate(z_src_sel: jax.Array, w: jax.Array,
                          dst: jax.Array, valid: jax.Array, n: int,
                          heads: int, features: int,
@@ -124,8 +139,10 @@ class SAGEConv(nn.Module):
   rows of ``x``, targets its first ``num_dst`` rows (every valid
   ``edge_index[1] < num_dst``), and the result is ``[num_dst, out]``
   — each row what the square form gives it over the same edges.  None
-  is the square form, ``[n_src, out]``.  The parameters are the same
-  either way.
+  is the square form, ``[n_src, out]``.  ``x`` may also be a pair
+  ``(x_src, x_dst)`` of equal width: sources and targets in tables of
+  their own, ``edge_index[0]`` into the first and ``[1]`` into the
+  second.  The parameters are the same either way.
   """
   out_features: int
   use_bias: bool = True
@@ -140,10 +157,11 @@ class SAGEConv(nn.Module):
                edge_mask: Optional[jax.Array] = None,
                edge_weight: Optional[jax.Array] = None,
                num_dst: Optional[int] = None) -> jax.Array:
+    x, x_dst, n = _bipartite(x, num_dst)
     if self.dtype is not None:
       x = x.astype(self.dtype)
+      x_dst = None if x_dst is None else x_dst.astype(self.dtype)
     n_src = x.shape[0]
-    n = n_src if num_dst is None else num_dst
     src, dst = edge_index[0], edge_index[1]
     msg = x[jnp.clip(src, 0, n_src - 1)]
     if self.aggr == 'mean':
@@ -161,9 +179,10 @@ class SAGEConv(nn.Module):
       agg = jax.ops.segment_sum(msg, seg, num_segments=n)
     else:
       raise ValueError(f'Unknown aggr {self.aggr!r}')
+    if x_dst is None:
+      x_dst = x if num_dst is None else x[:num_dst]
     out = (nn.Dense(self.out_features, use_bias=self.use_bias,
-                    dtype=self.dtype, name='lin_self')(
-                        x if num_dst is None else x[:num_dst])
+                    dtype=self.dtype, name='lin_self')(x_dst)
            + nn.Dense(self.out_features, use_bias=False,
                       dtype=self.dtype, name='lin_neigh')(agg))
     return out
@@ -239,33 +258,46 @@ class GINConv(nn.Module):
 
 
 class GATConv(nn.Module):
-  """Graph attention convolution (masked softmax over incoming edges)."""
+  """Graph attention convolution (masked softmax over incoming edges;
+  Velickovic et al. 2018 without self-loops, one projection ``W`` for
+  both ends of an edge and no bias).
+
+  ``z = W x`` per head, ``e_uv = leaky_relu(<a_src, z_u> + <a_dst,
+  z_v>)``, ``alpha = softmax`` of ``e`` over the valid in-edges of
+  ``v``, ``out[v] = sum_u alpha_uv z_u`` (a target without valid
+  in-edges gets 0).  The bipartite form is `SAGEConv`'s: ``num_dst``
+  (targets are the first ``num_dst`` rows, result ``[num_dst, .]``) or
+  ``x`` as ``(x_src, x_dst)``, both ends projected by the one ``W``.
+  """
   out_features: int
   heads: int = 1
   concat: bool = True
   negative_slope: float = 0.2
   dtype: Optional[jnp.dtype] = None
+  # an output row reads its own row and its in-edges, nothing else
+  in_edge_local = True
 
   @nn.compact
-  def __call__(self, x: jax.Array, edge_index: jax.Array,
-               edge_mask: Optional[jax.Array] = None) -> jax.Array:
-    if self.dtype is not None:
-      x = x.astype(self.dtype)
-    n = x.shape[0]
+  def __call__(self, x, edge_index: jax.Array,
+               edge_mask: Optional[jax.Array] = None,
+               num_dst: Optional[int] = None) -> jax.Array:
+    x, x_dst, n = _bipartite(x, num_dst)
     h, f = self.heads, self.out_features
     src, dst = edge_index[0], edge_index[1]
     valid = edge_mask if edge_mask is not None else (dst >= 0)
-    z = nn.Dense(h * f, use_bias=False,
-                 dtype=self.dtype)(x).reshape(n, h, f)
+    lin = nn.Dense(h * f, use_bias=False, dtype=self.dtype)
+    project = lambda rows: lin(rows).reshape(rows.shape[0], h, f)
+    z = project(x)
+    z_dst = z[:n] if x_dst is None else project(x_dst)
     a_src = self.param('att_src', nn.initializers.glorot_uniform(),
                        (h, f))
     a_dst = self.param('att_dst', nn.initializers.glorot_uniform(),
                        (h, f))
     a_src = a_src.astype(z.dtype)
     a_dst = a_dst.astype(z.dtype)
-    alpha_src = (z * a_src[None]).sum(-1).astype(jnp.float32)  # [n, h]
-    alpha_dst = (z * a_dst[None]).sum(-1).astype(jnp.float32)
-    sc = jnp.clip(src, 0, n - 1)
+    alpha_src = (z * a_src[None]).sum(-1).astype(jnp.float32)  # [n_src, h]
+    alpha_dst = (z_dst * a_dst[None]).sum(-1).astype(jnp.float32)
+    sc = jnp.clip(src, 0, z.shape[0] - 1)
     e = nn.leaky_relu(alpha_src[sc] + alpha_dst[jnp.clip(dst, 0, n - 1)],
                       self.negative_slope)          # [E, h]
     w = segment_softmax(e, dst, n, valid)

@@ -14,19 +14,43 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from ..telemetry.recorder import recorder
 from ..typing import EdgeType, NodeType, as_str
-from .conv import SAGEConv, segment_mean
+from ..utils.profiling import layer_scope
+from .conv import GATConv, segment_mean
 
 
 class _NamedConv(nn.Module):
   """Binds a factory-made conv under an explicit etype-keyed scope, so
   params never depend on positional auto-naming (which shifts when a
-  batch lacks some edge type)."""
+  batch lacks some edge type), and runs it over one relation:
+  ``x_src`` the source type's rows, ``x_dst`` the target type's
+  (``None`` for a relation within one type, whose targets are the
+  first ``num_dst`` source rows).
+
+  A conv that declares ``in_edge_local`` (`models.conv`) takes the two
+  tables as they are.  Any other conv gets the concatenation ``[x_dst;
+  x_src]`` — a copy of both tables per relation, kept for the backward
+  pass — with the source ids shifted, and cannot be trimmed."""
   factory: Callable[[], nn.Module]
 
   @nn.compact
-  def __call__(self, x, edge_index, edge_mask):
-    return self.factory()(x, edge_index, edge_mask)
+  def __call__(self, x_src, x_dst, edge_index, edge_mask, num_dst=None):
+    conv = self.factory()
+    if getattr(conv, 'in_edge_local', False):
+      if x_dst is None:
+        return conv(x_src, edge_index, edge_mask, num_dst=num_dst)
+      return conv((x_src, x_dst), edge_index, edge_mask)
+    if num_dst is not None:
+      raise ValueError(
+          f'{type(conv).__name__} is not in-edge-local: it cannot '
+          'compute a trimmed relation (num_dst)')
+    if x_dst is None:
+      return conv(x_src, edge_index, edge_mask)
+    na, nb = x_src.shape[0], x_dst.shape[0]
+    src2 = jnp.clip(edge_index[0], 0, na - 1) + nb
+    return conv(jnp.concatenate([x_dst, x_src], axis=0),
+                jnp.stack([src2, edge_index[1]]), edge_mask)[:nb]
 
 
 class HeteroConv(nn.Module):
@@ -39,8 +63,20 @@ class HeteroConv(nn.Module):
       mean-aggregation, plus a per-type self term — the RGCN flavor;
     * ``make_conv`` given: each edge type gets a fresh conv from the
       factory (e.g. ``lambda: GATConv(d, heads=h)`` for RGAT), run
-      bipartite via source-offset concatenation; no extra self term
+      bipartite — a conv that declares ``in_edge_local`` takes the
+      source and the target type's rows as two tables, any other the
+      source-offset concatenation (`_NamedConv`); no extra self term
       (the conv's own self path applies, PyG semantics).
+
+  ``num_dst_dict`` — ``{node type: rows}`` — computes, per target
+  type, only its first ``rows`` rows (a type left out or at 0 gets no
+  output): what a stack trimmed to the hops each layer feeds asks of
+  its layers (`RGAT`).  The parameters are the same either way.
+
+  Device ops carry ``glt.model/<scope>/<relation>`` per relation's
+  convolution and ``glt.model/<scope>/merge`` over the sum into the
+  target types (``<scope>`` is ``part``: the stack's ``layer<l>``; the
+  module's name by default).
 
   Args:
     etypes: edge types to convolve.
@@ -55,9 +91,14 @@ class HeteroConv(nn.Module):
   aggr: str = 'sum'
   make_conv: Optional[Callable[[], nn.Module]] = None
   dtype: Optional[jnp.dtype] = None   # compute dtype; params stay f32
+  part: Optional[str] = None   # the scopes' ``<scope>``
 
   @nn.compact
-  def __call__(self, x_dict, edge_index_dict, edge_mask_dict=None):
+  def __call__(self, x_dict, edge_index_dict, edge_mask_dict=None,
+               num_dst_dict=None):
+    scope = self.part or self.name or 'hetero'
+    rows_out = lambda nt: (x_dict[nt].shape[0] if num_dst_dict is None
+                           else int(num_dst_dict.get(nt, 0)))
     if self.make_conv is not None and self.dtype is not None:
       # the factory owns its convs' compute dtype; accepting both
       # would leave the dominant per-etype matmuls silently f32
@@ -69,7 +110,7 @@ class HeteroConv(nn.Module):
     counts: Dict[NodeType, int] = {}
     for et in self.etypes:
       a, _, b = et
-      if a not in x_dict or b not in x_dict:
+      if a not in x_dict or b not in x_dict or not rows_out(b):
         continue
       if et in edge_index_dict:
         ei = edge_index_dict[et]
@@ -81,58 +122,50 @@ class HeteroConv(nn.Module):
         # missing one etype would init/apply a different pytree).
         ei = jnp.zeros((2, 0), jnp.int32)
         em = jnp.zeros((0,), jnp.bool_)
-      na, nb = x_dict[a].shape[0], x_dict[b].shape[0]
-      src, dst = ei[0], ei[1]
-      if self.make_conv is not None:
-        conv = _NamedConv(self.make_conv, name=f'conv_{as_str(et)}')
-        if a == b:
-          # self-relation: the conv runs directly — no concat, no
-          # doubled node dimension for the usually-largest relation.
-          agg = conv(x_dict[a], ei, em)
+      na, nb = x_dict[a].shape[0], rows_out(b)
+      with layer_scope('model', f'{scope}/{as_str(et)}'):
+        if self.make_conv is not None:
+          conv = _NamedConv(self.make_conv, name=f'conv_{as_str(et)}')
+          if a == b:
+            # a relation within one type: one table, no second copy
+            agg = conv(x_dict[a], None, ei, em,
+                       None if num_dst_dict is None else nb)
+          else:
+            xa, xb = x_dict[a], x_dict[b][:nb]
+            if xa.shape[-1] != xb.shape[-1]:
+              raise ValueError(
+                  f'HeteroConv(make_conv=...) needs equal feature '
+                  f'widths for {et}: {xa.shape[-1]} vs {xb.shape[-1]} — '
+                  f'project per-type inputs first (e.g. a Dense per '
+                  f'node type)')
+            agg = conv(xa, xb, ei, em)
         else:
-          # bipartite via concatenation: [x_b; x_a] so dst ids are
-          # unchanged and src ids shift by nb; any homogeneous conv
-          # then runs unmodified, and rows [0, nb) are the dst output.
-          xa, xb = x_dict[a], x_dict[b]
-          if xa.shape[-1] != xb.shape[-1]:
-            raise ValueError(
-                f'HeteroConv(make_conv=...) needs equal feature widths '
-                f'for {et}: {xa.shape[-1]} vs {xb.shape[-1]} — project '
-                f'per-type inputs first (e.g. a Dense per node type)')
-          xcat = jnp.concatenate([xb, xa], axis=0)
-          src2 = jnp.clip(src, 0, na - 1) + nb
-          ei2 = jnp.stack([src2, dst])
-          agg = conv(xcat, ei2, em)[:nb]
-      else:
-        msg = nn.Dense(self.out_features, use_bias=False,
-                       dtype=self.dtype, name=f'lin_{as_str(et)}')(
-                           x_dict[a][jnp.clip(src, 0, na - 1)])
-        agg = segment_mean(msg, dst, nb, em)
-      out[b] = out.get(b, 0) + agg
+          msg = nn.Dense(self.out_features, use_bias=False,
+                         dtype=self.dtype, name=f'lin_{as_str(et)}')(
+                             x_dict[a][jnp.clip(ei[0], 0, na - 1)])
+          agg = segment_mean(msg, ei[1], nb, em)
+      with layer_scope('model', f'{scope}/merge'):
+        out[b] = out.get(b, 0) + agg
       counts[b] = counts.get(b, 0) + 1
     res = {}
-    for nt, x in x_dict.items():
-      if self.make_conv is not None:
-        # factory mode: conv output only; untouched types pass through
-        # a projection so widths stay consistent across layers.
-        if nt in out:
-          h = out[nt]
-          if self.aggr == 'mean':
-            h = h / counts[nt]
-          res[nt] = h
-        else:
-          res[nt] = nn.Dense(self.out_features, dtype=self.dtype,
-                             name=f'lin_self_{nt}')(x)
-        continue
-      self_term = nn.Dense(self.out_features, dtype=self.dtype,
-                           name=f'lin_self_{nt}')(x)
-      if nt in out:
-        h = out[nt]
-        if self.aggr == 'mean':
+    with layer_scope('model', f'{scope}/merge'):
+      for nt, x in x_dict.items():
+        if not rows_out(nt):
+          continue
+        h = out.get(nt)
+        if h is not None and self.aggr == 'mean':
           h = h / counts[nt]
-        res[nt] = self_term + h
-      else:
-        res[nt] = self_term
+        if self.make_conv is not None and h is not None:
+          # factory mode: conv output only (the conv's own self path
+          # applies)
+          res[nt] = h
+          continue
+        # the default mode's self term; in factory mode, what a type
+        # no relation reaches passes through so that widths stay
+        # consistent across layers
+        self_term = nn.Dense(self.out_features, dtype=self.dtype,
+                             name=f'lin_self_{nt}')(x[:rows_out(nt)])
+        res[nt] = self_term if h is None else self_term + h
     return res
 
 
@@ -155,7 +188,8 @@ class RGCN(nn.Module):
       last = i == self.num_layers - 1
       feats = self.out_features if last else self.hidden_features
       h = HeteroConv(self.etypes, feats, dtype=self.dtype,
-                     name=f'conv{i}')(h, edge_index_dict, edge_mask_dict)
+                     part=f'layer{i}', name=f'conv{i}')(
+                         h, edge_index_dict, edge_mask_dict)
       if not last:
         h = {nt: nn.relu(v) for nt, v in h.items()}
         if self.dropout > 0:
@@ -166,6 +200,114 @@ class RGCN(nn.Module):
     if self.target_ntype is not None:
       return h[self.target_ntype]
     return h
+
+
+def typed_layer_extent(hop_capacities, hop: int):
+  """``(rows in, rows out, edge slots)`` — per node type, per node
+  type, per relation — of the layer whose outputs feed the nodes
+  within ``hop`` hops of the seeds, from the layout a typed sampler
+  states (`sampler.hetero_neighbor_sampler.typed_hop_capacities`): it
+  writes rows ``[0, C_hop(b))`` of every target type from rows ``[0,
+  C_{hop+1}(a))`` of the source types over each relation's edge blocks
+  ``0..hop``.  A stack deeper than the sampler keeps whole tables in
+  its first layers (`models.basic_gnn._layer_extent`)."""
+  node_caps, edge_caps = (dict(c) for c in hop_capacities)
+  hops = max((len(e) for e in edge_caps.values()), default=0)
+  hop = min(hop, hops)
+  return ({nt: c[min(hop + 1, hops)] for nt, c in node_caps.items()},
+          {nt: c[hop] for nt, c in node_caps.items()},
+          {et: e[min(hop, hops - 1)] for et, e in edge_caps.items()})
+
+
+class RGAT(nn.Module):
+  """Relational graph attention — the model of MLPerf Training's GNN
+  benchmark (R-GAT on IGBH; reference `examples/igbh/rgnn.py`): per
+  layer and relation ``(a, rel, b)`` a `GATConv` (its own projection
+  and attention vectors, softmax over a target's in-edges *within the
+  relation*), summed into the target type, ReLU after every layer, and
+  a linear head on the target type's rows.
+
+  Every node type's rows must have the same width (the relation's one
+  projection serves both ends); the input goes into layer 0 as it is,
+  cast to the compute dtype (float32 by default).  `make_conv` is the
+  one thing a sibling stack overrides, with another in-edge-local
+  conv (per-relation `SAGEConv`: `examples/igbh/train_rgnn.py`).
+
+  ``hop_capacities`` — the static per-type, per-relation hop layout a
+  typed `NeighborLoader` batch states (``metadata['hop_capacities']``,
+  handed on by the step builders' seam, `models.train.apply_to_batch`)
+  — trims the stack (its convs must be in-edge-local; another conv
+  raises when asked for a trimmed relation): layer ``l`` of
+  ``L`` reads rows ``[0, C_{L-l}(a))`` of each source type and edge
+  slots ``[:E_{L-1-l}(r)]`` of each relation and writes rows ``[0,
+  C_{L-1-l}(b))`` of each target type, so the result is
+  ``[C_0(target), out]``, the seed rows (`typed_layer_extent`;
+  `BasicGNN` says what holds of padded seed slots), with one
+  ``model.trim`` flight-recorder event per trace.  Without it, and
+  while initialising, every layer runs over whole tables and the
+  result is ``[n_target, out]``.  The parameters are the same either
+  way.
+  """
+  etypes: Tuple[EdgeType, ...]
+  hidden_features: int
+  out_features: int
+  num_layers: int = 3
+  heads: int = 4
+  target_ntype: NodeType = 'paper'
+  dtype: Optional[jnp.dtype] = None
+
+  # `__call__` accepts ``hop_capacities``
+  takes_hop_capacities = True
+
+  @nn.nowrap
+  def make_conv(self) -> nn.Module:
+    """One relation's conv; it is built inside that relation's scope
+    of the layer's `HeteroConv` (hence ``nowrap``)."""
+    assert self.hidden_features % self.heads == 0
+    return GATConv(self.hidden_features // self.heads, heads=self.heads,
+                   dtype=self.dtype)
+
+  @nn.compact
+  def __call__(self, x_dict, edge_index_dict, edge_mask_dict=None, *,
+               hop_capacities=None):
+    trim = hop_capacities is not None and not self.is_initializing()
+    with layer_scope('model', 'input'):
+      h = {nt: x.astype(self.dtype or jnp.float32)
+           for nt, x in x_dict.items()}
+    ei, em = dict(edge_index_dict), dict(edge_mask_dict or {})
+    trimmed = []
+    for i in range(self.num_layers):
+      num_dst = None
+      if trim:
+        rows_in, num_dst, slots = typed_layer_extent(
+            hop_capacities, self.num_layers - 1 - i)
+        trimmed.append((rows_in, num_dst, slots))
+        with layer_scope('model', f'layer{i}/trim'):
+          h = {nt: v[:rows_in[nt]] for nt, v in h.items()
+               if rows_in.get(nt)}
+          ei = {et: e[:, :slots[et]] for et, e in ei.items()
+                if et in slots}
+          em = {et: m[:slots[et]] for et, m in em.items() if et in slots}
+      h = HeteroConv(self.etypes, self.hidden_features,
+                     make_conv=self.make_conv, part=f'layer{i}',
+                     name=f'conv{i}')(h, ei, em, num_dst)
+      with layer_scope('model', f'layer{i}/merge'):
+        h = {nt: nn.relu(v) for nt, v in h.items()}
+    if trimmed:
+      # trace time: one event per compiled program that trims
+      rows_in, rows_out, slots = zip(*trimmed)
+      recorder.emit(
+          'model.trim', layers=len(trimmed),
+          rows_in={nt: [r[nt] for r in rows_in] for nt in rows_in[0]},
+          rows_out={nt: [r[nt] for r in rows_out] for nt in rows_out[0]},
+          edge_slots={as_str(et): [s[et] for s in slots]
+                      for et in slots[0]},
+          table_rows={nt: c[-1] for nt, c in hop_capacities[0]},
+          table_slots={as_str(et): e[-1] for et, e in hop_capacities[1]})
+    with layer_scope('model', 'head'):
+      out = nn.Dense(self.out_features, dtype=self.dtype,
+                     name='head')(h[self.target_ntype])
+    return out.astype(jnp.float32) if self.dtype is not None else out
 
 
 class HGTConv(nn.Module):

@@ -76,21 +76,25 @@ def make_extracted_supervised_step(extract: Callable,
   return supervised_step
 
 
-def _apply_with_weights(apply_fn, params, batch):
-  """One definition of "apply the model to a Batch": what the sampler
-  stated about the batch in its metadata goes on to the model (the
-  presence checks are static per pytree structure — no retrace churn).
+def apply_to_batch(apply_fn, params, batch):
+  """One definition of "apply the model to a batch" — a `Batch` or a
+  typed `HeteroBatch`: what the sampler stated about the batch in its
+  metadata goes on to the model (the presence checks are static per
+  pytree structure — no retrace churn).
 
   * ``edge_weight``, the GNS 1/q importance weights (PR 10), threads
     into the aggregation so biased sampling stays unbiased at the
     model.
-  * ``hop_capacities``, the static hop layout `NeighborSampler` states
-    (`sampler.neighbor_sampler.hop_capacities`), goes to a model that
-    declares ``takes_hop_capacities`` (`BasicGNN`, which trims each
-    layer to the hops it feeds when its convs allow it); the logits
-    are then the seed rows ``[C_0, out]``, which is all the loss and
-    the accuracy read.  Any other ``apply_fn`` and any batch without
-    the entry get the whole table, as before.
+  * ``hop_capacities``, the static hop layout `NeighborSampler`
+    (`sampler.neighbor_sampler.hop_capacities`) or
+    `HeteroNeighborSampler` (per node type and relation:
+    `sampler.hetero_neighbor_sampler.typed_hop_capacities`) states,
+    goes to a model that declares ``takes_hop_capacities`` (`BasicGNN`
+    and `RGAT`, which trim each layer to the hops it feeds when their
+    convs allow it); the logits are then the seed rows ``[C_0, out]``,
+    which is all the loss and the accuracy read.  Any other
+    ``apply_fn`` and any batch without the entry get the whole table,
+    as before.
   """
   md = getattr(batch, 'metadata', None) or {}
   kwargs = {}
@@ -101,19 +105,32 @@ def _apply_with_weights(apply_fn, params, batch):
     if (md.get('hop_capacities') is not None
         and getattr(model, 'takes_hop_capacities', False)):
       kwargs['hop_capacities'] = md['hop_capacities']
+  if hasattr(batch, 'x_dict'):
+    return apply_fn(params, batch.x_dict, batch.edge_index_dict,
+                    batch.edge_mask_dict, **kwargs)
   return apply_fn(params, batch.x, batch.edge_index, batch.edge_mask,
                   **kwargs)
 
 
-def make_supervised_step(apply_fn, tx: optax.GradientTransformation,
-                         batch_size: int):
-  """Build a jitted ``(state, batch) -> (state, loss, correct)`` step."""
-
+def _extract_of(apply_fn, target_ntype):
+  """The ``extract`` adapter of a model applied to whole batches: the
+  logits with the seed slots' labels and ids — of ``target_ntype`` in a
+  typed batch."""
   def extract(params, batch):
-    logits = _apply_with_weights(apply_fn, params, batch)
-    return logits, batch.y, batch.batch
+    logits = apply_to_batch(apply_fn, params, batch)
+    if target_ntype is None:
+      return logits, batch.y, batch.batch
+    return (logits, batch.y_dict[target_ntype],
+            batch.batch_dict[target_ntype])
+  return extract
 
-  return jax.jit(make_extracted_supervised_step(extract, tx, batch_size))
+
+def make_supervised_step(apply_fn, tx: optax.GradientTransformation,
+                         batch_size: int, target_ntype=None):
+  """Build a jitted ``(state, batch) -> (state, loss, correct)`` step;
+  ``target_ntype`` names the seeded node type of typed batches."""
+  return jax.jit(make_extracted_supervised_step(
+      _extract_of(apply_fn, target_ntype), tx, batch_size))
 
 
 def make_extracted_eval_step(extract: Callable, batch_size: int):
@@ -132,13 +149,9 @@ def make_extracted_eval_step(extract: Callable, batch_size: int):
   return eval_step
 
 
-def make_eval_step(apply_fn, batch_size: int):
-
-  def extract(params, batch):
-    logits = _apply_with_weights(apply_fn, params, batch)
-    return logits, batch.y, batch.batch
-
-  return jax.jit(make_extracted_eval_step(extract, batch_size))
+def make_eval_step(apply_fn, batch_size: int, target_ntype=None):
+  return jax.jit(make_extracted_eval_step(
+      _extract_of(apply_fn, target_ntype), batch_size))
 
 
 def unsupervised_link_loss(emb: jax.Array, metadata: dict) -> jax.Array:

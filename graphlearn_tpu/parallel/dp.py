@@ -83,13 +83,13 @@ def make_dp_supervised_step(apply_fn: Callable,
     batch = jax.tree_util.tree_map(lambda x: x[0], batch)
 
     def loss_fn(params):
-      from ..models.train import _apply_with_weights
+      from ..models.train import apply_to_batch
       # the example SAGE path: GNS batches carry metadata
       # ['edge_weight'] (PR 10 1/q weights) — threaded into the
       # aggregation so GNS-on DP training is unbiased at the model;
       # stacked `NeighborLoader` batches carry ['hop_capacities'], and
       # each device trims its own layers to the hops they feed
-      logits = _apply_with_weights(apply_fn, params, batch)
+      logits = apply_to_batch(apply_fn, params, batch)
       loss = supervised_loss(logits, batch.y, batch.batch, batch_size)
       return loss, logits
 

@@ -32,8 +32,9 @@ import numpy as np
 from ..data.graph import Graph
 from ..ops.neighbor import sample_one_hop
 from ..ops.unique import init_node, induce_next
-from ..typing import EdgeType, NodeType, reverse_edge_type
+from ..typing import EdgeType, NodeType, as_str, reverse_edge_type
 from ..utils.padding import INVALID_ID, round_up
+from ..utils.profiling import layer_scope
 from .base import (BaseSampler, HeteroSamplerOutput, NodeSamplerInput)
 
 
@@ -53,7 +54,7 @@ def normalize_fanouts(etypes: Tuple[EdgeType, ...], num_neighbors):
   return etypes, fanouts, num_hops
 
 
-def _plan_capacities(
+def _plan(
     etypes: Sequence[EdgeType],
     fanouts: Dict[EdgeType, Tuple[int, ...]],
     input_sizes: Dict[NodeType, int],
@@ -63,16 +64,19 @@ def _plan_capacities(
   """Host-side static-shape plan.
 
   Returns per-ntype table capacities, per-(hop, ntype) frontier
-  capacities, and per-(hop, etype) edge capacities — the hetero analog
+  capacities, per-(hop, etype) edge capacities — the hetero analog
   of the reference's `_max_sampled_nodes` bound
-  (`sampler/neighbor_sampler.py:595-612`).  ``input_sizes`` gives the
-  seed count per seeded node type (link sampling seeds two types).
+  (`sampler/neighbor_sampler.py:595-612`) — and per (hop, ntype) the
+  table capacity after that hop (entry 0: the seeds).  ``input_sizes``
+  gives the seed count per seeded node type (link sampling seeds two
+  types).
   """
   ntypes = sorted({t for (s, _, d) in etypes for t in (s, d)}
                   | set(input_sizes))
   frontier = {nt: int(input_sizes.get(nt, 0)) for nt in ntypes}
   frontier_caps = [dict(frontier)]
   table_cap = {nt: frontier[nt] for nt in ntypes}
+  hop_table_caps = [dict(table_cap)]
   edge_caps: List[Dict[EdgeType, int]] = []
   for h in range(num_hops):
     add = {nt: 0 for nt in ntypes}
@@ -91,9 +95,47 @@ def _plan_capacities(
       table_cap[nt] = min(table_cap[nt] + add[nt],
                           input_sizes.get(nt, 0)
                           + num_nodes.get(nt, 1 << 60))
+    hop_table_caps.append(dict(table_cap))
     edge_caps.append(ecap)
   table_cap = {nt: round_up(max(c, 1), 8) for nt, c in table_cap.items()}
-  return ntypes, table_cap, frontier_caps, edge_caps
+  return ntypes, table_cap, frontier_caps, edge_caps, hop_table_caps
+
+
+def _plan_capacities(etypes, fanouts, input_sizes, num_hops, num_nodes):
+  """`_plan`'s ``(ntypes, table_cap, frontier_caps, edge_caps)``: what
+  the jitted loops read."""
+  return _plan(etypes, fanouts, input_sizes, num_hops, num_nodes)[:4]
+
+
+def typed_hop_capacities(etypes, plan):
+  """The static layout of one `_hetero_multihop` output, the typed
+  twin of `sampler.neighbor_sampler.hop_capacities`: ``(((node type,
+  (C_0..C_L)), ...), ((emitted edge type, (E_0..E_{L-1})), ...))``,
+  cumulative table and edge-slot capacities per hop, sorted by type
+  (hashable: a batch carries it as pytree aux data).
+
+  Hop ``h`` samples relation ``(s, rel, d)`` from the nodes of ``s``
+  found by hop ``h`` (table slots below ``C_h(s)``), appends what it
+  finds to ``d``'s table (``count <= C_{h+1}(d)``) and emits its edges,
+  reversed, as block ``[E_{h-1}, E_h)`` of the relation's ``row`` /
+  ``col``; a relation not sampled at a hop has an empty block there.
+  So of the emitted relation ``(d, rev_rel, s)`` everything within
+  ``h`` hops of the seeds lives in edge slots ``[:E_h]``, with targets
+  below ``C_h(s)`` and sources below ``C_{h+1}(d)`` (what
+  `models.hetero.RGAT` trims its layers to).  ``C_L`` is a table's
+  shape, ``E_{L-1}`` the shape of a relation's ``row``.  ``plan`` is
+  `_plan`'s result, the one plan the jitted loop reads.
+  """
+  _, table_cap, _, edge_caps, hop_table_caps = plan
+  node = tuple(
+      (nt, tuple(min(c[nt], cap) for c in hop_table_caps[:-1]) + (cap,))
+      for nt, cap in sorted(table_cap.items()))
+  edge = []
+  for et in etypes:
+    slots = np.cumsum([ec.get(et, 0) for ec in edge_caps]).tolist()
+    if slots and slots[-1]:
+      edge.append((reverse_edge_type(et), tuple(slots)))
+  return node, tuple(sorted(edge))
 
 
 @functools.partial(
@@ -125,12 +167,14 @@ def _hetero_multihop(
   states = {}
   seed_locals = {}
   seed_by_type = dict(zip(seed_types, seeds_t))
-  for nt in ntypes:
-    if nt in seed_by_type:
-      states[nt], seed_locals[nt] = init_node(seed_by_type[nt], caps[nt])
-    else:
-      states[nt] = init_node(
-          jnp.full((1,), INVALID_ID, jnp.int32), caps[nt])[0]
+  with layer_scope('sample', 'dedup'):
+    for nt in ntypes:
+      if nt in seed_by_type:
+        states[nt], seed_locals[nt] = init_node(seed_by_type[nt],
+                                                caps[nt])
+      else:
+        states[nt] = init_node(
+            jnp.full((1,), INVALID_ID, jnp.int32), caps[nt])[0]
 
   # frontier windows: (start, cap) per ntype.
   fr_start = {nt: jnp.zeros((), jnp.int32) for nt in ntypes}
@@ -144,17 +188,18 @@ def _hetero_multihop(
     # Snapshot hop-start state: frontiers are nodes discovered at h-1.
     hop_start_count = {nt: states[nt].count for nt in ntypes}
     frontiers = {}
-    for nt in ntypes:
-      fcap = frontier_caps[h].get(nt, 0)
-      if fcap <= 0:
-        frontiers[nt] = None
-        continue
-      slots = fr_start[nt] + jnp.arange(fcap, dtype=jnp.int32)
-      valid = slots < hop_start_count[nt]
-      nodes = states[nt].nodes[
-          jnp.clip(slots, 0, caps[nt] - 1)]
-      frontiers[nt] = (jnp.where(valid, nodes, INVALID_ID),
-                       jnp.where(valid, slots, -1))
+    with layer_scope('sample', f'hop{h}/frontier'):
+      for nt in ntypes:
+        fcap = frontier_caps[h].get(nt, 0)
+        if fcap <= 0:
+          frontiers[nt] = None
+          continue
+        slots = fr_start[nt] + jnp.arange(fcap, dtype=jnp.int32)
+        valid = slots < hop_start_count[nt]
+        nodes = states[nt].nodes[
+            jnp.clip(slots, 0, caps[nt] - 1)]
+        frontiers[nt] = (jnp.where(valid, nodes, INVALID_ID),
+                         jnp.where(valid, slots, -1))
 
     for ei, et in enumerate(etypes):
       s, _, d = et
@@ -163,41 +208,44 @@ def _hetero_multihop(
         continue
       fr_nodes, fr_local = frontiers[s]
       indptr, indices, edge_ids = graphs[et]
-      hop_key = jax.random.fold_in(jax.random.fold_in(key, h), ei)
-      res = sample_one_hop(indptr, indices, fr_nodes, int(k), hop_key,
-                           edge_ids, with_edge_ids=with_edge,
-                           sort_locality=sort_locality)
-      states[d], rows, cols, _ = induce_next(
-          states[d], fr_local, res.nbrs, res.mask)
-      rows_acc[et].append(rows)
-      cols_acc[et].append(cols)
-      if with_edge:
-        eids_acc[et].append(
-            jnp.where(rows >= 0, res.eids.reshape(-1), INVALID_ID))
+      # one relation's draw and its dedup into the found type's table
+      with layer_scope('sample', f'hop{h}/{as_str(et)}'):
+        hop_key = jax.random.fold_in(jax.random.fold_in(key, h), ei)
+        res = sample_one_hop(indptr, indices, fr_nodes, int(k), hop_key,
+                             edge_ids, with_edge_ids=with_edge,
+                             sort_locality=sort_locality)
+        states[d], rows, cols, _ = induce_next(
+            states[d], fr_local, res.nbrs, res.mask)
+        rows_acc[et].append(rows)
+        cols_acc[et].append(cols)
+        if with_edge:
+          eids_acc[et].append(
+              jnp.where(rows >= 0, res.eids.reshape(-1), INVALID_ID))
 
     for nt in ntypes:
       fr_start[nt] = hop_start_count[nt]
       nsn[nt].append(states[nt].count)
 
-  node = {nt: states[nt].nodes for nt in ntypes}
-  node_count = {nt: states[nt].count for nt in ntypes}
-  # Emit under reversed etypes with transposed direction.
-  row_out, col_out, eid_out, emask_out = {}, {}, {}, {}
-  for et in etypes:
-    if not rows_acc[et]:
-      continue
-    rev = reverse_edge_type(et)
-    r = jnp.concatenate(rows_acc[et])
-    c = jnp.concatenate(cols_acc[et])
-    row_out[rev] = r
-    col_out[rev] = c
-    emask_out[rev] = r >= 0
-    if with_edge:
-      eid_out[rev] = jnp.concatenate(eids_acc[et])
-  num_sampled_nodes = {
-      nt: jnp.concatenate([jnp.stack(v)[:1],
-                           jnp.stack(v)[1:] - jnp.stack(v)[:-1]])
-      for nt, v in nsn.items()}
+  with layer_scope('sample', 'pack'):
+    node = {nt: states[nt].nodes for nt in ntypes}
+    node_count = {nt: states[nt].count for nt in ntypes}
+    # Emit under reversed etypes with transposed direction.
+    row_out, col_out, eid_out, emask_out = {}, {}, {}, {}
+    for et in etypes:
+      if not rows_acc[et]:
+        continue
+      rev = reverse_edge_type(et)
+      r = jnp.concatenate(rows_acc[et])
+      c = jnp.concatenate(cols_acc[et])
+      row_out[rev] = r
+      col_out[rev] = c
+      emask_out[rev] = r >= 0
+      if with_edge:
+        eid_out[rev] = jnp.concatenate(eids_acc[et])
+    num_sampled_nodes = {
+        nt: jnp.concatenate([jnp.stack(v)[:1],
+                             jnp.stack(v)[1:] - jnp.stack(v)[:-1]])
+        for nt, v in nsn.items()}
   return (node, node_count, row_out, col_out,
           eid_out if with_edge else None, emask_out, seed_locals,
           num_sampled_nodes)
@@ -231,18 +279,26 @@ class HeteroNeighborSampler(BaseSampler):
       self._num_nodes[d] = max(self._num_nodes.get(d, 0), dmax)
     self._base_key = jax.random.key(seed)
     self._step = 0
+    self._plans = {}
 
   def _next_key(self) -> jax.Array:
     self._step += 1
     return jax.random.fold_in(self._base_key, self._step)
 
+  def _planned(self, input_sizes: Dict[NodeType, int]):
+    """`_plan` for these seed counts, made once per batch shape (a
+    loader asks for the same one every step)."""
+    key = tuple(sorted(input_sizes.items()))
+    if key not in self._plans:
+      self._plans[key] = _plan(self.etypes, self.fanouts, input_sizes,
+                               self.num_hops, self._num_nodes)
+    return self._plans[key]
+
   def _run_multihop(self, seeds_by_type: Dict[NodeType, jax.Array]):
     """One fused hetero multi-hop from per-type seed sets; returns the
     raw pieces plus per-type seed-local maps."""
     input_sizes = {nt: int(s.shape[0]) for nt, s in seeds_by_type.items()}
-    ntypes, table_cap, frontier_caps, _ = _plan_capacities(
-        self.etypes, self.fanouts, input_sizes, self.num_hops,
-        self._num_nodes)
+    ntypes, table_cap, frontier_caps = self._planned(input_sizes)[:3]
     graphs = {}
     for et in self.etypes:
       g = self.graphs[et]
@@ -273,8 +329,14 @@ class HeteroNeighborSampler(BaseSampler):
         edge_mask=emask, batch={input_type: seeds},
         num_sampled_nodes=nsn,
         edge_types=[reverse_edge_type(et) for et in self.etypes],
+        # static ints, the same for every batch of a loader:
+        # `HeteroBatch` carries them as pytree aux data, not as arrays
         metadata={'seed_local': seed_locals[input_type],
-                  'input_type': input_type})
+                  'input_type': input_type,
+                  # from the plan the multi-hop program was built from
+                  'hop_capacities': typed_hop_capacities(
+                      self.etypes,
+                      self._planned({input_type: int(seeds.shape[0])}))})
 
   def sample_from_edges(self, inputs, neg_sampling=None,
                         **kwargs) -> HeteroSamplerOutput:

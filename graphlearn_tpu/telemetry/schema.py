@@ -59,10 +59,12 @@ EVENT_KINDS: Dict[str, str] = {
     'fused.compile':
         'loader.fused._counted_jit: fn, secs',
     'model.trim':
-        'models.BasicGNN at trace time, once per compiled program '
-        'that trims its layers to the hops they feed: layers, and per '
-        'layer rows_in, rows_out, edge_slots computed, beside the '
-        "batch's table_rows and table_slots (absent = untrimmed)",
+        'models.BasicGNN and models.hetero.RGAT at trace time, once '
+        'per compiled program that trims its layers to the hops they '
+        'feed: layers, and per layer rows_in, rows_out, edge_slots '
+        "computed, beside the batch's table_rows and table_slots "
+        '(absent = untrimmed); the typed model gives each as a dict, '
+        'rows per node type and slots per relation (its as_str form)',
     'span.begin':
         'telemetry.spans: name, trace_id, span_id, parent_id, pid, '
         'tid (+caller fields)',
@@ -280,8 +282,9 @@ SPAN_NAMES: Dict[str, str] = {
         'NodeLoader._produce: SamplerOutput -> Batch (feature and '
         'label lookups, pytree assembly)',
     'feature.get':
-        'loader.transform.to_data: the node-feature lookup inside '
-        'collate',
+        'loader.transform.to_data / to_hetero_data: the node-feature '
+        'lookup inside collate (typed batches: one span per node '
+        'type, field ntype)',
     'fused.seeds':
         'fused epoch drivers: host shuffle + stack (+ chunk padding) '
         'of the epoch\'s seed set and the epoch key\'s fold-in (an '

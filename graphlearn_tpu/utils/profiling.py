@@ -84,6 +84,24 @@ metrics = Metrics()
 #: first ``glt.<layer>`` token of an op's ``op_name`` (bare, or inside
 #: ``jvp(...)`` / ``transpose(jvp(...))`` for the backward pass), so a
 #: layer that is not here cannot be read back: `layer_scope` refuses it.
+#:
+#: Parts, one spelling each (``<rel>`` is `typing.as_str` of a
+#: relation, ``a__rel__b``; the typed programs name a relation or a
+#: node type where the homogeneous ones have one of each):
+#:
+#:   sample    hop<i> (a hop's draw) | hop<i>/<rel> (typed: one
+#:             relation's draw and its dedup into the found type's
+#:             table) | hop<i>/frontier | dedup | pack | key | owner |
+#:             negative
+#:   gather    level<t> | <node type> (typed `Feature.get`) | labels |
+#:             owner | mask | ids | split; bare in the homogeneous
+#:             `Feature.get`
+#:   model     input | layer<l> | layer<l>/<rel> (typed: one relation's
+#:             convolution) | layer<l>/merge (the sum into the target
+#:             types and the activation) | layer<l>/trim (typed: the
+#:             prefixes a trimmed layer reads) | head | loss | metrics
+#:   optimizer (none)
+#:   exchange  frontier | feature | pairs | grads | stats
 LAYERS: Tuple[str, ...] = ('sample', 'gather', 'model', 'optimizer',
                            'exchange')
 

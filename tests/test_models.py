@@ -349,7 +349,7 @@ _TRIM_CASES = {
 def test_trimmed_sage_matches_untrimmed(case):
   """Each layer computed only over the hops it feeds gives the seeds
   the logits, the loss and the gradients of the whole-table stack."""
-  from graphlearn_tpu.models.train import (_apply_with_weights,
+  from graphlearn_tpu.models.train import (apply_to_batch,
                                            supervised_loss)
   n, fanouts, layers, aggr, bs, which, weighted = _TRIM_CASES[case]
   loader = NeighborLoader(_skewed_dataset(n), fanouts, np.arange(min(n, 100)),
@@ -376,7 +376,7 @@ def test_trimmed_sage_matches_untrimmed(case):
                       batch.edge_mask)
 
   def loss_fn(p, b):
-    logits = _apply_with_weights(model.apply, p, b)
+    logits = apply_to_batch(model.apply, p, b)
     return supervised_loss(logits, b.y, b.batch, bs), logits
 
   (loss_t, logits_t), grads_t = jax.value_and_grad(
@@ -495,7 +495,7 @@ def test_gcn_declines_the_capacities():
   """GCN's normalisation counts a source's out-edges over the whole
   subgraph: it is handed the capacities by the seam and computes what
   it computes without them, over the whole table."""
-  from graphlearn_tpu.models.train import _apply_with_weights
+  from graphlearn_tpu.models.train import apply_to_batch
   loader = NeighborLoader(_skewed_dataset(), [5, 4, 3], np.arange(100),
                           batch_size=32, shuffle=True, seed=1)
   batch = next(iter(loader))
@@ -503,7 +503,7 @@ def test_gcn_declines_the_capacities():
   model = GCN(hidden_features=16, out_features=5, num_layers=3)
   params = model.init(jax.random.key(0), batch.x, batch.edge_index,
                       batch.edge_mask)
-  stated = _apply_with_weights(model.apply, params, batch)
+  stated = apply_to_batch(model.apply, params, batch)
   plain = model.apply(params, batch.x, batch.edge_index, batch.edge_mask)
   assert stated.shape == (batch.x.shape[0], 5)
   np.testing.assert_array_equal(np.asarray(stated), np.asarray(plain))
@@ -516,7 +516,7 @@ def test_batches_without_capacities_run_the_whole_table(source):
   other batch, and every ``apply_fn`` that is not a model's own
   ``apply``, gets the whole-table stack."""
   from graphlearn_tpu.loader.transform import Batch
-  from graphlearn_tpu.models.train import _apply_with_weights
+  from graphlearn_tpu.models.train import apply_to_batch
   model = GraphSAGE(hidden_features=8, out_features=5, num_layers=2)
   apply_fn = model.apply
   if source == 'hand-built':
@@ -552,7 +552,7 @@ def test_batches_without_capacities_run_the_whole_table(source):
     assert 'hop_capacities' not in batch.metadata
   params = model.init(jax.random.key(0), batch.x, batch.edge_index,
                       batch.edge_mask)
-  out = _apply_with_weights(apply_fn, params, batch)
+  out = apply_to_batch(apply_fn, params, batch)
   assert out.shape == (batch.x.shape[0], 5)
   np.testing.assert_array_equal(
       np.asarray(out),
