@@ -19,6 +19,7 @@ from typing import NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from ..telemetry.recorder import recorder
 from ..utils.padding import INVALID_ID
 
 
@@ -140,15 +141,29 @@ def induce_next(
     src_local: jax.Array,
     nbrs: jax.Array,
     nbr_mask: jax.Array,
+    capacity: Optional[int] = None,
 ) -> Tuple[InducerState, jax.Array, jax.Array, jax.Array]:
   """Insert newly sampled neighbors; counterpart of ``InduceNext``
   (`csrc/cuda/inducer.cu:94-141`).
+
+  What is sorted is the table AS HANDED IN plus the candidates,
+  ``state.nodes.shape[0] + B*k`` elements (one stable sort and four
+  permutation gathers, linear in that length on the v5e); what comes
+  back is a table of ``capacity`` rows.  The two are apart so that a
+  caller can grow its table insertion by insertion: handed a table of
+  the rows filled so far and asked for ``min(rows + B*k, final)``, it
+  gets the ids, counts and local indices a table held at ``final`` from
+  the start would give (the result depends on the valid elements and
+  their order only; overflow past ``capacity`` drops the
+  latest-appearing ids) from a sort that never covers the padding.
 
   Args:
     state: current node table.
     src_local: ``[B]`` local indices of the source nodes (-1 invalid).
     nbrs: ``[B, k]`` sampled neighbor global ids (-1 invalid).
     nbr_mask: ``[B, k]`` validity of each sampled neighbor.
+    capacity: static row count of the returned table; default the
+      incoming table's.
 
   Returns:
     ``(new_state, rows, cols, frontier_start)`` where ``rows``/``cols``
@@ -159,7 +174,9 @@ def induce_next(
     ``frontier_start`` is the previous node count (new frontier =
     ``state.nodes[frontier_start:new_count]``).
   """
-  capacity = state.nodes.shape[0]
+  held = state.nodes.shape[0]
+  if capacity is None:
+    capacity = held
   b, k = nbrs.shape
   flat_nbrs = nbrs.reshape(-1)
   flat_mask = nbr_mask.reshape(-1)
@@ -168,13 +185,27 @@ def induce_next(
   # then the new candidates in arrival order.
   combined = jnp.concatenate([state.nodes, flat_nbrs])
   valid = jnp.concatenate(
-      [jnp.arange(capacity) < state.count, flat_mask])
+      [jnp.arange(held) < state.count, flat_mask])
   res = unique_stable(combined, capacity, valid=valid)
 
   new_state = InducerState(nodes=res.values, count=res.count)
-  nbr_local = res.inverse[capacity:]            # [B*k]
+  nbr_local = res.inverse[held:]                # [B*k]
   src_flat = jnp.broadcast_to(src_local[:, None], (b, k)).reshape(-1)
   edge_valid = flat_mask & (src_flat >= 0) & (nbr_local >= 0)
   rows = jnp.where(edge_valid, nbr_local, -1)
   cols = jnp.where(edge_valid, src_flat, -1)
   return new_state, rows, cols, state.count
+
+
+def emit_dedup(insertions) -> None:
+  """The trace-time record of a sampler program's `induce_next` calls:
+  one ``sample.dedup`` flight-recorder event per compiled program,
+  listing per insertion ``(name, sorted, table_rows, candidates)`` —
+  the elements its sort covers, the capacity it returns and the
+  ``B*k`` it inserts.  A program whose tables start at their final
+  size (the mesh samplers) emits none."""
+  if insertions:
+    names, n_sorted, rows, candidates = zip(*insertions)
+    recorder.emit('sample.dedup', insertions=len(insertions),
+                  scope=list(names), sorted=list(n_sorted),
+                  table_rows=list(rows), candidates=list(candidates))

@@ -31,7 +31,7 @@ import numpy as np
 
 from ..data.graph import Graph
 from ..ops.neighbor import sample_one_hop
-from ..ops.unique import init_node, induce_next
+from ..ops.unique import emit_dedup, init_node, induce_next
 from ..typing import EdgeType, NodeType, as_str, reverse_edge_type
 from ..utils.padding import INVALID_ID, round_up
 from ..utils.profiling import layer_scope
@@ -157,6 +157,20 @@ def _hetero_multihop(
     with_edge: bool,
     sort_locality: bool = True,
 ):
+  """One fused typed multi-hop sample. Returns raw pytree pieces.
+
+  Every node type's table GROWS insertion by insertion: it starts at
+  its seed count (empty for a type without seeds) and the insertion of
+  relation ``(s, rel, d)`` at hop ``h`` hands `induce_next` the rows
+  ``d`` could hold so far and asks for ``min(rows + frontier_caps[h][s]
+  * k, table_caps[d])`` back, so a dedup sorts what is there plus its
+  candidates and never the padding of later hops (`hop0/cites` of the
+  IGBH shapes: 32 + 480 elements, not 134,912 + 480).  `pack` pads
+  every table to ``table_caps``; ids, counts and local indices are
+  those of tables held at that size from the start.  The relation
+  order (``etypes``) and the key schedule are part of the output; a
+  `sample.dedup` event lists the insertions at trace time.
+  """
   caps = dict(table_caps)
   fanouts = dict(zip(etypes, fanouts_t))
   frontier_caps = [dict(fc) for fc in frontier_caps_t]
@@ -169,12 +183,11 @@ def _hetero_multihop(
   seed_by_type = dict(zip(seed_types, seeds_t))
   with layer_scope('sample', 'dedup'):
     for nt in ntypes:
+      seeds = seed_by_type.get(nt, jnp.zeros((0,), jnp.int32))
+      states[nt], seed_local = init_node(seeds, seeds.shape[0])
       if nt in seed_by_type:
-        states[nt], seed_locals[nt] = init_node(seed_by_type[nt],
-                                                caps[nt])
-      else:
-        states[nt] = init_node(
-            jnp.full((1,), INVALID_ID, jnp.int32), caps[nt])[0]
+        seed_locals[nt] = seed_local
+  dedups = []   # (name, sorted, table_rows, candidates) per insertion
 
   # frontier windows: (start, cap) per ntype.
   fr_start = {nt: jnp.zeros((), jnp.int32) for nt in ntypes}
@@ -197,7 +210,7 @@ def _hetero_multihop(
         slots = fr_start[nt] + jnp.arange(fcap, dtype=jnp.int32)
         valid = slots < hop_start_count[nt]
         nodes = states[nt].nodes[
-            jnp.clip(slots, 0, caps[nt] - 1)]
+            jnp.clip(slots, 0, states[nt].nodes.shape[0] - 1)]
         frontiers[nt] = (jnp.where(valid, nodes, INVALID_ID),
                          jnp.where(valid, slots, -1))
 
@@ -214,8 +227,11 @@ def _hetero_multihop(
         res = sample_one_hop(indptr, indices, fr_nodes, int(k), hop_key,
                              edge_ids, with_edge_ids=with_edge,
                              sort_locality=sort_locality)
+        held, cands = states[d].nodes.shape[0], fr_nodes.shape[0] * int(k)
+        grown = min(held + cands, caps[d])
+        dedups.append((f'hop{h}/{as_str(et)}', held + cands, grown, cands))
         states[d], rows, cols, _ = induce_next(
-            states[d], fr_local, res.nbrs, res.mask)
+            states[d], fr_local, res.nbrs, res.mask, capacity=grown)
         rows_acc[et].append(rows)
         cols_acc[et].append(cols)
         if with_edge:
@@ -226,8 +242,13 @@ def _hetero_multihop(
       fr_start[nt] = hop_start_count[nt]
       nsn[nt].append(states[nt].count)
 
+  emit_dedup(dedups)
   with layer_scope('sample', 'pack'):
-    node = {nt: states[nt].nodes for nt in ntypes}
+    # consumers expect every table at its planned shape
+    node = {nt: jnp.concatenate([
+        states[nt].nodes,
+        jnp.full((caps[nt] - states[nt].nodes.shape[0],), INVALID_ID,
+                 states[nt].nodes.dtype)]) for nt in ntypes}
     node_count = {nt: states[nt].count for nt in ntypes}
     # Emit under reversed etypes with transposed direction.
     row_out, col_out, eid_out, emask_out = {}, {}, {}, {}

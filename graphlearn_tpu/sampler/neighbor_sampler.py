@@ -35,7 +35,7 @@ from ..ops.pallas_sample import fused_sample_enabled, sample_one_hop_auto
 from ..ops.pallas_window import prepare_window_table
 from ..ops.negative import edge_in_csr, sample_negative
 from ..ops.subgraph import induced_subgraph
-from ..ops.unique import InducerState, induce_next, init_node
+from ..ops.unique import InducerState, emit_dedup, induce_next, init_node
 from ..utils.padding import INVALID_ID, max_sampled_nodes, round_up
 from ..utils.profiling import layer_scope
 from .base import (BaseSampler, EdgeSamplerInput, NegativeSampling,
@@ -97,11 +97,13 @@ def _multihop_sample(
   """
   b = seeds.shape[0]
   # The node table GROWS hop by hop instead of starting at the final
-  # bound: `induce_next` sorts (table capacity + B*k) elements every
-  # hop, so an early hop carrying the full multi-hop capacity (~60x
-  # the live entries at hop 1 for fanout [15,10,5]) triples the total
-  # sort work for nothing.  Capacities are static per hop; the state
-  # pads up right before each hop's insertion (`hop_capacities`).
+  # bound: `induce_next` sorts (rows of the table handed in + B*k)
+  # elements, so a hop is handed the table at the capacity the hops
+  # before it could fill (`hop_capacities`, static) and asks for the
+  # next one back.  Its sort then covers every live element and none
+  # of the padding the hop is about to need: hop 2 of the flagship
+  # (batch 1024, fanout [15, 10, 5]) sorts 169,984 + 768,000 and not
+  # 937,984 + 768,000.  A `sample.dedup` event says so at trace time.
   node_caps, _ = hop_capacities(b, fanouts, node_cap)
   cap = node_caps[0]
   with layer_scope('sample', 'dedup'):
@@ -119,6 +121,7 @@ def _multihop_sample(
   rows_acc, cols_acc, eids_acc = [], [], []
   hop_node_counts = [state.count]
   hop_edge_counts = []
+  dedups = []   # (name, sorted, table_rows, candidates) per insertion
 
   for i, k in enumerate(fanouts):
     # dispatch resolves at trace time: use_fused is a static arg, so
@@ -132,17 +135,11 @@ def _multihop_sample(
           table=((win_table, win_e) if win_table is not None else None),
           use_fused=use_fused)
     with layer_scope('sample', 'dedup'):
-      new_cap = node_caps[i + 1]
-      if new_cap > cap:
-        state = InducerState(
-            nodes=jnp.concatenate([
-                state.nodes,
-                jnp.full((new_cap - cap,), INVALID_ID,
-                         state.nodes.dtype)]),
-            count=state.count)
-        cap = new_cap
+      f_cap = f_cap * int(k)        # this hop's candidates, B*k
+      dedups.append((f'hop{i}', cap + f_cap, node_caps[i + 1], f_cap))
+      cap = node_caps[i + 1]
       state, rows, cols, prev_cnt = induce_next(
-          state, frontier_local, res.nbrs, res.mask)
+          state, frontier_local, res.nbrs, res.mask, capacity=cap)
       rows_acc.append(rows)
       cols_acc.append(cols)
       if with_edge:
@@ -153,13 +150,13 @@ def _multihop_sample(
 
       # next frontier = nodes appended this hop: table slots
       # [prev, count).
-      f_cap = f_cap * int(k)
       slots = prev_cnt + jnp.arange(f_cap, dtype=jnp.int32)
       fr_valid = slots < state.count
       frontier = jnp.where(
           fr_valid, state.nodes[jnp.clip(slots, 0, cap - 1)], INVALID_ID)
       frontier_local = jnp.where(fr_valid, slots, -1)
 
+  emit_dedup(dedups)
   with layer_scope('sample', 'pack'):
     if cap < node_cap:
       # consumers expect the [node_cap] table shape

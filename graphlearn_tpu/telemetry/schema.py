@@ -65,6 +65,15 @@ EVENT_KINDS: Dict[str, str] = {
         "computed, beside the batch's table_rows and table_slots "
         '(absent = untrimmed); the typed model gives each as a dict, '
         'rows per node type and slots per relation (its as_str form)',
+    'sample.dedup':
+        'ops.unique.emit_dedup for sampler._multihop_sample and '
+        '_hetero_multihop at trace time, once per compiled program '
+        'whose node tables grow insertion by insertion: insertions, '
+        'and per induce_next call its scope (hop<i> or '
+        'hop<i>/<relation>), sorted (elements its sort covers: rows '
+        'of the table handed in + candidates), table_rows (capacity '
+        'returned) and candidates (B*k); absent = tables held at '
+        'their final size from the first hop on (the mesh samplers)',
     'span.begin':
         'telemetry.spans: name, trace_id, span_id, parent_id, pid, '
         'tid (+caller fields)',

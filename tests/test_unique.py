@@ -6,6 +6,7 @@ relabeling, capacity overflow.
 """
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from graphlearn_tpu.ops import induce_next, init_node, unique_stable
 
@@ -100,3 +101,39 @@ def test_inducer_overflow_keeps_existing_table():
   np.testing.assert_array_equal(nodes, [100, 5, 1, 2])  # 3 dropped
   # dropped neighbor's edge is masked out
   np.testing.assert_array_equal(np.asarray(rows), [2, 3, -1])
+
+
+@pytest.mark.parametrize('seeds,hops,final', [
+    (4, [(4, 3), (12, 2), (24, 2)], 88),   # never clamps: 4 + 12 + 24 + 48
+    (4, [(4, 3), (12, 2), (24, 2)], 24),   # the last two insertions clamp
+    (6, [(6, 5), (30, 1)], 8),             # overflows at the first
+    (0, [(3, 4), (12, 2)], 40),            # a table that starts empty
+])
+def test_induce_next_grown_table_equals_final_capacity(seeds, hops, final):
+  """A table handed in at the rows filled so far and asked back at
+  ``min(rows + B*k, final)`` gives, insertion for insertion, the ids,
+  count, ``rows`` and ``cols`` of a table held at ``final`` from the
+  start — overflow past ``final`` drops the latest-appearing ids in
+  both — from a sort of ``rows + B*k`` elements."""
+  rng = np.random.default_rng(seeds + final)
+  ids = jnp.asarray(rng.choice(1000, seeds, replace=False), jnp.int32)
+  grown, _ = init_node(ids, seeds)
+  whole, _ = init_node(ids, final)
+  for b, k in hops:
+    src = jnp.asarray(rng.integers(-1, 5, b), jnp.int32)
+    nbrs = jnp.asarray(rng.integers(0, 60, (b, k)), jnp.int32)
+    mask = jnp.asarray(rng.random((b, k)) < 0.8)
+    held = grown.nodes.shape[0]
+    cap = min(held + b * k, final)
+    grown, rows_g, cols_g, start_g = induce_next(grown, src, nbrs, mask,
+                                                 capacity=cap)
+    whole, rows_w, cols_w, start_w = induce_next(whole, src, nbrs, mask)
+    assert grown.nodes.shape == (cap,)
+    assert int(grown.count) == int(whole.count) == int(
+        min(whole.count, final))
+    np.testing.assert_array_equal(np.asarray(grown.nodes),
+                                  np.asarray(whole.nodes[:cap]))
+    assert (np.asarray(whole.nodes[cap:]) == -1).all()
+    np.testing.assert_array_equal(np.asarray(rows_g), np.asarray(rows_w))
+    np.testing.assert_array_equal(np.asarray(cols_g), np.asarray(cols_w))
+    assert int(start_g) == int(start_w)
