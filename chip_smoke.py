@@ -91,13 +91,67 @@ def _close(name: str, got, want) -> float:
   return err
 
 
-def build_dataset(num_nodes: int, avg_deg: int, dim: int, classes: int):
-  """The products-recipe synthetic, generated and CSR-sorted on device
-  (`benchmarks.common.build_graph_csr_device`): nothing is uploaded and
-  nothing is read from an earlier run."""
+def build_graph(num_nodes=NUM_NODES, avg_deg=AVG_DEG, seed=0):
+  """The products recipe on the host, as COO ``(rows, cols)``:
+  uniform sources, 30 % of the targets squared-uniform (hubs), the
+  rest uniform.  The mesh phase partitions it."""
+  rng = np.random.default_rng(seed)
+  n = num_nodes
+  e = n * avg_deg
+  rows = rng.integers(0, n, e, dtype=np.int64)
+  hubs = (rng.random(e) < 0.3)
+  cols = np.where(hubs,
+                  (rng.random(e) ** 2 * n).astype(np.int64),
+                  rng.integers(0, n, e, dtype=np.int64))
+  return rows, cols
+
+
+def build_graph_csr_device(num_nodes=NUM_NODES, avg_deg=AVG_DEG, seed=0):
+  """Device-side twin of `build_graph`: the same recipe (0.3 hub
+  mixture, squared-uniform hub targets) generated and CSR-sorted
+  entirely on the accelerator.  Zero host<->device transfer and no
+  host-side sort.  Statistically the host generator's graph, NOT bit
+  for bit (different RNG); same-seed calls are deterministic across
+  sessions.
+
+  Returns device ``(indptr, indices, edge_ids)`` for
+  ``Dataset.init_graph(layout='CSR')``'s device-native path.
+  """
   import jax
   import jax.numpy as jnp
-  from benchmarks.common import build_graph_csr_device
+
+  @jax.jit
+  def build(key):
+    e = num_nodes * avg_deg
+    k1, k2, k3 = jax.random.split(key, 3)
+    rows = jax.random.randint(k1, (e,), 0, num_nodes, jnp.int32)
+    hub = jax.random.uniform(k2, (e,)) < 0.3
+    u = jax.random.uniform(k3, (e,))
+    hub_cols = (u * u * num_nodes).astype(jnp.int32)
+    unif_cols = (u * num_nodes).astype(jnp.int32)
+    cols = jnp.where(hub, hub_cols, unif_cols)
+    # canonical sorted-CSR (cols ascending within each row) via
+    # two-pass stable lexsort — a fused int64 key would truncate to
+    # int32 without jax_enable_x64; the strict-negative sampler's
+    # `edge_in_csr` binary search requires the sorted form
+    by_col = jnp.argsort(cols, stable=True)
+    order = by_col[jnp.argsort(rows[by_col], stable=True)]
+    indices = cols[order]
+    rows_sorted = rows[order]
+    indptr = jnp.searchsorted(
+        rows_sorted, jnp.arange(num_nodes + 1, dtype=jnp.int32),
+        side='left').astype(jnp.int32)
+    return indptr, indices, order.astype(jnp.int32)
+
+  return build(jax.random.key(seed))
+
+
+def build_dataset(num_nodes: int, avg_deg: int, dim: int, classes: int):
+  """The products-recipe synthetic, generated and CSR-sorted on device
+  (`build_graph_csr_device`): nothing is uploaded and nothing is read
+  from an earlier run."""
+  import jax
+  import jax.numpy as jnp
   from graphlearn_tpu.data import Dataset
   indptr, indices, _ = build_graph_csr_device(num_nodes, avg_deg)
   kf, kl = jax.random.split(jax.random.key(7))
@@ -363,7 +417,6 @@ def mesh_phase(num_devices: int, *, num_nodes, avg_deg, dim, classes,
   fused tree steps, then the repo's multi-chip dry run."""
   import jax
   import optax
-  from benchmarks.common import build_graph
   from graphlearn_tpu.models import (GraphSAGE, TreeSAGE,
                                      create_train_state)
   from graphlearn_tpu.parallel import (DistDataset, DistNeighborLoader,
@@ -373,7 +426,7 @@ def mesh_phase(num_devices: int, *, num_nodes, avg_deg, dim, classes,
                                        make_mesh, replicate)
   from graphlearn_tpu.parallel.exchange import resolve_layout
   import __graft_entry__
-  rows, cols = build_graph(num_nodes, avg_deg, cache=False)
+  rows, cols = build_graph(num_nodes, avg_deg)
   rng = np.random.default_rng(7)
   feats = rng.random((num_nodes, dim), np.float32)
   labels = rng.integers(0, classes, num_nodes).astype(np.int32)

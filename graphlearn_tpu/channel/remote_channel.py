@@ -71,7 +71,7 @@ class RemoteReceivingChannel(ChannelBase):
     self._received = 0
     self._epoch = -1
     self._seen_seqs: set = set()
-    self.duplicates_discarded = 0    # run-total, for tests/telemetry
+    self.duplicates_discarded = 0    # run-total, for tests + telemetry
 
   def _replace_discarded(self, msg) -> None:
     """A discarded message (stale epoch or replay duplicate) consumed

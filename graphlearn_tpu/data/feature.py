@@ -111,9 +111,9 @@ class Feature:
                dtype=None, cold_cache_rows='auto'):
     if isinstance(feature_array, jax.Array):
       # device-native construction (tables produced on device — e.g.
-      # `benchmarks/common.build_products_device`): the array IS the
-      # hot tier; pulling it to host just to re-upload would cost a
-      # full d2h + h2d round trip of the table.
+      # `chip_smoke.build_dataset`): the array IS the hot tier;
+      # pulling it to host just to re-upload would cost a full
+      # d2h + h2d round trip of the table.
       if float(split_ratio) != 1.0:
         raise ValueError('device-resident feature input requires '
                          'split_ratio == 1.0 (a cold tier lives on '

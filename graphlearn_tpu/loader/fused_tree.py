@@ -178,9 +178,9 @@ class FusedTreeEpoch(_SupervisedScanEpoch):
 
   def _expand(self, seeds: jax.Array, key: jax.Array, dev: dict,
               use_pallas: bool):
-    # no sort: the tree gather is rate-bound by rows/s either way (r5
-    # roofline), and the locality sort is the subgraph sampler's
-    # dominant device cost
+    # no sort: what a sorted frontier is worth to this program is not
+    # measured on the chip (ROADMAP S3), and turning it on changes
+    # every per-seed draw
     levels, masks = expand_tree_levels(dev['indptr'], dev['indices'],
                                        seeds, key, self.fanouts,
                                        sort_locality=False)

@@ -4,10 +4,11 @@ sampler's native window structure.
 The subgraph path (`models.conv.SAGEConv` on a deduped node table)
 matches the reference's PyG consumption model
 (`examples/train_sage_ogbn_products.py` via PyG ``SAGEConv``), but its
-aggregation is a `segment_sum` — an XLA scatter, measured at ~2/3 of
-the whole train step on v5e at products scale (r5 decomposition:
-205 ms of a ~440 ms fused step was the model, dominated by
-scatter-add over ~938k edge slots, fwd AND bwd).
+aggregation is a `segment_sum` — an XLA scatter.  On one v5e at the
+flagship's widths the per-batch step spends 45.3 of its 147.7 ms in
+that model, 20.2 of them in layer 0's two scatter-adds over ~938k
+edge slots; this layout's whole model is 7.1 ms of a 37.3 ms fused
+step (PERF.md section 5).
 
 TPUs want streams, not scatters.  Multi-hop sampling already produces
 a STATIC tree: level ``t`` holds ``B * k_1 * ... * k_t`` slots, and

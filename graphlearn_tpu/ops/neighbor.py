@@ -90,9 +90,9 @@ def sample_one_hop(
     replace: force with-replacement draws for every ``deg > k`` row
       (skips the window gather entirely — cheaper, more approximate).
     sort_locality: process seeds in sorted-id order internally (outputs
-      restored to input order) — adjacent CSR rows share HBM pages, so
-      the window gathers run ~25% faster on large graphs (measured on
-      v5e at products scale).  Distribution-identical; per-seed draws
+      restored to input order) — adjacent CSR rows share HBM pages;
+      what that is worth to the window gathers is not measured on the
+      chip (ROADMAP S3).  Distribution-identical; per-seed draws
       differ from the unsorted order.
   """
   if sort_locality and seeds.shape[0] > 1:

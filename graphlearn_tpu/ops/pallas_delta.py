@@ -35,9 +35,8 @@ parity, stamping a ``pallas.fallback`` event.
 Roofline note (r19): the rank kernel is compare-bound, O(L^2) per
 row over VMEM-resident tiles vs the host loop's O(L log L) serial
 passes + interpreter overhead per row; the win is batching every
-dirty row into one launch, not asymptotics — re-measure on hardware
-via `benchmarks/bench_pallas_sample.py` (delta-merge events/s row)
-before defaulting it on.
+dirty row into one launch, not asymptotics — not measured on the
+chip; measure it there before defaulting it on (ROADMAP D2).
 """
 from __future__ import annotations
 

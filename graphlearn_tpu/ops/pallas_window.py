@@ -17,8 +17,8 @@ matches `xla_window_gather` on the products-scale CSR at B=1024/w=120
 and B=15360/w=80; at B=153600/w=64 the compiler refuses it — its two
 ``[B]`` scalar-prefetch vectors need 1.17 MB of the 1 MB SMEM — and
 this function has no guard for that, so the compiler's message is what
-the caller sees.  ``benchmarks/bench_pallas_window.py`` is the speed
-comparison; it has not been run on today's code (ROADMAP S1, D2).
+the caller sees.  Its speed against `xla_window_gather` is not
+measured on the chip (ROADMAP S3, D2).
 """
 from __future__ import annotations
 
@@ -136,7 +136,8 @@ def _window_dma(ind2d: jax.Array, starts: jax.Array, *, w: int,
 @functools.partial(jax.jit, static_argnames=('w',))
 def xla_window_gather(indices: jax.Array, starts: jax.Array,
                       w: int) -> jax.Array:
-  """The sampler's current window access, isolated for the bench."""
+  """The sampler's current window access, isolated: the kernel's
+  reference twin."""
   e = indices.shape[0]
   pos = jnp.clip(starts[:, None].astype(jnp.int32)
                  + jnp.arange(w, dtype=jnp.int32)[None, :],

@@ -687,8 +687,8 @@ class DistDataset:
     self.host_parts = (np.asarray(host_parts, np.int64)
                        if host_parts is not None else None)
     #: placement identity ('range' | 'locality' | 'custom' |
-    #: 'explicit') — benchmark artifacts record it so regression
-    #: baselines never compare rows across partitioner changes.
+    #: 'explicit') — recorded so that runs are never compared across
+    #: a partitioner change.
     self.partitioner = 'explicit'
     self._partition_book = None
     #: ISSUE 15: durably re-loaded shards parked by `failover.

@@ -149,8 +149,7 @@ class _BookPlan:
   per-range positions) — the property that makes adopted epochs
   byte-identical to fault-free runs (`partition_book` module
   docstring).  Dense-style: post-adoption exchanges rebuild onto this
-  plan whatever layout the identity book ran (documented in
-  benchmarks/README "Elastic failover").
+  plan whatever layout the identity book ran.
   """
 
   layout = 'book'
@@ -611,7 +610,7 @@ def resolve_exchange_slack(exchange_slack, shuffle: bool):
 #: bite under the compact/hier layouts (the dense layout's
 #: `MIN_EXCHANGE_CAP` floor dominates their caps) — they are what
 #: lets the ladder keep reclaiming padding on drop-free workloads
-#: instead of pinning at 1.25 with 80%+ waste (the r5 envelope).
+#: instead of pinning at 1.25.
 SLACK_LADDER = (0.75, 1.0, 1.25, 1.5, 2.0, 3.0, None)
 
 #: tightest rung the ladder may reach by default (override per
@@ -1337,7 +1336,7 @@ class ExchangeTelemetry:
     self._attr_total: Optional[np.ndarray] = None
     self._attr_reported = (0, 0)
     # host-side cold-tier counters (tiered feature stores only).
-    # Definitions (benchmarks/README "Cold-tier metrics"):
+    # Definitions:
     #   lookups      = valid node-table feature lookups;
     #   cold_lookups = lookups past the owner's hot count (the cold
     #                  tier's demand — the cache denominator);
@@ -1463,8 +1462,7 @@ class ExchangeTelemetry:
     # hot_hit_rate: fraction of feature lookups the HBM hot tier
     # served (what r5's "cold_hit_rate" actually measured);
     # cache/cold_hit_rate: fraction of COLD lookups served on-device
-    # by the victim cache — each miss is host-gather work.  See
-    # benchmarks/README "Cold-tier metrics".
+    # by the victim cache — each miss is host-gather work.
     out['dist.feature.hot_hit_rate'] = (
         1.0 - cold_lookups / lookups if lookups else 1.0)
     out['dist.feature.cache_hit_rate'] = (
@@ -1538,7 +1536,7 @@ class ExchangeTelemetry:
     not given).  ``hot_ranges`` prefers the GNS sketches' decayed
     range mass (the learned hotness); without an active sketch it
     falls back to the attribution matrix's column mass — measured
-    demand per range (benchmarks/README "Fleet signal plane").
+    demand per range.
     """
     fr, ft = self.attribution_matrices()
     p = int(fr.shape[0])
@@ -2530,8 +2528,8 @@ class DistNeighborSampler(ExchangeTelemetry):
       self._cache_admits += admits
       self._cache_evicts += evicts
     if cache is not None:
-      # cache-off runs (GLT_COLD_CACHE_ROWS=0, the static-split bench
-      # baseline) must not record phantom cache.miss traffic — cold
+      # cache-off runs (GLT_COLD_CACHE_ROWS=0, the static split)
+      # must not record phantom cache.miss traffic — cold
       # service without a cache is already visible as cold_misses
       emit_cache_events('dist', hits, served, admits, evicts)
     return x

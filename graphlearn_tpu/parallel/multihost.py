@@ -2,7 +2,7 @@
 
 The reference scales out by launching one process group per machine
 with torch RPC worlds knitted over TCP/RDMA (`distributed/rpc.py:
-236-292`, `run_dist_bench.py` ssh fan-out).  JAX is single-controller
+236-292`, an ssh fan-out launcher).  JAX is single-controller
 per host: every host runs the SAME program, `jax.distributed`
 initializes the cross-host runtime, and the mesh spans all hosts'
 devices — collectives ride ICI within a slice and DCN across slices

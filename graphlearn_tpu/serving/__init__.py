@@ -22,13 +22,12 @@ admits only warm, verified replicas; scale-in drains and retires the
 coldest), and `parallel.handoff` moves partition ownership planned —
 fence then one-bump cutover, zero degraded window.
 
-Knobs: ``GLT_SERVING_BUCKETS``, ``GLT_SERVING_MAX_WAIT_MS``,
-``GLT_SERVING_QUEUE_DEPTH``, ``GLT_SERVING_DEADLINE_MS``
-(benchmarks/README "Online serving (r9)"); ``GLT_AOT_CACHE_DIR``,
+Knobs (one row each in KNOBS.md): ``GLT_SERVING_BUCKETS``,
+``GLT_SERVING_MAX_WAIT_MS``, ``GLT_SERVING_QUEUE_DEPTH``,
+``GLT_SERVING_DEADLINE_MS``, ``GLT_AOT_CACHE_DIR``,
 ``GLT_FLEET_HEARTBEAT_MS``, ``GLT_FLEET_OVERLOAD_RATIO``,
-``GLT_SERVING_DRAIN_RETRY_MS`` ("Fleet serving & failover (r14)");
-``GLT_SCALE_*``, ``GLT_FLEET_FLAP_WINDOW_S`` ("Elastic autoscaling &
-planned handoff (r20)").
+``GLT_SERVING_DRAIN_RETRY_MS``, ``GLT_SCALE_*``,
+``GLT_FLEET_FLAP_WINDOW_S``.
 """
 from .admission import (AdmissionController, AdmissionRejected,
                         ServingFuture)

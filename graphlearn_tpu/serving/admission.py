@@ -31,8 +31,7 @@ import threading
 import time
 from typing import List, Optional
 
-#: env knobs (documented in benchmarks/README "Online serving (r9)" +
-#: "Fleet serving & failover (r14)")
+#: env knobs (one row each in KNOBS.md)
 QUEUE_DEPTH_ENV = 'GLT_SERVING_QUEUE_DEPTH'
 DEADLINE_ENV = 'GLT_SERVING_DEADLINE_MS'
 DRAIN_RETRY_ENV = 'GLT_SERVING_DRAIN_RETRY_MS'

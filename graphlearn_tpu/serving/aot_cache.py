@@ -47,7 +47,8 @@ from typing import Any, Callable, Dict, Optional
 AOT_CACHE_DIR_ENV = 'GLT_AOT_CACHE_DIR'
 
 #: entry format version — bumped on layout change, stale-skips old files
-_FORMAT = 1
+#: (2: entries name the devices their executable runs on)
+_FORMAT = 2
 
 
 def cache_dir_from_env() -> Optional[str]:
@@ -144,9 +145,15 @@ class AotExecutableCache:
       if hashlib.sha256(payload).hexdigest() != rec.get('sha256'):
         miss('corrupt')
         return None
+      import jax
       from jax.experimental import serialize_executable
+      # load onto the devices the program was compiled for: the
+      # default is EVERY visible device, and an executable loaded
+      # over eight devices refuses a one-device call
+      by_id = {d.id: d for d in jax.devices()}
       fn = serialize_executable.deserialize_and_load(
-          payload, rec['in_tree'], rec['out_tree'])
+          payload, rec['in_tree'], rec['out_tree'],
+          execution_devices=[by_id[i] for i in rec['device_ids']])
     except Exception:               # noqa: BLE001 — bad payload,
       # moved jax internals, foreign device set: recompile, never
       # crash the warmup (and never run a questionable executable)
@@ -184,6 +191,8 @@ class AotExecutableCache:
              'sha256': hashlib.sha256(payload).hexdigest(),
              'payload': payload_out,
              'in_tree': in_tree, 'out_tree': out_tree,
+             'device_ids': [d.id for d in compiled
+                            .runtime_executable().local_devices()],
              'saved_at': time.time()}
       tmp.write_bytes(pickle.dumps(rec, protocol=5))
       os.replace(tmp, path)

@@ -40,8 +40,8 @@ victim is un-drained, a postmortem bundle is dumped — and RE-ARMS:
 the failed direction's cooldown is not spent, so the next evaluation
 retries immediately.
 
-Knobs (benchmarks/README "Elastic autoscaling & planned handoff
-(r20)"): ``GLT_SCALE_EVAL_S``, ``GLT_SCALE_COOLDOWN_S``,
+Knobs (one row each in KNOBS.md): ``GLT_SCALE_EVAL_S``,
+``GLT_SCALE_COOLDOWN_S``,
 ``GLT_SCALE_MIN`` / ``GLT_SCALE_MAX``, ``GLT_SCALE_OUT_BURN`` /
 ``GLT_SCALE_IN_BURN``.
 """

@@ -15,7 +15,7 @@ Latency anatomy of one request (all spans/events in the flight
 recorder): queue wait (bounded by max-wait + the in-flight dispatch),
 ``serving.infer`` span (the device dispatch + tiered host fill),
 demux.  ``serving.request`` events carry the end-to-end
-``latency_ms`` the bench's percentile table is built from.
+``latency_ms`` the report's percentile table is built from.
 
 Coalescing is a LATENCY/THROUGHPUT dial, not a correctness one:
 ``GLT_SERVING_MAX_WAIT_MS=0`` degrades to serve-every-request-alone

@@ -37,14 +37,13 @@ invisible to callers:
     replica; only when EVERY replica refuses does the rejection reach
     the caller (with the draining arm's ``retry_after_ms`` hint).
 
-Chaos site ``serving.replica`` (kill / delay / flap) drives the
-kill-one-replica-mid-bench acceptance run (`bench_serving --fleet`).
+Chaos site ``serving.replica`` (kill / delay / flap) kills, stalls or
+flaps one replica under traffic (`tests/test_fleet.py`).
 
-Knobs: ``GLT_FLEET_HEARTBEAT_MS`` (monitor cadence),
-``GLT_FLEET_OVERLOAD_RATIO`` (queue-depth fraction classified
-overloaded) — benchmarks/README "Fleet serving & failover (r14)" —
-and ``GLT_FLEET_FLAP_WINDOW_S`` (the flap-damping window,
-benchmarks/README "Elastic autoscaling & planned handoff (r20)").
+Knobs (one row each in KNOBS.md): ``GLT_FLEET_HEARTBEAT_MS`` (monitor
+cadence), ``GLT_FLEET_OVERLOAD_RATIO`` (queue-depth fraction
+classified overloaded) and ``GLT_FLEET_FLAP_WINDOW_S`` (the
+flap-damping window).
 """
 from __future__ import annotations
 

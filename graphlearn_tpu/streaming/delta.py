@@ -172,7 +172,7 @@ class StreamingGraph:
     indptr/indices/edge_ids: the base CSR (canonical sorted form —
       build through `CSRTopo`/`coo_to_csr` first).
     num_nodes: fixed node universe (edge inserts only — ISSUE 14;
-      node inserts are follow-on work, see benchmarks/README r15).
+      node inserts are follow-on work).
     reserve_edges: floor for the padded device-indices capacity; size
       it to the expected growth so steady-state ingest publishes at
       ONE shape and the warm serving executables stay warm.

@@ -1,4 +1,4 @@
-"""Structured telemetry plane: flight recorder, mesh aggregation, sinks.
+"""Structured telemetry plane: flight recorder, mesh aggregation, spans.
 
 What is on which clock:
 
@@ -19,7 +19,7 @@ around a few dispatches, open ``dir`` in xprof, filter ops by ``glt.``.
 The reference has NO tracing/profiling subsystem (SURVEY §5: wall-clock
 prints in benchmarks only); `utils/profiling.py` grew the first counters
 and xprof hooks, and this package grows them into a real layer with
-three pieces:
+two pieces:
 
   * :mod:`~graphlearn_tpu.telemetry.recorder` — a bounded, thread-safe
     JSON-lines "flight recorder" (`EventRecorder` / the global
@@ -37,14 +37,8 @@ three pieces:
     the existing collective plane so the distributed engines report
     CLUSTER-wide padding-waste / drop-rate / throughput instead of
     host-0-only numbers (`DistNeighborSampler.cluster_exchange_stats`).
-  * :mod:`~graphlearn_tpu.telemetry.sink` — the file-based bench
-    artifact sink: the full artifact JSON goes to ``BENCH_ARTIFACT.json``
-    (``GLT_BENCH_ARTIFACT`` overrides) and stdout carries only a short
-    summary line, so a driver that tails the last 2000 characters can
-    never truncate the artifact (the ``"parsed": null`` failure
-    mode).
 
-On top of the recorder sits the CAUSAL layer (this PR's tentpole):
+On top of the recorder sits the CAUSAL layer:
 
   * :mod:`~graphlearn_tpu.telemetry.spans` — ``span()`` context
     manager — the ONE host span primitive: a
@@ -63,24 +57,19 @@ On top of the recorder sits the CAUSAL layer (this PR's tentpole):
     trace-event JSON (Perfetto-loadable), and the
     ``python -m graphlearn_tpu.telemetry.report`` per-stage latency
     table / trace-diff CLI.
-  * :mod:`~graphlearn_tpu.telemetry.regress` — the bench regression
-    gate (`bench.py --check-regression`): artifact vs committed
-    ``BENCH_BASELINE.json``, nonzero exit + per-metric report on a
-    threshold breach.
   * :mod:`~graphlearn_tpu.telemetry.schema` — the registry of event
     kinds and span names the static schema test enforces.
 
 xprof integration: :func:`step_annotation` wraps
 `jax.profiler.StepTraceAnnotation` so fused-epoch dispatches show up as
-steps on the TensorBoard timeline; ``bench.py --trace-dir DIR`` captures
-a trace around the fused session.
+steps on the TensorBoard timeline.
 
 The LIVE ops plane (ISSUE 12) sits beside the offline stack:
 
   * :mod:`~graphlearn_tpu.telemetry.live` — the declared live-metric
     registry (`LiveRegistry` / the global :data:`live`): counters and
     log2 histograms writing through the shared `Metrics` store (one
-    vocabulary with the offline artifact and `gather_metrics`), plus
+    vocabulary with `gather_metrics`), plus
     scrape-time gauges and health providers.
   * :mod:`~graphlearn_tpu.telemetry.opsserver` — the per-process HTTP
     ops endpoint (``/metrics`` Prometheus text, ``/varz`` JSON,
@@ -138,8 +127,6 @@ from .live import (LiveRegistry, live, parse_prometheus_text,
 from .memaccount import TIERS, CapacityModel, register_tier
 from .opsserver import OpsServer, maybe_start_from_env
 from .recorder import EventRecorder, recorder
-from .sink import (artifact_path, append_record, summary_line,
-                   write_artifact)
 from .slo import SloTracker
 from .spans import SpanContext, span
 from .timeseries import TimeSeriesStore
@@ -148,12 +135,10 @@ from .tracing import Tracer, child_ctx, spans_to_events, tracer
 __all__ = [
     'CapacityModel', 'EventRecorder', 'FleetScraper', 'Histogram',
     'LiveRegistry', 'Metrics', 'OpsServer', 'SloTracker',
-    'SpanContext', 'TIERS', 'TimeSeriesStore', 'Tracer',
-    'append_record', 'artifact_path', 'capture', 'child_ctx',
-    'exchange_summary', 'from_snapshot', 'gather_metrics', 'live',
+    'SpanContext', 'TIERS', 'TimeSeriesStore', 'Tracer', 'capture',
+    'child_ctx', 'exchange_summary', 'from_snapshot', 'gather_metrics', 'live',
     'maybe_start_from_env', 'metrics', 'parse_prometheus_text',
     'per_hop_padding', 'recorder', 'register_tier', 'span',
     'spans_to_events', 'split_exemplar', 'start_trace',
-    'step_annotation', 'stop_trace', 'summary_line', 'tracer',
-    'write_artifact',
+    'step_annotation', 'stop_trace', 'tracer',
 ]

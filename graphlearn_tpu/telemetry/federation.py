@@ -20,8 +20,8 @@ the families into one federated view:
 
 The merged exposition is what the `OpsServer` ``/fleet`` route serves
 (``?format=json`` for the health rollup), and it stays strictly
-parseable by `live.parse_prometheus_text` — the acceptance check the
-fleet bench runs mid-traffic.
+parseable by `live.parse_prometheus_text` (`tests/test_federation.py`
+holds it to that).
 
 Each replica's exposition is rendered from ONE snapshot on the
 replica side, so per-replica histogram bucket/count pairs are

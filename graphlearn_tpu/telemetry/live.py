@@ -14,10 +14,9 @@ incident (`telemetry.opsserver` binds them to ``/metrics`` /
 
   * **counters** write through to the backing `Metrics` registry
     under their declared name (plus an optional ``{k=v}`` label
-    suffix), so `gather_metrics`, the bench artifact and
-    ``report --metrics-json`` consume them unchanged — one metrics
-    vocabulary for the offline artifact, the regression gate and the
-    fleet scrape.  Declaring an EXISTING key (``dist.feature.cache_hits``)
+    suffix), so `gather_metrics` and ``report --metrics-json``
+    consume them unchanged — one metrics vocabulary for the offline
+    report and the fleet scrape.  Declaring an EXISTING key (``dist.feature.cache_hits``)
     simply exposes it on the scrape; the tick sites don't move.
   * **histograms** reuse the log2 bucket layout of
     `telemetry.histogram` (flat ``span.<name>.hist.*`` keys, recorded
@@ -446,8 +445,7 @@ def parse_prometheus_text(text: str) -> Dict[str, float]:
   """Strictly parse a Prometheus text exposition into
   ``{sample_name_with_labels: value}``; raises ``ValueError`` on the
   first malformed line.  The acceptance validator for the ops
-  endpoint (and the bench's mid-run scrape check) — deliberately
-  small, not a Prometheus client.  OpenMetrics exemplar suffixes on
+  endpoint — deliberately small, not a Prometheus client.  OpenMetrics exemplar suffixes on
   bucket samples are accepted (and dropped — exemplars are trace
   pointers, not sample values)."""
   out: Dict[str, float] = {}

@@ -38,9 +38,9 @@ so they are consistent but never hold a hot-path lock across I/O.
 
 Enable with ``GLT_OPS_PORT`` (**0 = disabled, the default** — the
 data plane is byte-identical with the plane off).
-`maybe_start_from_env` is called by `DistServer`, the
-`ServingFrontend` and the bench drivers; the first caller binds, the
-rest share the process singleton.
+`maybe_start_from_env` is called by `DistServer` and the
+`ServingFrontend`; the first caller binds, the rest share the process
+singleton.
 """
 from __future__ import annotations
 
@@ -251,7 +251,7 @@ def maybe_start_from_env() -> Optional[OpsServer]:
   """Start (or return) the process-global ops server per
   ``GLT_OPS_PORT``; None when disabled (0/unset — the default, under
   which the data plane is byte-identical to having no ops plane at
-  all).  Called by every server/frontend/bench entry point;
+  all).  Called by every server/frontend entry point;
   idempotent, first caller binds.  Also chains the post-mortem
   fatal-signal handler when ``GLT_POSTMORTEM_DIR`` is set — the two
   halves of "observable during the incident"."""

@@ -1,8 +1,8 @@
 """Post-mortem flight-recorder bundles — the data plane's black box.
 
 A `MeshStallError`, an irrecoverable worker pool, a serving-executor
-fault or a fatal signal today leaves NO artifact unless a bench
-harness happened to be tee'ing the recorder to a file; the operator's
+fault or a fatal signal leaves NO artifact unless something happened
+to be tee'ing the recorder to a file; the operator's
 first question ("what was in flight?") is unanswerable after the
 process dies.  With ``GLT_POSTMORTEM_DIR`` set, :func:`dump` writes
 one self-contained timestamped JSON bundle at the moment of death:

@@ -17,7 +17,7 @@ Modes:
     counter/gauge delta table instead — changed keys with Δ and
     per-second rate over the snapshots' wall-clock gap.
   * ``--attribution FILE``: render per-partition traffic attribution
-    (`DistNeighborSampler.attribution_stats` JSON, a bench envelope
+    (`DistNeighborSampler.attribution_stats` JSON, an envelope
     row carrying an ``attribution`` block, or a records JSONL holding
     one): the P×P src-device → dst-range byte matrix, the locality
     summary, padding-waste-by-layout when the envelope's ``layouts``
@@ -172,9 +172,7 @@ def format_resilience_table(events) -> str:
 
 def nearest_rank(sorted_vals, p: float):
   """Nearest-rank quantile over PRE-SORTED values (``None`` on
-  empty).  ONE definition shared by this report CLI and
-  `benchmarks/bench_serving.py`, so the bench's regression-guarded
-  p99 and the trace report's p99 can never silently diverge."""
+  empty): the report CLI's one definition of a percentile."""
   if not sorted_vals:
     return None
   i = min(int(p * (len(sorted_vals) - 1) + 0.5), len(sorted_vals) - 1)

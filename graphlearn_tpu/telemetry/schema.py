@@ -116,8 +116,8 @@ EVENT_KINDS: Dict[str, str] = {
     'serving.request':
         'serving.frontend executor, one per de-multiplexed request: '
         'seeds, bucket, coalesced (requests in the dispatch), ok, '
-        'latency_ms (arrival -> resolve; the percentile-table and '
-        'bench p50/p95/p99 source), error when ok=False',
+        'latency_ms (arrival -> resolve; the percentile-table '
+        'source), error when ok=False',
     'serving.coalesce':
         'serving.frontend executor, one per coalesced dispatch: '
         'requests, seeds, bucket (chosen capacity), waited_ms since '
@@ -359,9 +359,9 @@ SPAN_NAMES: Dict[str, str] = {
 #: pass above.  The value is ``'<type>: <doc>'`` where type is one of
 #: ``counter`` / ``gauge`` / ``histogram`` (the pass also checks the
 #: registration call matches the declared type).  This table is the
-#: ONE metrics vocabulary the offline artifact, the regression gate
-#: and the fleet `/metrics` scrape share; an undeclared metric is a
-#: dashboard panel nobody can discover.
+#: ONE metrics vocabulary `gather_metrics` and the fleet `/metrics`
+#: scrape share; an undeclared metric is a dashboard panel nobody can
+#: discover.
 METRIC_NAMES: Dict[str, str] = {
     'ops.scrapes_total':
         'counter: opsserver — HTTP requests answered by the ops '
@@ -426,8 +426,7 @@ METRIC_NAMES: Dict[str, str] = {
     'cache.evicts_total':
         'counter: residents displaced by admissions, by scope',
     'cache.hit_rate':
-        'gauge: hits/(hits+misses) summed across cache scopes — the '
-        'live twin of the bench cache_hit_rate',
+        'gauge: hits/(hits+misses) summed across cache scopes',
     'cache.hbm_served_rate':
         'gauge: 1 - cold_misses/lookups from the dist feature '
         'counters — total fraction of feature lookups served from '

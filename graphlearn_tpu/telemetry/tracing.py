@@ -134,8 +134,8 @@ class Tracer:
   def configure(self, sample: Optional[int] = None,
                 slow_ms: Optional[float] = None,
                 buffer: Optional[int] = None) -> None:
-    """(Re)apply knobs; None re-reads the environment — tests and the
-    bench driver flip sampling without rebuilding the global."""
+    """(Re)apply knobs; None re-reads the environment — tests flip
+    sampling without rebuilding the global."""
     if sample is None:
       sample = _env_int(TRACE_SAMPLE_ENV, 0)
     if slow_ms is None:

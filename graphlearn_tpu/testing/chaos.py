@@ -585,7 +585,7 @@ def serving_request_check(op: str = '', replica: str = '') -> None:
   server-side request loss — the replay cache still answers any
   transport retry of the same request id verbatim).  ``replica``
   carries the frontend's fleet name (when it has one), so a plan can
-  stall ONE replica's dispatches — how the fleet bench backs its
+  stall ONE replica's dispatches — how a fleet test backs its
   victim up with real in-flight requests before killing it."""
   for f in on('serving.request', op=op or None,
               replica=replica or None):

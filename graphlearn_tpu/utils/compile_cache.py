@@ -1,8 +1,8 @@
 """Where JAX's persistent compilation cache lives — decided in ONE place.
 
-Entry-point scripts (`chip_smoke.py`, the `bench.py` workers,
-`benchmarks/*.py`) call `enable_compile_cache()` once, after choosing
-their platform and before their first compile.  The library never
+Entry-point scripts (`chip_smoke.py`, `chipbench/run.py`) call
+`enable_compile_cache()` once, after choosing their platform and
+before their first compile.  The library never
 calls it at import, and the test suite never calls it at all: a
 process that did not ask for a cache has none.
 

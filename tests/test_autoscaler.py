@@ -9,13 +9,8 @@ only after quiesce — a quiesce timeout un-drains and keeps it;
 min/max bounds are hard stops; the hysteresis band between in_burn
 and out_burn decides nothing.  Plus the `SloTracker` idle contract
 the controller's first post-scale-out evaluation depends on (empty /
-idle / zero-budget windows read burn 0.0, never NaN or stale), and
-the open-loop client side of draining: `pace_schedule` resubmits
-``retry_after_ms``-hinted drain sheds instead of counting them.
+idle / zero-budget windows read burn 0.0, never NaN or stale).
 """
-import os
-import sys
-
 import pytest
 
 from graphlearn_tpu.serving.autoscaler import (ElasticController,
@@ -24,8 +19,6 @@ from graphlearn_tpu.telemetry.live import LiveRegistry
 from graphlearn_tpu.telemetry.slo import SloTracker
 from graphlearn_tpu.testing import chaos
 
-sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))), 'benchmarks'))
 
 
 # -- scripted fleet ---------------------------------------------------------
@@ -288,39 +281,3 @@ def test_zero_budget_and_zero_target_read_burn_zero():
       assert t.window_stats(1.0)['burn_rate'] == 0.0
     finally:
       t.close()
-
-
-# -- the open-loop client side of draining ----------------------------------
-
-def test_pace_schedule_resubmits_drain_sheds():
-  """Satellite 1: a ``reason='draining'`` refusal with a
-  ``retry_after_ms`` hint is resubmitted after the hint, not counted
-  a shed — every request lands once the drain window passes."""
-  import time as _time
-  from bench_serving import pace_schedule
-  from graphlearn_tpu.serving import AdmissionRejected
-
-  t_open = _time.monotonic() + 0.06
-
-  def submit(seeds):
-    if _time.monotonic() < t_open:
-      raise AdmissionRejected('draining', reason='draining',
-                              retry_after_ms=15.0)
-    return ('ok', seeds)
-
-  plan = [(i * 0.005, i) for i in range(5)]
-  out, _t0 = pace_schedule(plan, submit)
-  assert len(out) == 5
-  assert all(isinstance(r, tuple) and r[0] == 'ok' for _, r in out)
-
-
-def test_pace_schedule_drain_retries_are_bounded():
-  from bench_serving import pace_schedule
-  from graphlearn_tpu.serving import AdmissionRejected
-
-  def submit(seeds):
-    raise AdmissionRejected('draining', reason='draining',
-                            retry_after_ms=1.0)
-
-  out, _t0 = pace_schedule([(0.0, 0)], submit, max_retries=2)
-  assert [r for _, r in out] == ['shed']

@@ -1,6 +1,6 @@
 """Device-native construction path: `Graph.from_device_arrays`,
-device `Feature`, device labels — the zero-upload setup `bench.py`
-and `chip_smoke.py` use (benchmarks/common.build_graph_csr_device).
+device `Feature`, device labels — the zero-upload setup
+`chip_smoke.py` uses (its `build_graph_csr_device`).
 
 The contract under test: a Dataset built from device arrays behaves
 identically to one built from the same arrays via the host path.
@@ -92,7 +92,7 @@ def test_device_loader_parity(tiny):
 def test_build_graph_csr_device_valid():
   import sys, os
   sys.path.insert(0, os.path.join(os.path.dirname(__file__), '..'))
-  from benchmarks.common import build_graph_csr_device
+  from chip_smoke import build_graph_csr_device
   n = 500
   indptr, indices, eids = build_graph_csr_device(num_nodes=n, avg_deg=4,
                                                  seed=1)

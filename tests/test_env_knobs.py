@@ -1,6 +1,6 @@
 """Env-knob documentation enforcement (ISSUE 6 satellite): every
-``GLT_*`` knob referenced anywhere in the package or bench drivers
-must appear in the ``benchmarks/README.md`` knob tables — the same
+``GLT_*`` knob referenced anywhere in the package must appear in the
+``KNOBS.md`` knob tables — the same
 drift-proofing contract `test_event_schema.py` applies to event kinds
 (PR 4/5 both shipped knobs the docs never learned about)."""
 import sys
@@ -16,7 +16,7 @@ def test_every_knob_documented():
   missing = undocumented()
   assert not missing, (
       f'GLT_* knobs referenced in code but missing from '
-      f'benchmarks/README.md: {missing} — add a row to the knob '
+      f'KNOBS.md: {missing} — add a row to the knob '
       'tables (an undocumented knob is a feature only its author can '
       'use)')
 

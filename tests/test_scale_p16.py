@@ -4,8 +4,7 @@ Scale validation must not stop at P=8: the suite's
 conftest fixes an 8-device mesh, so this test spawns a subprocess with
 16 virtual CPU devices and runs one batch each of the node, hetero,
 and induced-subgraph engines with full provenance checks
-(tests/_p16_worker.py).  P=32 at the realistic batch-1024 workload is
-covered by `bench_dist_loader.py --capacity-sweep`.
+(tests/_p16_worker.py).
 """
 import os
 import json

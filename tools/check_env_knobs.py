@@ -25,11 +25,10 @@ if str(REPO) not in sys.path:               # standalone-script import
 from tools.glint.passes.env_knobs import (documented_knobs as  # noqa: E402
                                           _documented, knob_constants)
 
-#: scanned roots: the package plus the bench drivers (their knobs are
-#: user-facing too).  The glint pass scans the driver's wider root set
-#: (``examples/`` included); this shim keeps its historical contract.
-SCAN_ROOTS = ('graphlearn_tpu', 'benchmarks', 'bench.py')
-README = REPO / 'benchmarks' / 'README.md'
+#: scanned roots: the package.  The glint pass scans the driver's
+#: wider root set (``examples/`` included).
+SCAN_ROOTS = ('graphlearn_tpu',)
+README = REPO / 'KNOBS.md'
 
 
 def knob_references() -> dict:
@@ -58,7 +57,7 @@ def documented_knobs(readme_path: Path = README) -> set:
 
 
 def undocumented(readme_path: Path = README) -> dict:
-  """Knobs referenced in code but absent from the README's tables."""
+  """Knobs referenced in code but absent from the knob tables."""
   doc = documented_knobs(readme_path)
   return {k: sorted(set(files)) for k, files in knob_references().items()
           if k not in doc}

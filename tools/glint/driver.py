@@ -2,13 +2,13 @@
 baseline application, reporting, CLI.
 
 Default scan roots are the data-plane surfaces the invariants govern:
-the package, the bench drivers, ``bench.py``, and ``examples/``.
+the package and ``examples/``.
 Tests (``tests/``) are deliberately out of scope — they exercise
 ad-hoc event kinds and throwaway RNG on private objects by design.
 
 Exit code contract: 0 when every finding is inline-suppressed or
 baselined, 1 otherwise, 2 on usage errors.  This is the single entry
-point the bench/dev docs reference::
+point the docs reference::
 
     python -m tools.glint --baseline tools/glint/baseline.json
 """
@@ -26,7 +26,7 @@ from .findings import Finding
 from .registry import all_passes
 
 REPO = Path(__file__).resolve().parent.parent.parent
-DEFAULT_ROOTS = ('graphlearn_tpu', 'benchmarks', 'bench.py', 'examples')
+DEFAULT_ROOTS = ('graphlearn_tpu', 'examples')
 DEFAULT_BASELINE = Path(__file__).resolve().parent / 'baseline.json'
 
 
@@ -38,7 +38,7 @@ class Run:
 
   repo: Path = REPO
   #: knob table the env-knob-drift pass checks against
-  readme_path: Path = REPO / 'benchmarks' / 'README.md'
+  readme_path: Path = REPO / 'KNOBS.md'
   #: telemetry schema registry the event-schema pass checks against
   schema_path: Path = REPO / 'graphlearn_tpu' / 'telemetry' / 'schema.py'
   #: repo-relative prefix of "the package" for package-only passes

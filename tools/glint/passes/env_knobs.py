@@ -6,13 +6,13 @@ documented CLI keeps working.  The contract is unchanged: every
 ``GLT_*`` string constant in the scanned surfaces — the knob
 vocabulary: env reads go through ``os.environ.get('GLT_X')``,
 ``os.environ['GLT_X']`` or a ``FOO_ENV = 'GLT_X'`` constant, all of
-which surface as a string literal — must appear in the
-``benchmarks/README.md`` knob tables.  An undocumented knob is a
-feature only its author can use.
+which surface as a string literal — must appear in the ``KNOBS.md``
+knob tables.  An undocumented knob is a feature only its author can
+use.
 
 As a glint pass the scan covers every file the driver scans (the
-shim keeps its original three roots), so e.g. ``examples/`` knobs
-get drift-checked for free.
+shim scans the package), so e.g. ``examples/`` knobs get
+drift-checked for free.
 """
 from __future__ import annotations
 
@@ -45,7 +45,7 @@ def documented_knobs(readme_path: Path) -> set:
 class EnvKnobDriftPass(GlintPass):
   name = 'env-knob-drift'
   description = ('every GLT_* knob referenced in code appears in the '
-                 'benchmarks/README.md knob tables')
+                 'KNOBS.md knob tables')
 
   def begin(self, run):
     self._readme = run.readme_path
