@@ -11,8 +11,12 @@ A batch that states its sampler's hop layout (``metadata
 a stack of in-edge-local convs compute each layer only over the hops
 that layer feeds (PyG's ``trim_to_layer`` with static shapes); the
 result is then ``[C_0, out]``, the seed rows, instead of the whole
-table.  `models.train.apply_to_batch` is the seam that passes
-the layout on.
+table.  Where the batch also states its fanout windows (``metadata
+['hop_windows']``, `sampler.neighbor_sampler.hop_windows`) the convs
+that can aggregate by window do, over the windows of the blocks their
+layer keeps, instead of scattering every edge slot into the target
+rows (`models.conv`).  `models.train.apply_to_batch` is the seam that
+passes both on.
 """
 from __future__ import annotations
 
@@ -57,6 +61,16 @@ class BasicGNN(nn.Module):
   stack of other convs ignores the argument; without it every layer
   runs over the whole table and the result is ``[n, out]``.  The
   parameters are the same either way.
+
+  ``hop_windows`` — static ``((F_0, k_0), ..)``, the fanout windows of
+  the sampler's edge blocks — goes to the convs that declare
+  ``takes_windows`` (`SAGEConv`, `GATConv`): a layer that keeps
+  edge blocks ``0..h`` hands its conv ``hop_windows[:h + 1]``, a layer
+  over the whole table all of them, and the conv reduces over each
+  window in place of a scatter over the edge slots — the same values to
+  float32 round-off.  The ``model.trim`` event lists per layer the
+  slots aggregated either way (``windowed_slots`` /
+  ``scattered_slots``).
   """
   hidden_features: int
   out_features: int
@@ -76,20 +90,26 @@ class BasicGNN(nn.Module):
 
   @nn.compact
   def __call__(self, x, edge_index, edge_mask=None, *,
-               edge_weight=None, hop_capacities=None,
+               edge_weight=None, hop_capacities=None, hop_windows=None,
                train: bool = False):
-    trim = []   # per trimmed layer: (rows in, rows out, edge slots)
+    # per trimmed layer: (rows in, rows out, edge slots, by window?)
+    trim = []
     for i in range(self.num_layers):
       last = i == self.num_layers - 1
       out = self.out_features if last else self.hidden_features
       conv = self.make_conv(out, i)
       with layer_scope('model', f'layer{i}'):
         kwargs = {}
+        windows = (hop_windows if getattr(conv, 'takes_windows', False)
+                   else None)
         if (hop_capacities is not None and hop_capacities[1]
             and getattr(conv, 'in_edge_local', False)):
-          rows_in, rows_out, slots = _layer_extent(
-              hop_capacities, self.num_layers - 1 - i)
-          trim.append((rows_in, rows_out, slots))
+          hop = self.num_layers - 1 - i
+          rows_in, rows_out, slots = _layer_extent(hop_capacities, hop)
+          if windows is not None:
+            # the blocks a trimmed layer keeps are a prefix of the list
+            windows = windows[:hop + 1]
+          trim.append((rows_in, rows_out, slots, windows is not None))
           x = x[:rows_in]
           edge_index = edge_index[:, :slots]
           if edge_mask is not None:
@@ -97,6 +117,8 @@ class BasicGNN(nn.Module):
           if edge_weight is not None:
             edge_weight = edge_weight[:slots]
           kwargs['num_dst'] = rows_out
+        if windows is not None:
+          kwargs['windows'] = windows
         if edge_weight is not None:
           # GNS 1/q importance weights (Batch.metadata['edge_weight']):
           # only convs that define an unbiased weighted aggregation
@@ -110,10 +132,14 @@ class BasicGNN(nn.Module):
             x = nn.Dropout(self.dropout, deterministic=not train)(x)
     if trim and not self.is_initializing():
       # trace time: one event per compiled program that trims
-      rows_in, rows_out, slots = zip(*trim)
+      rows_in, rows_out, slots, by_window = zip(*trim)
       recorder.emit('model.trim', layers=len(trim),
                     rows_in=list(rows_in), rows_out=list(rows_out),
                     edge_slots=list(slots),
+                    windowed_slots=[s if w else 0
+                                    for s, w in zip(slots, by_window)],
+                    scattered_slots=[0 if w else s
+                                     for s, w in zip(slots, by_window)],
                     table_rows=hop_capacities[0][-1],
                     table_slots=hop_capacities[1][-1])
     return x.astype(jnp.float32) if self.dtype is not None else x

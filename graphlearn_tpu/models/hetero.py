@@ -35,12 +35,17 @@ class _NamedConv(nn.Module):
   factory: Callable[[], nn.Module]
 
   @nn.compact
-  def __call__(self, x_src, x_dst, edge_index, edge_mask, num_dst=None):
+  def __call__(self, x_src, x_dst, edge_index, edge_mask, num_dst=None,
+               windows=None):
     conv = self.factory()
+    # the relation's fanout windows, for a conv that aggregates by them
+    kwargs = ({'windows': windows} if windows is not None
+              and getattr(conv, 'takes_windows', False) else {})
     if getattr(conv, 'in_edge_local', False):
       if x_dst is None:
-        return conv(x_src, edge_index, edge_mask, num_dst=num_dst)
-      return conv((x_src, x_dst), edge_index, edge_mask)
+        return conv(x_src, edge_index, edge_mask, num_dst=num_dst,
+                    **kwargs)
+      return conv((x_src, x_dst), edge_index, edge_mask, **kwargs)
     if num_dst is not None:
       raise ValueError(
           f'{type(conv).__name__} is not in-edge-local: it cannot '
@@ -73,6 +78,13 @@ class HeteroConv(nn.Module):
   output): what a stack trimmed to the hops each layer feeds asks of
   its layers (`RGAT`).  The parameters are the same either way.
 
+  ``windows_dict`` — ``{edge type: ((F_0, k_0), ..)}``, the fanout
+  windows of each relation's edge list as handed in
+  (`sampler.hetero_neighbor_sampler.typed_hop_windows`) — goes to the
+  factory convs that declare ``takes_windows``, which then reduce
+  over each window in place of the segment operations over the edge
+  slots (`models.conv`); the default mode keeps `segment_mean`.
+
   Device ops carry ``glt.model/<scope>/<relation>`` per relation's
   convolution and ``glt.model/<scope>/merge`` over the sum into the
   target types (``<scope>`` is ``part``: the stack's ``layer<l>``; the
@@ -95,7 +107,7 @@ class HeteroConv(nn.Module):
 
   @nn.compact
   def __call__(self, x_dict, edge_index_dict, edge_mask_dict=None,
-               num_dst_dict=None):
+               num_dst_dict=None, windows_dict=None):
     scope = self.part or self.name or 'hetero'
     rows_out = lambda nt: (x_dict[nt].shape[0] if num_dst_dict is None
                            else int(num_dst_dict.get(nt, 0)))
@@ -112,9 +124,11 @@ class HeteroConv(nn.Module):
       a, _, b = et
       if a not in x_dict or b not in x_dict or not rows_out(b):
         continue
+      windows = None
       if et in edge_index_dict:
         ei = edge_index_dict[et]
         em = (edge_mask_dict or {}).get(et)
+        windows = (windows_dict or {}).get(et)
       else:
         # etype configured but absent from this batch: run the conv on
         # an empty edge set so the param structure stays a function of
@@ -129,7 +143,7 @@ class HeteroConv(nn.Module):
           if a == b:
             # a relation within one type: one table, no second copy
             agg = conv(x_dict[a], None, ei, em,
-                       None if num_dst_dict is None else nb)
+                       None if num_dst_dict is None else nb, windows)
           else:
             xa, xb = x_dict[a], x_dict[b][:nb]
             if xa.shape[-1] != xb.shape[-1]:
@@ -138,7 +152,7 @@ class HeteroConv(nn.Module):
                   f'widths for {et}: {xa.shape[-1]} vs {xb.shape[-1]} — '
                   f'project per-type inputs first (e.g. a Dense per '
                   f'node type)')
-            agg = conv(xa, xb, ei, em)
+            agg = conv(xa, xb, ei, em, windows=windows)
         else:
           msg = nn.Dense(self.out_features, use_bias=False,
                          dtype=self.dtype, name=f'lin_{as_str(et)}')(
@@ -247,6 +261,16 @@ class RGAT(nn.Module):
   while initialising, every layer runs over whole tables and the
   result is ``[n_target, out]``.  The parameters are the same either
   way.
+
+  ``hop_windows`` — the fanout windows of each relation's edge blocks
+  (``metadata['hop_windows']``,
+  `sampler.hetero_neighbor_sampler.typed_hop_windows`) — has every
+  relation's conv aggregate by window over the blocks its layer keeps
+  (a prefix of the relation's list), where it otherwise runs three
+  segment operations over every edge slot (`models.conv.GATConv`): the
+  same values to float32 round-off; the ``model.trim`` event lists per
+  relation and layer the slots aggregated either way
+  (``windowed_slots`` / ``scattered_slots``).
   """
   etypes: Tuple[EdgeType, ...]
   hidden_features: int
@@ -258,6 +282,9 @@ class RGAT(nn.Module):
 
   # `__call__` accepts ``hop_capacities``
   takes_hop_capacities = True
+  # `make_conv`'s convs declare ``takes_windows`` (a sibling stack
+  # whose conv does not says so here, and keeps the segment path)
+  windowed_convs = True
 
   @nn.nowrap
   def make_conv(self) -> nn.Module:
@@ -269,8 +296,12 @@ class RGAT(nn.Module):
 
   @nn.compact
   def __call__(self, x_dict, edge_index_dict, edge_mask_dict=None, *,
-               hop_capacities=None):
+               hop_capacities=None, hop_windows=None):
     trim = hop_capacities is not None and not self.is_initializing()
+    windows = None
+    if (hop_windows is not None and self.windowed_convs
+        and not self.is_initializing()):
+      windows = dict(hop_windows)
     with layer_scope('model', 'input'):
       h = {nt: x.astype(self.dtype or jnp.float32)
            for nt, x in x_dict.items()}
@@ -281,7 +312,12 @@ class RGAT(nn.Module):
       if trim:
         rows_in, num_dst, slots = typed_layer_extent(
             hop_capacities, self.num_layers - 1 - i)
-        trimmed.append((rows_in, num_dst, slots))
+        if windows is not None:
+          # the blocks a trimmed layer keeps are a prefix of the list
+          hop = self.num_layers - 1 - i
+          windows = {et: w[:hop + 1] for et, w in windows.items()
+                     if et in slots}
+        trimmed.append((rows_in, num_dst, slots, set(windows or ())))
         with layer_scope('model', f'layer{i}/trim'):
           h = {nt: v[:rows_in[nt]] for nt, v in h.items()
                if rows_in.get(nt)}
@@ -290,18 +326,24 @@ class RGAT(nn.Module):
           em = {et: m[:slots[et]] for et, m in em.items() if et in slots}
       h = HeteroConv(self.etypes, self.hidden_features,
                      make_conv=self.make_conv, part=f'layer{i}',
-                     name=f'conv{i}')(h, ei, em, num_dst)
+                     name=f'conv{i}')(h, ei, em, num_dst, windows)
       with layer_scope('model', f'layer{i}/merge'):
         h = {nt: nn.relu(v) for nt, v in h.items()}
     if trimmed:
       # trace time: one event per compiled program that trims
-      rows_in, rows_out, slots = zip(*trimmed)
+      rows_in, rows_out, slots, by_window = zip(*trimmed)
+      aggregated = lambda windowed: {
+          as_str(et): [s[et] if (et in w) == windowed else 0
+                       for s, w in zip(slots, by_window)]
+          for et in slots[0]}
       recorder.emit(
           'model.trim', layers=len(trimmed),
           rows_in={nt: [r[nt] for r in rows_in] for nt in rows_in[0]},
           rows_out={nt: [r[nt] for r in rows_out] for nt in rows_out[0]},
           edge_slots={as_str(et): [s[et] for s in slots]
                       for et in slots[0]},
+          windowed_slots=aggregated(True),
+          scattered_slots=aggregated(False),
           table_rows={nt: c[-1] for nt, c in hop_capacities[0]},
           table_slots={as_str(et): e[-1] for et, e in hop_capacities[1]})
     with layer_scope('model', 'head'):

@@ -95,6 +95,14 @@ def apply_to_batch(apply_fn, params, batch):
     which is all the loss and the accuracy read.  Any other
     ``apply_fn`` and any batch without the entry get the whole table,
     as before.
+  * ``hop_windows``, the fanout windows of the edge blocks the same
+    samplers state beside it (`sampler.neighbor_sampler.hop_windows`,
+    `sampler.hetero_neighbor_sampler.typed_hop_windows`), goes to the
+    same models: their `SAGEConv` / `GATConv` layers then aggregate by
+    window instead of scattering every edge slot into the target rows
+    — the same logits to float32 round-off.  A batch without the entry
+    (the mesh loader's, a fused epoch's, link and induced-subgraph
+    batches) keeps the `segment_*` path.
   """
   md = getattr(batch, 'metadata', None) or {}
   kwargs = {}
@@ -102,9 +110,10 @@ def apply_to_batch(apply_fn, params, batch):
     if md.get('edge_weight') is not None:
       kwargs['edge_weight'] = md['edge_weight']
     model = getattr(apply_fn, '__self__', None)
-    if (md.get('hop_capacities') is not None
-        and getattr(model, 'takes_hop_capacities', False)):
-      kwargs['hop_capacities'] = md['hop_capacities']
+    if getattr(model, 'takes_hop_capacities', False):
+      for stated in ('hop_capacities', 'hop_windows'):
+        if md.get(stated) is not None:
+          kwargs[stated] = md[stated]
   if hasattr(batch, 'x_dict'):
     return apply_fn(params, batch.x_dict, batch.edge_index_dict,
                     batch.edge_mask_dict, **kwargs)

@@ -124,7 +124,9 @@ def typed_hop_capacities(etypes, plan):
   below ``C_h(s)`` and sources below ``C_{h+1}(d)`` (what
   `models.hetero.RGAT` trims its layers to).  ``C_L`` is a table's
   shape, ``E_{L-1}`` the shape of a relation's ``row``.  ``plan`` is
-  `_plan`'s result, the one plan the jitted loop reads.
+  `_plan`'s result, the one plan the jitted loop reads.  Within a
+  block the slots lie by target, window by window: `typed_hop_windows`
+  states that half of the layout.
   """
   _, table_cap, _, edge_caps, hop_table_caps = plan
   node = tuple(
@@ -136,6 +138,35 @@ def typed_hop_capacities(etypes, plan):
     if slots and slots[-1]:
       edge.append((reverse_edge_type(et), tuple(slots)))
   return node, tuple(sorted(edge))
+
+
+def typed_hop_windows(etypes, fanouts, plan):
+  """The fanout windows of one `_hetero_multihop` output, beside
+  `typed_hop_capacities` and in its order: ``((emitted edge type,
+  ((F_0, k_0), .., (F_{L-1}, k_{L-1}))), ...)``.  Block ``h`` of the
+  emitted relation ``(d, rev_rel, s)`` is ``[F_h, k_h]`` flattened:
+  ``F_h`` the frontier capacity of ``s`` at hop ``h``, ``k_h`` the
+  relation's fanout there, 0 for a hop at which it is not sampled (an
+  empty block).
+
+  The contract is `sampler.neighbor_sampler.hop_windows`', per
+  relation: slot ``(i, j)`` of block ``h`` has target ``col ==
+  start_h + i`` or is masked (-1), ``start_h`` being the count of
+  ``s``'s table when hop ``h - 1`` began (0 for the first hop), so
+  ``start_h <= F_0 + .. + F_{h-1}``; a node is in its type's frontier
+  once, so within a relation all of a target's in-edges are one window
+  of ``k_h`` consecutive slots.
+  """
+  _, _, frontier_caps, edge_caps, _ = plan
+  out = []
+  for et in etypes:
+    if not any(ec.get(et, 0) for ec in edge_caps):
+      continue
+    s = et[0]
+    out.append((reverse_edge_type(et), tuple(
+        (fc.get(s, 0), fanouts[et][h] if et in ec else 0)
+        for h, (fc, ec) in enumerate(zip(frontier_caps, edge_caps)))))
+  return tuple(sorted(out))
 
 
 @functools.partial(
@@ -345,6 +376,8 @@ class HeteroNeighborSampler(BaseSampler):
     seeds = jnp.asarray(np.asarray(inputs.node, dtype=np.int32))
     (node, node_count, row, col, eid, emask, seed_locals,
      nsn) = self._run_multihop({input_type: seeds})
+    # the plan the multi-hop program was built from
+    plan = self._planned({input_type: int(seeds.shape[0])})
     return HeteroSamplerOutput(
         node=node, node_count=node_count, row=row, col=col, edge=eid,
         edge_mask=emask, batch={input_type: seeds},
@@ -354,10 +387,10 @@ class HeteroNeighborSampler(BaseSampler):
         # `HeteroBatch` carries them as pytree aux data, not as arrays
         metadata={'seed_local': seed_locals[input_type],
                   'input_type': input_type,
-                  # from the plan the multi-hop program was built from
-                  'hop_capacities': typed_hop_capacities(
-                      self.etypes,
-                      self._planned({input_type: int(seeds.shape[0])}))})
+                  'hop_capacities': typed_hop_capacities(self.etypes,
+                                                         plan),
+                  'hop_windows': typed_hop_windows(
+                      self.etypes, self.fanouts, plan)})
 
   def sample_from_edges(self, inputs, neg_sampling=None,
                         **kwargs) -> HeteroSamplerOutput:

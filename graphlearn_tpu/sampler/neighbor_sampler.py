@@ -57,19 +57,44 @@ def hop_capacities(batch_size: int, fanouts: Sequence[int],
   hops of the seeds lives in the prefixes ``[0, C_h)`` / ``[:E_h]``
   (what `models.BasicGNN` trims its layers to).  ``C_L`` is the
   table's shape ``node_cap``, ``E_{L-1}`` the shape of ``row``.
+  Within a block the slots lie by target, window by window:
+  `hop_windows` states that half of the layout.
   """
-  f_cap = int(batch_size)
-  cap, edges = min(f_cap, node_cap), 0
+  cap, edges = min(int(batch_size), node_cap), 0
   node_caps, edge_caps = [cap], []
-  for k in fanouts:
-    f_cap *= int(k)
-    edges += f_cap
-    cap = min(cap + f_cap, node_cap)
+  for f, k in hop_windows(batch_size, fanouts):
+    edges += f * k
+    cap = min(cap + f * k, node_cap)
     node_caps.append(cap)
     edge_caps.append(edges)
   if edge_caps:
     node_caps[-1] = node_cap
   return tuple(node_caps), tuple(edge_caps)
+
+
+def hop_windows(batch_size: int, fanouts: Sequence[int]
+                ) -> Tuple[Tuple[int, int], ...]:
+  """The fanout windows of one `_multihop_sample` output, beside
+  `hop_capacities`: ``((F_0, k_0), .., (F_{L-1}, k_{L-1}))``, edge
+  block ``h`` being ``[F_h, k_h]`` flattened (``F_h * k_h = E_h -
+  E_{h-1}`` slots, ``F_0`` the batch size, ``F_{h+1} = F_h * k_h``).
+
+  The contract: slot ``(i, j)`` of block ``h`` has target ``col ==
+  start_h + i`` or is masked (-1), where ``start_h`` is the table's
+  count when hop ``h - 1`` began (0 for the first hop) — hop ``h``
+  samples the frontier ``[start_h, start_h + F_h)``, window ``i`` holds
+  the ``k_h`` draws of frontier node ``i``, and a node is in a frontier
+  once.  So all of a target's in-edges are one window of ``k_h``
+  consecutive slots, consecutive windows are consecutive target rows,
+  the windows past the frontier's valid end are wholly masked, and
+  ``start_h <= F_0 + .. + F_{h-1}`` (what `models.conv` aggregates by
+  in place of a scatter over the edge slots).
+  """
+  f, windows = int(batch_size), []
+  for k in fanouts:
+    windows.append((f, int(k)))
+    f *= int(k)
+  return tuple(windows)
 
 
 @functools.partial(
@@ -288,7 +313,8 @@ class NeighborSampler(BaseSampler):
         # carries them as pytree aux data, not as arrays
         metadata={'seed_local': seed_local,
                   'hop_capacities': hop_capacities(
-                      b, self.num_neighbors, node_cap)})
+                      b, self.num_neighbors, node_cap),
+                  'hop_windows': hop_windows(b, self.num_neighbors)})
 
   # -- link sampling --------------------------------------------------------
 

@@ -62,9 +62,13 @@ EVENT_KINDS: Dict[str, str] = {
         'models.BasicGNN and models.hetero.RGAT at trace time, once '
         'per compiled program that trims its layers to the hops they '
         'feed: layers, and per layer rows_in, rows_out, edge_slots '
-        "computed, beside the batch's table_rows and table_slots "
-        '(absent = untrimmed); the typed model gives each as a dict, '
-        'rows per node type and slots per relation (its as_str form)',
+        'computed — of which windowed_slots aggregated by fanout '
+        'window (the batch stated hop_windows) and scattered_slots by '
+        'the segment path over every edge slot — beside the '
+        "batch's table_rows and table_slots (absent = untrimmed; "
+        'windowed_slots 0 = the window mechanism did not engage); the '
+        'typed model gives each as a dict, rows per node type and '
+        'slots per relation (its as_str form)',
     'sample.dedup':
         'ops.unique.emit_dedup for sampler._multihop_sample and '
         '_hetero_multihop at trace time, once per compiled program '

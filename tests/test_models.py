@@ -318,7 +318,8 @@ def _skewed_dataset(n=400, d=12, classes=5, seed=0):
 def _without_capacities(batch):
   """The same batch as a loader that states no layout would hand it."""
   from graphlearn_tpu.loader.transform import Batch
-  md = {k: v for k, v in batch.metadata.items() if k != 'hop_capacities'}
+  md = {k: v for k, v in batch.metadata.items()
+        if k not in ('hop_capacities', 'hop_windows')}
   return Batch(batch.x, batch.y, batch.edge_index, batch.edge_attr,
                batch.node, batch.node_mask, batch.edge_mask, batch.edge,
                batch.batch, batch.batch_size, batch.num_sampled_nodes,
@@ -385,8 +386,10 @@ def test_trimmed_sage_matches_untrimmed(case):
       loss_fn, has_aux=True)(params, full)
   assert logits_f.shape == (batch.x.shape[0], 5)
   assert logits_t.shape == (node_caps[0], 5) == (bs, 5)
-  np.testing.assert_array_equal(np.asarray(logits_t)[valid],
-                                np.asarray(logits_f)[:bs][valid])
+  # by window against the whole-table segment path: float32 round-off
+  np.testing.assert_allclose(np.asarray(logits_t)[valid],
+                             np.asarray(logits_f)[:bs][valid],
+                             rtol=1e-5, atol=1e-6)
   np.testing.assert_allclose(float(loss_t), float(loss_f), rtol=1e-5)
   flat_t = jax.tree_util.tree_leaves_with_path(grads_t)
   flat_f = jax.tree_util.tree_leaves_with_path(grads_f)
@@ -460,9 +463,12 @@ def test_trim_record_names_rows_and_slots_per_layer():
   assert caps == ((1024, 16384, 169984, 937984), (15360, 168960, 936960))
   model = GraphSAGE(hidden_features=256, out_features=47, num_layers=3)
   params = jax.eval_shape(model.init, jax.random.key(0), x, ei, em)
+  from graphlearn_tpu.sampler.neighbor_sampler import hop_windows
+  windows = hop_windows(1024, (15, 10, 5))
+  assert windows == ((1024, 15), (15360, 10), (153600, 5))
   events = _trim_events(
-      lambda p, *a: model.apply(p, *a, hop_capacities=caps), params,
-      x, ei, em)
+      lambda p, *a: model.apply(p, *a, hop_capacities=caps,
+                                hop_windows=windows), params, x, ei, em)
   assert len(events) == 1
   ev = events[0]
   assert ev['layers'] == 3
@@ -470,6 +476,16 @@ def test_trim_record_names_rows_and_slots_per_layer():
   assert ev['rows_out'] == [169984, 16384, 1024]
   assert ev['edge_slots'] == [936960, 168960, 15360]
   assert (ev['table_rows'], ev['table_slots']) == (937984, 936960)
+  # every layer's slots aggregated by fanout window, none scattered
+  assert ev['windowed_slots'] == [936960, 168960, 15360]
+  assert ev['scattered_slots'] == [0, 0, 0]
+  # capacities alone: the trimmed stack on the segment path
+  (ev,) = _trim_events(
+      lambda p, *a: model.apply(p, *a, hop_capacities=caps), params,
+      x, ei, em)
+  assert ev['windowed_slots'] == [0, 0, 0]
+  assert ev['scattered_slots'] == ev['edge_slots'] == [936960, 168960,
+                                                       15360]
 
 
 @pytest.mark.parametrize('who', ['sage-no-capacities', 'gcn-declines',

@@ -88,7 +88,8 @@ def trimmed(world):
 
 
 def _without_capacities(batch):
-  md = {k: v for k, v in batch.metadata.items() if k != 'hop_capacities'}
+  md = {k: v for k, v in batch.metadata.items()
+        if k not in ('hop_capacities', 'hop_windows')}
   leaves, tree = jax.tree_util.tree_flatten(batch)
   out = jax.tree_util.tree_unflatten(tree, leaves)
   out.metadata = md
@@ -194,9 +195,31 @@ def test_trim_event_names_rows_and_slots_per_type_and_relation(world):
     assert ev['rows_in'][t] == [c[3], c[2], c[1]]
     assert ev['rows_out'][t] == [c[2], c[1], c[0]]
     assert ev['table_rows'][t] == c[3] == batch.x_dict[t].shape[0]
+  s = world['loader'].sampler
+  frontier_caps, edge_caps = _plan(s.etypes, s.fanouts, {P: B}, s.num_hops,
+                                   s._num_nodes)[2:4]
+  assert set(dict(batch.metadata['hop_windows'])) == set(edge)
   for rel, e in edge.items():
     assert ev['edge_slots'][as_str(rel)] == [e[2], e[1], e[0]]
     assert ev['table_slots'][as_str(rel)] == e[2]
+    # all of them by fanout window — the plan's frontier capacity of
+    # the target type times the fanout, summed over the blocks kept
+    stored = reverse_edge_type(rel)
+    by_hop = np.cumsum([frontier_caps[h].get(rel[2], 0) * FANOUT[h]
+                        if stored in edge_caps[h] else 0
+                        for h in range(3)])
+    assert ev['windowed_slots'][as_str(rel)] == list(by_hop[::-1])
+    assert ev['scattered_slots'][as_str(rel)] == [0, 0, 0]
+  # capacities alone: trimmed, every slot on the segment path
+  md = dict(batch.metadata)
+  del md['hop_windows']
+  leaves, tree = jax.tree_util.tree_flatten(batch)
+  unstated = jax.tree_util.tree_unflatten(tree, leaves)
+  unstated.metadata = md
+  (seg,) = _trim_events(lambda p, b: apply_to_batch(model.apply, p, b),
+                        params, unstated)
+  assert seg['scattered_slots'] == seg['edge_slots'] == ev['edge_slots']
+  assert not any(sum(v) for v in seg['windowed_slots'].values())
   assert ev['rows_out'][P][-1] == B and ev['rows_out'][A][-1] == 0
   assert not _trim_events(
       lambda p, b: apply_to_batch(model.apply, p, b), params,
