@@ -30,20 +30,33 @@ from ..typing import RangePartitionBook
 from ..utils.topo import coo_to_csr, ptr2ind
 
 
+def _stack(a):
+  """A ``[P, ...]`` stack as its holder keeps it: a device array (the
+  shards `DistDataset.from_device_coo` built on the mesh) as it is, so
+  that it never comes back to the host; anything else as a host
+  array."""
+  return a if isinstance(a, jax.Array) else np.asarray(a)
+
+
 class DistGraph:
   """Stacked per-partition local CSRs + ownership bounds.
 
   Attributes:
     indptr: ``[P, max_local_nodes + 1]``.
     indices: ``[P, max_local_edges]`` (GLOBAL neighbor ids, -1 pad).
-    edge_ids: ``[P, max_local_edges]`` global edge ids (-1 pad).
+    edge_ids: ``[P, max_local_edges]`` global edge ids (-1 pad), or
+      None where none were built (`DistDataset.from_device_coo`): a
+      sampler asked for edge ids (``with_edge=True``) then raises.
     bounds: ``[P + 1]`` ownership ranges (RangePartitionBook).
+
+  The stacks are host arrays, or device arrays already sharded over
+  the mesh axis (`_stack`).
   """
 
   def __init__(self, indptr, indices, edge_ids, bounds):
-    self.indptr = np.asarray(indptr)
-    self.indices = np.asarray(indices)
-    self.edge_ids = np.asarray(edge_ids)
+    self.indptr = _stack(indptr)
+    self.indices = _stack(indices)
+    self.edge_ids = None if edge_ids is None else _stack(edge_ids)
     self.bounds = np.asarray(bounds, dtype=np.int64)
 
   @property
@@ -61,6 +74,39 @@ class DistGraph:
   @property
   def max_local_nodes(self) -> int:
     return self.indptr.shape[1] - 1
+
+
+def round_robin_book(num_nodes: int, num_parts: int,
+                     seed: int = 0) -> np.ndarray:
+  """THE default placement (``partitioner='range'``): a seeded random
+  permutation of the nodes dealt round-robin, so partition ``p`` owns
+  ``perm[p::P]`` — with ``num_nodes`` a multiple of ``P`` exactly
+  ``N / P`` nodes each.  One definition for the host path
+  (`DistDataset.from_full_graph`) and the device path
+  (`DistDataset.from_device_coo`)."""
+  rng = np.random.default_rng(seed)
+  node_pb = np.empty(num_nodes, dtype=np.int32)
+  perm = rng.permutation(num_nodes)
+  for p in range(num_parts):
+    node_pb[perm[p::num_parts]] = p
+  return node_pb
+
+
+def edge_width(per_part, edge_capacity: Optional[int]) -> int:
+  """Width of the stacked ``indices``: the largest partition's edge
+  count — which changes with the graph, and the compiled programs
+  with it — or the STATED ``edge_capacity``, the same for every graph
+  of a configuration.  A partition that holds more edges than the
+  stated capacity is an error: never a silent drop, never a resize."""
+  most = int(np.max(per_part, initial=0))
+  if edge_capacity is None:
+    return max(most, 1)
+  if most > int(edge_capacity):
+    raise ValueError(
+        f'a partition holds {most} edges, over the stated '
+        f'edge_capacity {int(edge_capacity)} (edges per partition: '
+        f'{[int(c) for c in per_part]}); state a larger capacity')
+  return int(edge_capacity)
 
 
 def relabel_by_partition(node_pb: np.ndarray, num_parts: int,
@@ -352,7 +398,8 @@ def build_dist_graph(rows: np.ndarray, cols: np.ndarray,
                      node_pb: np.ndarray, num_nodes: int,
                      edge_ids: Optional[np.ndarray] = None,
                      num_parts: Optional[int] = None,
-                     hotness: Optional[np.ndarray] = None
+                     hotness: Optional[np.ndarray] = None,
+                     edge_capacity: Optional[int] = None
                      ) -> Tuple[DistGraph, np.ndarray]:
   """Relabel + shard a COO graph by a node partition book.
 
@@ -365,6 +412,11 @@ def build_dist_graph(rows: np.ndarray, cols: np.ndarray,
   hottest-first, so a tiered feature store's ``split_ratio`` keeps the
   hottest rows in HBM — the sharded analog of `sort_by_in_degree`
   (reference `data/reorder.py:19-31`).
+
+  ``edge_capacity`` states the width of the stacked ``indices``
+  (`edge_width`): with it every graph of a configuration gives the
+  same shard shapes, so one compiled program serves them all; without
+  it the width is the largest partition's edge count of THIS graph.
   """
   node_pb = np.asarray(node_pb)
   if num_parts is None:
@@ -380,7 +432,8 @@ def build_dist_graph(rows: np.ndarray, cols: np.ndarray,
   # per-partition local CSR (rows local, cols global).
   max_nodes = int(counts.max()) if num_parts else 0
   owner = node_pb[np.asarray(rows)]
-  max_edges = max(int(np.bincount(owner, minlength=num_parts).max()), 1)
+  max_edges = edge_width(np.bincount(owner, minlength=num_parts),
+                         edge_capacity)
   indptr_s = np.zeros((num_parts, max_nodes + 1), dtype=np.int64)
   indices_s = np.full((num_parts, max_edges), -1, dtype=np.int32)
   eids_s = np.full((num_parts, max_edges), -1, dtype=np.int64)
@@ -488,7 +541,7 @@ class DistFeature:
                mod_sharded: bool = False, hot_counts=None,
                cold_host=None, cold_local=None,
                cache_local: bool = False):
-    self.shards = np.asarray(shards)
+    self.shards = _stack(shards)
     self.bounds = np.asarray(bounds, dtype=np.int64)
     self.hot_counts = (np.asarray(hot_counts, np.int32)
                        if hot_counts is not None
@@ -660,6 +713,173 @@ def build_dist_edge_feature(efeats: np.ndarray,
                      mod_sharded=True)
 
 
+def exchange_width(edge_capacity: int, num_parts: int) -> int:
+  """What one device may hold for one owner in the shard build's
+  exchange: ``ceil(edge_capacity / P)`` — blocks that mix the owners
+  evenly, as any block of a shuffled COO under a round-robin placement
+  does, send each owner a ``P``-th of what it may hold."""
+  return -(-int(edge_capacity) // int(num_parts))
+
+
+def coo_shard_program(mesh, axis: str, max_nodes: int,
+                      edge_capacity: int):
+  """The jitted program of `shard_coo_on_mesh`: ``(rows [E], cols [E],
+  old2new [N], bounds [P + 1]) -> (indptr [P, max_nodes + 1], indices
+  [P, edge_capacity], sent [P, P])``, the COO and the outputs sharded
+  over ``axis``, the two tables replicated, everything int32.  Its
+  shapes follow the arguments here and ``E``, ``N`` alone."""
+  import jax.numpy as jnp
+  from jax.sharding import PartitionSpec as P
+  from .shard_map_compat import shard_map
+  num_parts = mesh.shape[axis]
+  cap, send = int(edge_capacity), exchange_width(edge_capacity, num_parts)
+  big = np.iinfo(np.int32).max
+
+  def per_device(rows, cols, o2n, lo):
+    ok = rows >= 0
+    r = jnp.where(ok, o2n[jnp.where(ok, rows, 0)], big)
+    c = jnp.where(ok, o2n[jnp.where(ok, cols, 0)], -1)
+    # new ids are contiguous per owner: sorted by new row id, owner
+    # p's edges are one run
+    r, c = jax.lax.sort((r, c), num_keys=1)
+    starts = jnp.searchsorted(r, lo).astype(jnp.int32)        # [P + 1]
+    sent = starts[1:] - starts[:-1]
+    # a run may start within ``send`` of the block's end
+    r = jnp.concatenate([r, jnp.full((send,), big, jnp.int32)])
+    c = jnp.concatenate([c, jnp.full((send,), -1, jnp.int32)])
+    lane = jnp.arange(send, dtype=jnp.int32)
+    out_r, out_c = [], []
+    for p in range(num_parts):
+      keep = lane < sent[p]
+      run_r = jax.lax.dynamic_slice(r, (starts[p],), (send,)) - lo[p]
+      run_c = jax.lax.dynamic_slice(c, (starts[p],), (send,))
+      out_r.append(jnp.where(keep, run_r, big))
+      out_c.append(jnp.where(keep, run_c, -1))
+    got_r = jax.lax.all_to_all(jnp.stack(out_r), axis, 0, 0)
+    got_c = jax.lax.all_to_all(jnp.stack(out_c), axis, 0, 0)
+    # rows, then columns within a row: `utils.topo.coo_to_csr`'s order
+    lr, lc = jax.lax.sort((got_r.reshape(-1), got_c.reshape(-1)),
+                          num_keys=2)
+    if lc.shape[0] < cap:
+      lc = jnp.concatenate(
+          [lc, jnp.full((cap - lc.shape[0],), -1, jnp.int32)])
+    indptr = jnp.searchsorted(
+        lr, jnp.arange(max_nodes + 1, dtype=jnp.int32),
+        side='left').astype(jnp.int32)
+    return indptr[None], lc[None, :cap], sent[None]
+
+  return jax.jit(shard_map(
+      per_device, mesh=mesh, in_specs=(P(axis), P(axis), P(), P()),
+      out_specs=(P(axis), P(axis), P(axis))))
+
+
+def shard_coo_on_mesh(rows, cols, old2new: np.ndarray,
+                      bounds: np.ndarray, mesh, axis: str,
+                      edge_capacity: int):
+  """`build_dist_graph`'s stacks, built ON the mesh from a COO that
+  lives there: ``(indptr [P, max_nodes + 1], indices [P,
+  edge_capacity], sent [P, P])``, the first two sharded over ``axis``
+  and equal to the host path's byte for byte (as a sampler places
+  them: int32), ``sent[d, p]`` the edges device ``d`` held for owner
+  ``p`` (a host array; its column sums are the partitions' counts).
+
+  ``rows`` / ``cols``: ``[E]`` ids in the original space (``-1``
+  pads), device arrays or anything `jax.device_put` takes; each device
+  works on the ``E / P`` block the mesh sharding gives it and the host
+  never holds the COO.  Per device, one program
+  (`coo_shard_program`): relabel both ends through ``old2new``
+  (replicated, int32), sort the block by new row id, send each owner
+  its run (one ``all_to_all`` of ``[P, exchange_width]`` buckets of
+  local rows and new columns), sort what arrived by (row, column), and
+  read ``indptr`` off the sorted rows.
+
+  ``edge_capacity`` is STATED, so the program's shapes do not follow
+  the graph: it is the width of ``indices`` (`edge_width`), and what
+  one device may hold for one owner follows from it
+  (`exchange_width`).  A graph over either raises `ValueError` once
+  the counts are back — never a silent drop or a resize."""
+  import jax.numpy as jnp
+  from jax.sharding import NamedSharding, PartitionSpec as P
+  from ..utils.padding import round_up
+  bounds = np.asarray(bounds, np.int64)
+  num_parts = len(bounds) - 1
+  cap, send = int(edge_capacity), exchange_width(edge_capacity, num_parts)
+  shard = NamedSharding(mesh, P(axis))
+  repl = NamedSharding(mesh, P())
+
+  def block(a):
+    a = jnp.asarray(a, jnp.int32).reshape(-1)
+    pad = round_up(a.shape[0], num_parts) - a.shape[0]
+    if pad:
+      a = jnp.concatenate([a, jnp.full((pad,), -1, jnp.int32)])
+    return jax.device_put(a, shard)
+
+  indptr, indices, sent = coo_shard_program(
+      mesh, axis, int(np.diff(bounds).max()), cap)(
+          block(rows), block(cols),
+          jax.device_put(np.asarray(old2new).astype(np.int32), repl),
+          jax.device_put(bounds.astype(np.int32), repl))
+  sent = np.asarray(sent)
+  if int(sent.max(initial=0)) > send:
+    d, p = np.unravel_index(int(np.argmax(sent)), sent.shape)
+    raise ValueError(
+        f'device {d} holds {int(sent[d, p])} edges for partition {p}, '
+        f'over the exchange width {send} (edge_capacity {cap} / '
+        f'{num_parts}); state a larger edge_capacity, or hand the COO '
+        'in shuffled, so that every block mixes the owners evenly')
+  edge_width(sent.sum(0), cap)          # raises over the capacity
+  return indptr, indices, sent
+
+
+def owned_ids_on_mesh(new2old: np.ndarray, bounds: np.ndarray, mesh,
+                      axis: str):
+  """``[P, rows_max]`` int32, sharded over ``axis``: the original ids
+  each device owns, in the relabelled order, ``-1`` past its count."""
+  from jax.sharding import NamedSharding, PartitionSpec as P
+  bounds = np.asarray(bounds, np.int64)
+  counts = np.diff(bounds)
+  owned = np.full((len(counts), int(counts.max())), -1, np.int32)
+  for p, count in enumerate(counts):
+    owned[p, :count] = new2old[bounds[p]:bounds[p + 1]]
+  return jax.device_put(owned, NamedSharding(mesh, P(axis)))
+
+
+def shard_rows_on_mesh(source, owned, mesh, axis: str):
+  """`build_dist_feature`'s untiered shards (``[P, rows_max, D]``; a
+  1-D table gives the label stack ``[P, rows_max]``), built ON the
+  mesh: device ``p`` takes the rows of the original ids it owns
+  (``owned``: `owned_ids_on_mesh`), rows past its count zero.
+
+  ``source`` is the ``[N, ...]`` table in the original id order —
+  replicated over the mesh first, so it must fit one device, as the
+  one-chip `Dataset`'s does — or, for a table no device holds whole,
+  ``(f, operands)``: a jittable ``f(old_ids, *operands) -> rows`` that
+  each device calls on the ``[rows_max]`` ids it owns (pads asked as
+  id 0 and zeroed).  Operands are replicated and enter the program as
+  ARGUMENTS, so one compiled program serves every value of them (a
+  random key, say)."""
+  import jax.numpy as jnp
+  from jax.sharding import NamedSharding, PartitionSpec as P
+  from .shard_map_compat import shard_map
+  if isinstance(source, tuple):
+    fn, operands = source
+  else:
+    fn, operands = (lambda ids, table: table[ids]), (jnp.asarray(source),)
+  operands = jax.device_put(tuple(operands), NamedSharding(mesh, P()))
+
+  def per_device(ids, *operands):
+    ids = ids[0]
+    ok = ids >= 0
+    rows = fn(jnp.where(ok, ids, 0), *operands)
+    ok = ok.reshape(ok.shape + (1,) * (rows.ndim - 1))
+    return jnp.where(ok, rows, jnp.zeros((), rows.dtype))[None]
+
+  return jax.jit(shard_map(
+      per_device, mesh=mesh,
+      in_specs=(P(axis),) + (P(),) * len(operands), out_specs=P(axis)))(
+          owned, *operands)
+
+
 class DistDataset:
   """Sharded dataset: graph + features + labels in the relabeled space.
 
@@ -758,9 +978,16 @@ class DistDataset:
                       split_ratio: float = 1.0,
                       hotness: Optional[np.ndarray] = None,
                       partitioner=None,
-                      replica_frac: Optional[float] = None
+                      replica_frac: Optional[float] = None,
+                      edge_capacity: Optional[int] = None
                       ) -> 'DistDataset':
     """In-memory partition + shard (testing & single-host path).
+
+    ``edge_capacity`` states the per-partition width of ``indices``
+    (`edge_width`; `build_dist_graph`): the graphs of one
+    configuration then give identical shard shapes — and
+    `from_device_coo`, handed the same capacity, the same bytes —
+    where the default follows the largest partition of this graph.
 
     ``split_ratio < 1`` tiers the node-feature store (HBM hot /
     host-DRAM cold, see `build_dist_feature`); ``hotness`` defaults to
@@ -797,11 +1024,7 @@ class DistDataset:
       part = resolve_partitioner(partitioner)
       if isinstance(part, str) and part == 'range':
         part_identity = 'range'
-        rng = np.random.default_rng(seed)
-        node_pb = np.empty(n, dtype=np.int32)
-        perm = rng.permutation(n)
-        for p in range(num_parts):
-          node_pb[perm[p::num_parts]] = p
+        node_pb = round_robin_book(n, num_parts, seed)
       elif isinstance(part, str):              # 'locality'
         from .locality import locality_partition
         part_identity = 'locality'
@@ -818,7 +1041,8 @@ class DistDataset:
     if split_ratio < 1.0 and hotness is None:
       hotness = np.bincount(cols, minlength=n)       # in-degree
     g, old2new = build_dist_graph(rows, cols, node_pb, n,
-                                  num_parts=num_parts, hotness=hotness)
+                                  num_parts=num_parts, hotness=hotness,
+                                  edge_capacity=edge_capacity)
     nf = (build_dist_feature(node_feat, old2new, g.bounds,
                              split_ratio=split_ratio)
           if node_feat is not None else None)
@@ -847,6 +1071,84 @@ class DistDataset:
           if edge_feat is not None else None)
     ds = cls(g, nf, nl, old2new, edge_features=ef)
     ds.partitioner = part_identity
+    return ds
+
+  @classmethod
+  def from_device_coo(cls, num_parts: int, rows, cols, *,
+                      num_nodes: int, edge_capacity: int,
+                      node_feat=None, node_label=None, mesh=None,
+                      axis: str = 'data', seed: int = 0
+                      ) -> 'DistDataset':
+    """`from_full_graph`'s dataset, with every shard built on the mesh
+    it will be sampled on — the way in for a graph that lives on the
+    devices (as the one-chip `Dataset` takes device arrays) or is too
+    large for the host to partition: the COO is never on the host, and
+    a table no device holds whole never is in one place.
+
+    The partition book is `from_full_graph`'s default: the seeded
+    round-robin placement (`round_robin_book`) and the same relabel
+    (`relabel_by_partition`) — ``old2new``, ``bounds`` and with them
+    every id a batch surfaces are the host path's.  ``rows`` /
+    ``cols`` are the ``[E]`` COO in original ids
+    (`shard_coo_on_mesh`); ``node_feat`` / ``node_label`` a table in
+    original id order that fits one device, or ``(f, operands)`` with
+    a jittable ``f(old_ids, *operands) -> rows``
+    (`shard_rows_on_mesh`).
+
+    ``edge_capacity`` is REQUIRED: the stated width of each device's
+    ``indices`` (a configuration's mean edge count per device plus its
+    margin).  What one device may hold for one owner in the build's
+    exchange follows from it (`exchange_width`).  Shapes follow it and
+    ``num_nodes`` alone, so every graph of a configuration runs the
+    same compiled programs, the build's included; a graph over it —
+    a partition's edges, or one block's edges for one owner — raises.
+    Handed the same capacity,
+    `from_full_graph` gives the same bytes (``indptr``, ``indices``,
+    feature and label shards as a sampler places them; held by
+    ``tests/test_device_shards.py``).  No edge ids are built: a
+    sampler that needs them (``with_edge=True``) says so.  Tiered
+    stores, replica caches, edge features and hotness orders stay
+    `from_full_graph`'s.
+
+    The build runs inside a ``dist.shard_build`` span whose fields —
+    and ``ds.shard_build`` — give the nodes and edges each device
+    holds, the stated capacity with the exchange width it gives, and
+    the seconds it took, the devices' work included.
+    """
+    import time
+    from ..telemetry.spans import span
+    from .dp import make_mesh
+    mesh = mesh or make_mesh(num_parts, axis)
+    n = int(num_nodes)
+    cap = int(edge_capacity)
+    built = dict(edge_capacity=cap,
+                 exchange_capacity=exchange_width(cap, num_parts))
+    build_span = span('dist.shard_build', num_parts=num_parts, **built)
+    with build_span:
+      t0 = time.monotonic()
+      old2new, counts, bounds = relabel_by_partition(
+          round_robin_book(n, num_parts, seed), num_parts)
+      indptr, indices, sent = shard_coo_on_mesh(
+          rows, cols, old2new, bounds, mesh, axis, cap)
+      ds = cls(DistGraph(indptr, indices, None, bounds),
+               old2new=old2new)
+      owned = owned_ids_on_mesh(ds.new2old, bounds, mesh, axis)
+      if node_feat is not None:
+        ds.node_features = DistFeature(
+            shard_rows_on_mesh(node_feat, owned, mesh, axis), bounds)
+      if node_label is not None:
+        ds.node_labels = shard_rows_on_mesh(node_label, owned, mesh, axis)
+      jax.block_until_ready(
+          (ds.node_features and ds.node_features.shards, ds.node_labels))
+      # the span's end event carries what the build found
+      built.update(nodes=[int(c) for c in counts],
+                   edges=[int(c) for c in sent.sum(0)])
+      build_span.fields.update(built)
+      built['secs'] = time.monotonic() - t0
+    ds.partitioner = 'range'
+    #: what the build found and took: the span's fields, and the
+    #: seconds inside it
+    ds.shard_build = built
     return ds
 
   @classmethod

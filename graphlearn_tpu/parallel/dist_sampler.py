@@ -1762,6 +1762,10 @@ class DistNeighborSampler(ExchangeTelemetry):
     self.mesh = mesh or make_mesh(self.num_parts, axis)
     self.axis = axis
     self.with_edge = with_edge
+    if with_edge and dataset.graph.edge_ids is None:
+      raise ValueError(
+          'with_edge=True needs edge ids, and this dataset was built '
+          'without them (DistDataset.from_device_coo builds none)')
     self.collect_features = (collect_features
                              and dataset.node_features is not None)
     self.collect_labels = dataset.node_labels is not None
@@ -1929,7 +1933,8 @@ class DistNeighborSampler(ExchangeTelemetry):
         arrs = dict(self._device_arrays)  # RCU: in-flight dicts frozen
         arrs['indptr'] = self._put_shard(indptr_s)
         arrs['indices'] = self._put_shard(indices_s)
-        arrs['eids'] = self._put_shard(eids_s)
+        if self.with_edge:
+          arrs['eids'] = self._put_shard(eids_s)
         self._device_arrays = arrs
     self._gns_ver = -1                   # version-fenced invalidation
     self._stream_ver = view.version
@@ -2106,7 +2111,7 @@ class DistNeighborSampler(ExchangeTelemetry):
       return adopted[r]
     g = self.ds.graph
     out = {'indptr': g.indptr[r], 'indices': g.indices[r],
-           'eids': g.edge_ids[r]}
+           'eids': None if g.edge_ids is None else g.edge_ids[r]}
     nf = self.ds.node_features
     if self.collect_features and nf is not None:
       out['fshard'] = nf.shards[r]
@@ -2167,6 +2172,10 @@ class DistNeighborSampler(ExchangeTelemetry):
       hcounts = (self.ds.node_features.hot_counts
                  if self.collect_features
                  else np.zeros(self.num_parts, np.int32))
+      # edge ids go to the devices only where a step reads them
+      # (`with_edge`); every other step takes a placeholder
+      eids = (g.edge_ids if self.with_edge
+              else np.full((self.num_parts, 1), -1, np.int32))
       if getattr(self.ds, 'host_parts', None) is not None:
         # stacked arrays hold ONLY this host's partitions: assemble
         # the global sharded arrays shard-by-shard.  Placeholder
@@ -2181,18 +2190,26 @@ class DistNeighborSampler(ExchangeTelemetry):
           crows = crows[:pl]
         if not self.collect_edge_features:
           efshards = efshards[:pl]
+        if not self.with_edge:
+          eids = eids[:pl]
         putS = self._put_stacked
       else:
-        putS = lambda a: put(a, shard)       # noqa: E731
+        def putS(a):
+          # a stack that is already a device array sharded over the
+          # mesh axis (`DistDataset.from_device_coo`) is handed on as
+          # it is: it never visits the host
+          if isinstance(a, jax.Array) and a.sharding.is_equivalent_to(
+              shard, a.ndim):
+            return a
+          return put(a, shard)
       spec = self.book_spec
       if spec is None:
         # identity book: EXACTLY the pre-book arrays (the fault-free
         # byte-identity contract — failover compiled in costs nothing)
         self._device_arrays = dict(
             indptr=putS(g.indptr), indices=putS(g.indices),
-            eids=putS(g.edge_ids), bounds=put(g.bounds, repl),
-            fshards=putS(np.asarray(fshards)),
-            lshards=putS(np.asarray(lshards)),
+            eids=putS(eids), bounds=put(g.bounds, repl),
+            fshards=putS(fshards), lshards=putS(lshards),
             cids=putS(cids), crows=putS(crows),
             efshards=putS(efshards), ebounds=put(ebounds, repl),
             hcounts=put(np.asarray(hcounts, np.int32), repl))
@@ -2204,7 +2221,8 @@ class DistNeighborSampler(ExchangeTelemetry):
         self._device_arrays = dict(
             indptr=putS(self._lane_stacked('indptr', g.indptr, 0)),
             indices=putS(self._lane_stacked('indices', g.indices, -1)),
-            eids=putS(self._lane_stacked('eids', g.edge_ids, -1)),
+            eids=putS(self._lane_stacked('eids', g.edge_ids, -1)
+                      if self.with_edge else eids),
             bounds=put(g.bounds, repl),
             fshards=putS(self._lane_stacked('fshard',
                                             np.asarray(fshards), 0)),

@@ -826,6 +826,7 @@ class FusedDistTreeEpoch(_MeshEpochDriver):
     # all-zero) on every tiered envelope epoch
     attr_owner = range_owner_fn(bounds)
     attr_fr = jnp.zeros((self.num_parts,), jnp.int32)
+    hop_plans = []
     # the exchange and the owners' work carry their own scopes
     # (`dist_sampler`); a scope around them here would claim them, so
     # only what this function does itself is scoped
@@ -833,12 +834,13 @@ class FusedDistTreeEpoch(_MeshEpochDriver):
       with layer_scope('exchange', 'stats'):
         attr_fr = attr_fr + dest_histogram(frontier, attr_owner,
                                            self.num_parts)
+      hop_plans.append((frontier.shape[0], _slack_cap(
+          frontier.shape[0], self.num_parts, slack, layout)))
       nbrs, mask, _, hw, st = _dist_one_hop(
           indptr_s, indices_s, None, bounds, frontier, int(k),
           jax.random.fold_in(key, h), self.axis, self.num_parts,
           False, sort_locality=False,
-          exchange_capacity=_slack_cap(frontier.shape[0],
-                                       self.num_parts, slack, layout),
+          exchange_capacity=hop_plans[-1][1],
           gns_bits=gns_bits, gns_boost=boost, book_spec=book_spec)
       with layer_scope('sample', f'hop{h}'):
         fstats = fstats + jnp.stack(st)
@@ -849,11 +851,12 @@ class FusedDistTreeEpoch(_MeshEpochDriver):
         frontier = nxt
     with layer_scope('gather', 'ids'):
       all_ids = jnp.concatenate(levels)
+    feature_plan = (all_ids.shape[0], _slack_cap(
+        all_ids.shape[0], self.num_parts, slack, layout))
+    self._emit_exchange_plan(hop_plans, feature_plan)
     (feats, labels), gst = dist_gather_multi(
         (fshards_s, lshards_s), bounds, all_ids, self.axis,
-        self.num_parts,
-        exchange_capacity=_slack_cap(all_ids.shape[0], self.num_parts,
-                                     slack, layout),
+        self.num_parts, exchange_capacity=feature_plan[1],
         hot_counts=hcounts, book_spec=book_spec)
     with layer_scope('exchange', 'stats'):
       attr_ft = dest_histogram(all_ids, attr_owner, self.num_parts)
@@ -874,6 +877,29 @@ class FusedDistTreeEpoch(_MeshEpochDriver):
         off += s
       masks = [lvl >= 0 for lvl in levels]
     return xs, masks, y, stats7, hop_counts
+
+  def _emit_exchange_plan(self, hop_plans, feature_plan) -> None:
+    """Trace time, once per compiled mesh program: the exchange
+    layout that was chosen, its slack, and per exchange (each hop's
+    frontier, then the one feature and label gather) the ids a device
+    offers and the send slots its buffer holds for them — ``slots``
+    over ``ids`` is the padding the owners draw and gather over
+    (`exchange_stats()` counts it at run time).  An exact exchange
+    (no slack) holds ``P * ids`` slots."""
+    from ..telemetry.recorder import recorder
+    from .exchange import resolve_layout
+    slots = lambda n, spec: (self.num_parts * n if spec is None
+                             else spec.slots)
+    recorder.emit(
+        'exchange.plan', scope=type(self).__name__,
+        layout=resolve_layout(self.sampler.exchange_layout,
+                              self.num_parts),
+        slack=self.sampler.exchange_slack, num_parts=self.num_parts,
+        batch=self.batch_size,
+        frontier_ids=[n for n, _ in hop_plans],
+        frontier_slots=[slots(n, spec) for n, spec in hop_plans],
+        feature_ids=feature_plan[0],
+        feature_slots=slots(*feature_plan))
 
   def _eval_tail(self, params, xs, masks, y, valid):
     axis = self.axis

@@ -78,6 +78,15 @@ EVENT_KINDS: Dict[str, str] = {
         'of the table handed in + candidates), table_rows (capacity '
         'returned) and candidates (B*k); absent = tables held at '
         'their final size from the first hop on (the mesh samplers)',
+    'exchange.plan':
+        'parallel.FusedDistTreeEpoch at trace time, once per compiled '
+        'mesh program: scope, layout (the exchange layout chosen: '
+        'dense/compact/hier), slack, num_parts, batch (per device), '
+        'and the slots per hop — frontier_ids / frontier_slots (ids '
+        'one device offers in each hop\'s frontier exchange, send '
+        'slots its buffer holds for them), feature_ids / '
+        'feature_slots (the one feature and label gather); slots '
+        'over ids is the padding the owners draw and gather over',
     'span.begin':
         'telemetry.spans: name, trace_id, span_id, parent_id, pid, '
         'tid (+caller fields)',
@@ -313,6 +322,13 @@ SPAN_NAMES: Dict[str, str] = {
         'steps = batches corrected)',
     'fused.init_state':
         'FusedTreeEpoch.init_state: param init from the dummy batch',
+    'dist.shard_build':
+        'DistDataset.from_device_coo: the whole build of a dataset\'s '
+        'shards on the mesh (partition book, relabel, exchange and '
+        'sort of the COO, feature and label shards, the devices\' '
+        'work included) — num_parts, edge_capacity (stated), '
+        'exchange_capacity (the exchange width that follows from it) '
+        'and, on the end event, nodes and edges per device',
     'exchange.layout':
         'mesh samplers, build time: one span per compiled SPMD step '
         'with the resolved exchange layout (dense/compact/hier/'
