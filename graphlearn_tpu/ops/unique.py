@@ -38,6 +38,36 @@ class UniqueResult(NamedTuple):
   count: jax.Array
 
 
+#: permutation gathers ``a[perm]`` over all ``n`` sorted elements that
+#: one `unique_stable` makes (it made five before its sorts carried
+#: their payloads); `emit_dedup` reports ``gathered`` from it.
+GATHERS_PER_DEDUP = 0
+
+
+def _copy_from_heads(head: jax.Array, vals: jax.Array) -> jax.Array:
+  """``vals`` at each element's segment head, handed along the sorted
+  order without a gather: ``head`` flags the first element of every
+  segment, ``vals`` lie in ``[0, n)``.
+
+  A running max over keys that pack (position of the head, a chunk of
+  the head's value) into 31 bits: positions rise along the order, so
+  the max at an element is its own head's key.  One pass where
+  position and value fit 31 bits together (``n <= 2**15``), else one
+  per chunk of the value (two up to ``2**20`` elements).  Elements
+  before the first head read garbage; the caller masks them.
+  """
+  n = head.shape[0]
+  pos_bits = max(int(n - 1).bit_length(), 1)
+  chunk = 31 - pos_bits
+  low = (1 << chunk) - 1
+  pos = jnp.arange(n, dtype=jnp.int32) << chunk
+  out = jnp.zeros((n,), jnp.int32)
+  for shift in range(0, pos_bits, chunk):
+    key = jnp.where(head, pos | ((vals >> shift) & low), -1)
+    out = out | ((jax.lax.cummax(key) & low) << shift)
+  return out
+
+
 @functools.partial(jax.jit, static_argnames=('capacity', 'fill_value'))
 def unique_stable(
     x: jax.Array,
@@ -47,19 +77,27 @@ def unique_stable(
 ) -> UniqueResult:
   """Order-preserving unique with a static output capacity.
 
-  Algorithm (all O(n log n), static shapes):
-    1. stable-sort ids (invalid ids mapped to a +inf sentinel) — within
-       an equal-value segment the original positions stay ascending, so
-       each segment HEAD already sits at its value's first occurrence
-       (no segment-min scatters needed),
+  Algorithm (all O(n log n), static shapes): four sorts, each carrying
+  as operands what the next step reads, and no gather or scatter over
+  the ``n`` elements —
+    1. stable-sort ``(id, position)`` by id (invalid ids mapped to a
+       +inf sentinel) — within an equal-value segment the original
+       positions stay ascending, so each segment HEAD already sits at
+       its value's first occurrence (no segment-min scatters needed),
     2. rank segments in appearance order by sorting the heads' original
-       positions,
-    3. recover each element's appearance rank scatter-free: a running
-       max propagates the segment head's sorted position, and argsort
-       inverts the rank and sort permutations (a scatter's cost
-       against these sorts is not measured on the chip; as written
-       the dedup is 46.9 of the per-batch step's 147.7 ms at the
-       flagship's shapes, PERF.md section 5).
+       positions, with the sorted ids and the sorted positions as
+       payload: the ids come out in appearance order (``values`` is a
+       static slice of them),
+    3. invert that permutation with a third sort, which puts each
+       head's appearance rank at its sorted position, and hand it to
+       the rest of its segment by a running max (`_copy_from_heads`),
+    4. sort ``(position, rank)`` by position: ``inverse``.
+  On the v5e a permutation gather ``a[perm]`` over 937,984 elements
+  costs 6.69 ms, a sort of them 0.86 ms with two operands (1.04 asked
+  to be stable) and 1.30 with three: the form that fetched these five
+  values with gathers spent 33.4 of its 38.2 ms at that size in them,
+  this one takes 5.2 (PERF.md section 6, PR 35).  A three-operand sort
+  compiles about half again as long as a two-operand one.
   """
   n = x.shape[0]
   if n == 0:
@@ -73,44 +111,36 @@ def unique_stable(
     valid = valid & (x != fill_value)
   big = jnp.iinfo(x.dtype).max
   xv = jnp.where(valid, x, big)
+  iota = jnp.arange(n, dtype=jnp.int32)
 
-  order = jnp.argsort(xv, stable=True)          # positions sorted by value
-  xs = xv[order]
-  head = jnp.concatenate([jnp.ones((1,), bool), xs[1:] != xs[:-1]])
-  head = head & (xs != big)
-  # unique id (in sorted order) of each sorted element; invalids -> n.
+  # ids sorted by value, each with its original position
+  xs, order = jax.lax.sort((xv, iota), num_keys=1, is_stable=True)
+  live = xs != big
+  head = jnp.concatenate([jnp.ones((1,), bool), xs[1:] != xs[:-1]]) & live
   # Up to n distinct segments exist; overflow past `capacity` must drop
   # the *latest-appearing* ids (preserving earlier local indices), so
   # ranking happens over all n segments before truncation.
-  uid = jnp.where(xs != big, jnp.cumsum(head) - 1, n)
-
   count = jnp.minimum(jnp.sum(head), capacity)
 
   # appearance order: stable sort -> the head of each segment carries
   # that value's first original position; sorting those positions gives
-  # the appearance ranking directly.  Non-heads sink to the tail.
+  # the appearance ranking directly.  Non-heads sink to the tail, tied:
+  # nothing reads their order, so the sort need not be stable.
   first_pos = jnp.where(head, order, jnp.iinfo(jnp.int32).max)
-  rank_to_sorted = jnp.argsort(first_pos)       # appearance rank -> sorted pos
-  vals_by_rank = xs[rank_to_sorted]             # [n] value of rank j
-  slot = jnp.arange(capacity)
-  values = jnp.where(slot < count,
-                     vals_by_rank[jnp.clip(slot, 0, n - 1)].astype(x.dtype),
-                     fill_value)
+  _, vals_by_rank, rank_to_sorted = jax.lax.sort(
+      (first_pos, xs, iota), num_keys=1, is_stable=False)
+  vals_by_rank = jnp.pad(vals_by_rank[:capacity],
+                         (0, max(capacity - n, 0)))
+  values = jnp.where(jnp.arange(capacity) < count, vals_by_rank, fill_value)
 
-  # Each element's appearance rank, scatter-free (TPU scatters measured
-  # ~3.5x the cost of sorts in this program): a running max over the
-  # sorted order gives every element its segment head's sorted
-  # position (heads come first within a segment), and inverting the
-  # rank permutation with argsort maps that head position to its rank.
-  head_pos = jax.lax.cummax(
-      jnp.where(head, jnp.arange(n, dtype=jnp.int32), -1))
-  sorted_to_rank = jnp.argsort(rank_to_sorted)  # sorted pos -> rank
-  inv_sorted = jnp.where(
-      (uid < n) & (head_pos >= 0),
-      sorted_to_rank[jnp.clip(head_pos, 0, n - 1)], -1)
-  inv_sorted = jnp.where(inv_sorted < capacity, inv_sorted, -1)
-  # inverse permutation of `order`, again via argsort instead of scatter
-  inverse = inv_sorted[jnp.argsort(order)]
+  # Each element's appearance rank: the keys of the last two sorts are
+  # permutations, so they ask for no stability (asking adds an operand).
+  _, sorted_to_rank = jax.lax.sort((rank_to_sorted, iota), num_keys=1,
+                                   is_stable=False)
+  rank = _copy_from_heads(head, sorted_to_rank)
+  inv_sorted = jnp.where(live & (rank < capacity), rank, -1)
+  _, inverse = jax.lax.sort((order, inv_sorted), num_keys=1,
+                            is_stable=False)
   return UniqueResult(values=values, inverse=inverse, count=count)
 
 
@@ -148,10 +178,11 @@ def induce_next(
   (`csrc/cuda/inducer.cu:94-141`).
 
   What is sorted is the table AS HANDED IN plus the candidates,
-  ``state.nodes.shape[0] + B*k`` elements (one stable sort and four
-  permutation gathers, linear in that length on the v5e); what comes
-  back is a table of ``capacity`` rows.  The two are apart so that a
-  caller can grow its table insertion by insertion: handed a table of
+  ``state.nodes.shape[0] + B*k`` elements (four sorts that carry
+  their payloads and a running max, no gather over them: 5.2 ms for
+  937,984 elements on the v5e, 0.7 for 169,984); what comes back is a
+  table of ``capacity`` rows.  The two are apart so that a caller can
+  grow its table insertion by insertion: handed a table of
   the rows filled so far and asked for ``min(rows + B*k, final)``, it
   gets the ids, counts and local indices a table held at ``final`` from
   the start would give (the result depends on the valid elements and
@@ -203,10 +234,14 @@ def emit_dedup(insertions) -> None:
   one ``sample.dedup`` flight-recorder event per compiled program,
   listing per insertion ``(name, sorted, table_rows, candidates)`` —
   the elements its sort covers, the capacity it returns and the
-  ``B*k`` it inserts.  A program whose tables start at their final
-  size (the mesh samplers) emits none."""
+  ``B*k`` it inserts — and ``gathered``, the elements that dedup moves
+  through a permutation gather (`GATHERS_PER_DEDUP` times ``sorted``;
+  an event without the field is of the form that gathered five times
+  ``sorted``).  A program whose tables start at their final size (the
+  mesh samplers) emits none."""
   if insertions:
     names, n_sorted, rows, candidates = zip(*insertions)
     recorder.emit('sample.dedup', insertions=len(insertions),
                   scope=list(names), sorted=list(n_sorted),
-                  table_rows=list(rows), candidates=list(candidates))
+                  table_rows=list(rows), candidates=list(candidates),
+                  gathered=[GATHERS_PER_DEDUP * n for n in n_sorted])

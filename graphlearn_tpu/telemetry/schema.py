@@ -76,7 +76,11 @@ EVENT_KINDS: Dict[str, str] = {
         'and per induce_next call its scope (hop<i> or '
         'hop<i>/<relation>), sorted (elements its sort covers: rows '
         'of the table handed in + candidates), table_rows (capacity '
-        'returned) and candidates (B*k); absent = tables held at '
+        'returned), candidates (B*k) and gathered (elements that '
+        'dedup moves through a permutation gather a[perm]: '
+        'ops.unique.GATHERS_PER_DEDUP x sorted, 0 since the sorts '
+        'carry their payloads; a record without the field is of the '
+        'form that gathered 5 x sorted); absent = tables held at '
         'their final size from the first hop on (the mesh samplers)',
     'exchange.plan':
         'parallel.FusedDistTreeEpoch at trace time, once per compiled '

@@ -7,10 +7,13 @@ tables held at their final size from the first hop on.
     outputs against an oracle built here from the public `init_node` /
     `induce_next` at the FINAL capacity from the first hop on;
   * `test_dedup_event_*`: the mechanism engaged, read off the
-    trace-time `sample.dedup` event and the lowered text's largest sort;
+    trace-time `sample.dedup` event and the lowered text's largest sort
+    — and, since a dedup's sorts carry what it gathered, that the
+    lowered dedups hold four sorts each and no gather (`gathered` 0);
   * `test_induce_next_default_lowers_as_before`: a caller that passes
     no capacity (the mesh samplers) keeps its program.
 """
+import functools
 import re
 
 import jax
@@ -29,6 +32,7 @@ from graphlearn_tpu.sampler.hetero_neighbor_sampler import (
     _hetero_multihop, _plan, typed_hop_capacities)
 from graphlearn_tpu.sampler.neighbor_sampler import (_multihop_sample,
                                                      hop_capacities)
+from graphlearn_tpu.telemetry.schema import EVENT_KINDS
 from graphlearn_tpu.typing import as_str, reverse_edge_type
 from graphlearn_tpu.utils.padding import INVALID_ID
 
@@ -277,6 +281,49 @@ def _largest_sort(text):
       r'"stablehlo\.sort".*?\}\) : \(tensor<(\d+)x', text, flags=re.S))
 
 
+_FUNC = re.compile(r'func\.func (?:public |private )?@([\w.$-]+)\(')
+
+
+def _dedup_ops(text):
+  """``(dedups, sorts, gathers, scatters)`` of a lowered program: its
+  calls of `unique_stable`, and the `stablehlo` sorts, gathers and
+  scatters those calls run — inside the `unique_stable` functions and
+  whatever they call, a function called three times counted three
+  times (`jnp.argsort` lowers to one shared function a shape)."""
+  heads = list(_FUNC.finditer(text))
+  body = {m.group(1): text[m.end():(heads[i + 1].start()
+                                     if i + 1 < len(heads) else len(text))]
+          for i, m in enumerate(heads)}
+
+  @functools.lru_cache(None)
+  def ops(name):
+    own = [body[name].count(f'"stablehlo.{op}"(')
+           for op in ('sort', 'gather', 'scatter')]
+    for callee in re.findall(r'call @([\w.$-]+)', body[name]):
+      own = [a + b for a, b in zip(own, ops(callee))]
+    return tuple(own)
+
+  dedups = re.findall(r'call @(unique_stable[\w.$-]*)', text)
+  totals = [sum(col) for col in zip(*(ops(name) for name in dedups))]
+  return (len(dedups), *totals)
+
+
+def test_dedup_ops_counts_the_gathering_form():
+  """The counter itself, on the form `tests/test_unique.py` keeps as
+  the oracle: four sorts and five gathers a dedup."""
+  from test_unique import _unique_stable_before
+
+  @functools.partial(jax.jit, static_argnums=1)
+  def unique_stable(x, capacity):     # the name `_dedup_ops` looks for
+    return _unique_stable_before(x, capacity)
+
+  def two(x):
+    return unique_stable(x, 64), unique_stable(x[:50], 50)
+  text = jax.jit(two).lower(
+      jax.ShapeDtypeStruct((80,), jnp.int32)).as_text()
+  assert _dedup_ops(text) == (2, 8, 10, 0)
+
+
 def _flagship_lower():
   """`_multihop_sample` at `sage-products`' shapes (batch 1024, fanout
   [15, 10, 5], 9,796,116 nodes), traced and lowered, never compiled."""
@@ -298,6 +345,10 @@ def test_dedup_event_and_largest_sort_at_flagship_shapes():
                           169_984 + 768_000]
   assert sum(ev['sorted']) == 1_124_352
   assert _largest_sort(lowered.as_text()) == 937_984   # not 1,705,984
+  # the seeds' dedup and three insertions: four sorts each, as before,
+  # and nothing moved through a permutation gather (it was 5 x sorted)
+  assert ev['gathered'] == [0, 0, 0]
+  assert _dedup_ops(lowered.as_text()) == (4, 16, 0, 0)
 
 
 def _igbh_lower(batch=32):
@@ -353,6 +404,11 @@ def test_dedup_event_and_largest_sort_at_igbh_shapes():
   # rounding `pack` pads
   assert _largest_sort(lowered.as_text()) == max(ev['sorted'])
   assert max(ev['sorted']) < 134_912 + 72_000
+  # the paper seeds' dedup and sixteen insertions, four sorts each: 68
+  # in the dedups, 64 of them the insertions' (the three seedless
+  # types' empty tables are dedups of no element and no sort)
+  assert ev['gathered'] == [0] * 16
+  assert _dedup_ops(lowered.as_text()) == (20, 68, 0, 0)
 
 
 def test_dedup_event_lists_old_capacity_plus_candidates_small():
@@ -364,6 +420,11 @@ def test_dedup_event_lists_old_capacity_plus_candidates_small():
   _, (ev,) = _dedup_events(lambda: sampler.sample_from_nodes(seeds))
   assert ev['sorted'] == [7 + 21, 28 + 42]
   assert ev['table_rows'] == [28, sampler.node_capacity(7)]
+  assert ev['gathered'] == [0, 0]
+  # the registry tells consumers of every field the emitter sets
+  for field in ('insertions', 'scope', 'sorted', 'table_rows',
+                'candidates', 'gathered'):
+    assert field in ev and field in EVENT_KINDS['sample.dedup'], field
   assert _dedup_events(lambda: sampler.sample_from_nodes(seeds))[1] == []
 
 
