@@ -44,13 +44,14 @@ from ..data.dataset import Dataset
 from ..data.feature import _device_gather
 from ..models.train import (TrainState, make_extracted_eval_step,
                             make_extracted_supervised_step)
-from ..ops.negative import sample_negative
 from ..ops.pallas_gather import pallas_enabled
 from ..ops.pallas_sample import fused_sample_enabled
 from ..ops.pallas_window import prepare_window_table
 from ..sampler.base import NegativeSampling
 from ..sampler.neighbor_sampler import (NeighborSampler, _multihop_sample,
-                                        _triplet_neg_dst)
+                                        hop_capacities, hop_windows,
+                                        link_metadata, link_plan,
+                                        link_seeds)
 from ..utils.profiling import metrics, step_annotation
 from .link_loader import EdgeSeedBatcher
 from .node_loader import SeedBatcher
@@ -907,11 +908,18 @@ class FusedLinkEpoch(_SnapshotHooks):
   link loss — the objective of the reference's unsupervised SAGE
   (`examples/graph_sage_unsup_ppi.py:41-45`).
 
-  The seed/negative/metadata assembly mirrors
-  `sampler.neighbor_sampler.NeighborSampler.sample_from_edges`
-  (binary: `neighbor_sampler.py:255-282`, triplet: `:284-300`) in
-  functional form (keys passed in, not held); the parity test pins
-  the two paths together.
+  The seeds, the strict negative draw and the metadata are
+  `sampler.neighbor_sampler.link_seeds` / `link_metadata`, the
+  functions `NeighborSampler.sample_from_edges` calls (keys passed
+  in, not held).  A batch states its expansion's static hop layout
+  (``hop_capacities`` / ``hop_windows`` for the seed width ``2B +
+  2 * negatives`` binary, ``2B + B * amount`` triplet) and the step
+  applies the model through `models.train.apply_to_batch`, so a
+  `BasicGNN` computes each layer over the hops it feeds only and
+  aggregates by fanout window; the link loss reads the seed rows,
+  which lie below ``C_0``.  No labels are gathered: the link loss
+  reads none.  `batch_fill` counts the valid rows and edge slots of
+  the batches every dispatch ran, on the device.
 
   Args:
     data: `Dataset` with fully device-resident features (labels
@@ -958,8 +966,7 @@ class FusedLinkEpoch(_SnapshotHooks):
     self._dev = dict(indptr=graph.indptr, indices=graph.indices,
                      hot=None if self._tiered else feat.hot_tier,
                      id2index=(None if self._tiered
-                               else feat._id2index_dev),
-                     labels=data.get_node_label_device())
+                               else feat._id2index_dev))
     self._init_fused_sampling(graph)
 
     rows, cols = _as_edge_pairs(edge_label_index)
@@ -967,29 +974,31 @@ class FusedLinkEpoch(_SnapshotHooks):
                                     self.batch_size, shuffle, drop_last,
                                     seed)
 
-    b = self.batch_size
-    if self.neg.is_binary():
-      self._num_neg = self.neg.sample_size(b)
-      seed_width = 2 * b + 2 * self._num_neg
-    else:
-      self._amount = int(np.ceil(float(self.neg.amount)))
-      self._num_neg = b * self._amount
-      seed_width = 2 * b + self._num_neg
+    (self._mode, self._num_neg, self._amount,
+     seed_width) = link_plan(self.neg, self.batch_size)
     ref = NeighborSampler(graph, self.fanouts, seed=0)
     self._node_cap = ref.node_capacity(seed_width)
+    self._layout = (hop_capacities(seed_width, self.fanouts,
+                                   self._node_cap),
+                    hop_windows(seed_width, self.fanouts))
 
     self._base_key = jax.random.key(seed or 0)
     self._epoch_idx = 0
+    self._fill = []                   # per dispatch [4] int32, on device
+    self._filled = np.zeros(4, np.int64)
     from ..models.train import make_unsupervised_step
-    step_apply = jax.checkpoint(apply_fn) if remat else apply_fn
-    self._apply = apply_fn            # un-remat'd: evaluate() is fwd-only
-    self._step = make_unsupervised_step(step_apply, tx)
+    self._apply = apply_fn            # evaluate() is fwd-only: no remat
+    self._step = make_unsupervised_step(apply_fn, tx, remat=remat)
     self._compiled = _counted_jit(self._epoch_fn, donate_argnums=(0,),
                                   static_argnums=(6,))
     self._compiled_eval = _counted_jit(self._auc_fn,
                                        static_argnums=(5,))
+    # the sample-only scan: the tiered epochs' front half, and the
+    # epoch's own draw for whoever wants the batches a dispatch trained
+    # on again (`epoch_key`)
+    self._compiled_collect = _counted_jit(
+        self._link_collect_fn, static_argnames=('collect_x',))
     if self._tiered:
-      self._compiled_collect = _counted_jit(self._link_collect_fn)
       self._compiled_train = _counted_jit(self._link_train_fn,
                                           donate_argnums=(0,))
       self._compiled_auc_consume = _counted_jit(self._auc_consume_fn)
@@ -1004,16 +1013,46 @@ class FusedLinkEpoch(_SnapshotHooks):
   _fill_cold_x = _SupervisedScanEpoch._fill_cold_x
   compile_count = _SupervisedScanEpoch.compile_count
 
+  def epoch_key(self, epoch_idx: int) -> jax.Array:
+    """The key of epoch ``epoch_idx`` (`run` numbers them from 1);
+    step ``i`` of a one-dispatch epoch draws with ``fold_in(key, i)``,
+    as `_link_collect_fn` does — the one statement of the schedule."""
+    return jax.random.fold_in(self._base_key, epoch_idx)
+
+  def batch_fill(self) -> dict:
+    """Valid node rows and valid edge slots of the batches that
+    every dispatch so far ran, beside the padded extents they were
+    laid out over (``rows_valid`` / ``rows``, ``edges_valid`` /
+    ``edge_slots``).  Counted on the device inside each program; this
+    call pulls them."""
+    for fill in self._fill:
+      self._filled += np.asarray(fill, np.int64)
+    self._fill = []
+    rows_valid, rows, edges_valid, slots = (int(v) for v in self._filled)
+    return dict(rows_valid=rows_valid, rows=rows, edges_valid=edges_valid,
+                edge_slots=slots)
+
+  @staticmethod
+  def _fill_of(batch: Batch) -> jax.Array:
+    """``[rows valid, rows, edge slots valid, edge slots]`` of one
+    batch."""
+    return jnp.stack([
+        jnp.sum(batch.node_mask, dtype=jnp.int32),
+        jnp.int32(batch.node_mask.shape[0]),
+        jnp.sum(batch.edge_mask, dtype=jnp.int32),
+        jnp.int32(batch.edge_mask.shape[0])])
+
   def _link_collect_fn(self, srcs: jax.Array, dsts: jax.Array,
-                       labs: jax.Array, key: jax.Array, dev: dict):
-    """Sample-only link scan (negatives + expansion + metadata, no
-    feature gather) for one chunk."""
+                       labs: jax.Array, key: jax.Array, dev: dict,
+                       collect_x: bool = False):
+    """Sample-only link scan (negatives + expansion + metadata; the
+    feature gather only with ``collect_x``) for one chunk."""
 
     def body(_, xs):
       i, src, dst, lab = xs
       return 0, self._link_batch(src, dst, lab,
                                  jax.random.fold_in(key, i), dev,
-                                 False, collect_x=False)
+                                 False, collect_x=collect_x)
 
     steps = jnp.arange(srcs.shape[0], dtype=jnp.int32)
     _, batches = jax.lax.scan(body, 0, (steps, srcs, dsts, labs))
@@ -1028,11 +1067,12 @@ class FusedLinkEpoch(_SnapshotHooks):
       state = jax.tree_util.tree_map(
           lambda new, old: jnp.where(any_valid, new, old),
           new_state, state)
-      return state, (loss, jnp.sum((src >= 0) & (dst >= 0)))
+      return state, (loss, jnp.sum((src >= 0) & (dst >= 0)),
+                     self._fill_of(batch))
 
-    state, (losses, valids) = jax.lax.scan(body, state,
-                                           (batches, srcs, dsts))
-    return state, losses, jnp.sum(valids)
+    state, (losses, valids, fill) = jax.lax.scan(body, state,
+                                                 (batches, srcs, dsts))
+    return state, losses, jnp.sum(valids), jnp.sum(fill, axis=0)
 
   def _auc_consume_fn(self, params, batches):
     def body(carry, batch):
@@ -1045,9 +1085,9 @@ class FusedLinkEpoch(_SnapshotHooks):
     """Embed one batch and accumulate the pairwise (pos > neg) win
     counts — the batched rank-sum AUC body, shared by the
     single-program `_auc_fn` and the tiered `_auc_consume_fn`."""
+    from ..models.train import apply_to_batch
     b = self.batch_size
-    emb = self._apply(params, batch.x, batch.edge_index,
-                      batch.edge_mask)
+    emb = apply_to_batch(self._apply, params, batch)
     eli = batch.metadata['edge_label_index']        # [2, b + nn]
     mask = batch.metadata['edge_label_mask']
     score = (emb[eli[0]] * emb[eli[1]]).sum(-1)
@@ -1131,65 +1171,36 @@ class FusedLinkEpoch(_SnapshotHooks):
                   label: Optional[jax.Array], key: jax.Array,
                   dev: dict, use_pallas: bool,
                   collect_x: bool = True) -> Batch:
-    """Functional seeds+negatives+metadata assembly (see class doc).
-    ``collect_x=False`` skips the feature gather (tiered collect scans
-    — the cold service fills x between dispatches)."""
+    """One link batch: `link_seeds` (the strict negatives), the
+    expansion, `link_metadata` (see class doc).  ``collect_x=False``
+    skips the feature gather (tiered collect scans — the cold service
+    fills x between dispatches)."""
     b = self.batch_size
     pair_valid = (src >= 0) & (dst >= 0)
-    k_neg = jax.random.fold_in(key, 0)
-    k_hop = jax.random.fold_in(key, 1)
     pos_label = (label if label is not None
                  else jnp.ones((b,), jnp.int32))
-
-    if self.neg.is_binary():
-      nn = self._num_neg
-      nres = sample_negative(dev['indptr'], dev['indices'], nn, k_neg,
-                             strict=True, padding=True)
-      seeds = jnp.concatenate([src, dst, nres.rows, nres.cols])
-      sl, out = self._expand(seeds, k_hop, dev)
-      metadata = {
-          'edge_label_index': jnp.stack([
-              jnp.concatenate([sl[:b], sl[2 * b:2 * b + nn]]),
-              jnp.concatenate([sl[b:2 * b], sl[2 * b + nn:]])]),
-          'edge_label': jnp.concatenate(
-              [pos_label, jnp.zeros((nn,), pos_label.dtype)]),
-          'edge_label_mask': jnp.concatenate(
-              [pair_valid, jnp.ones((nn,), jnp.bool_)]),
-          'seed_local': sl,
-      }
-    else:
-      amount = self._amount
-      neg_dst = _triplet_neg_dst(dev['indptr'], dev['indices'], src,
-                                 k_neg, amount=amount,
-                                 num_nodes=self._num_nodes)
-      seeds = jnp.concatenate([src, dst, neg_dst.reshape(-1)])
-      sl, out = self._expand(seeds, k_hop, dev)
-      metadata = {
-          'src_index': sl[:b],
-          'dst_pos_index': sl[b:2 * b],
-          'dst_neg_index': sl[2 * b:].reshape(b, amount),
-          'pair_mask': pair_valid,
-          'seed_local': sl,
-      }
-    nodes, row, col, emask = out
+    seeds = link_seeds(dev['indptr'], dev['indices'], src, dst,
+                       jax.random.fold_in(key, 0), mode=self._mode,
+                       num_neg=self._num_neg, amount=self._amount,
+                       num_nodes=self._num_nodes, layout=self._layout)
+    (nodes, _count, row, col, _edge, emask, seed_local, nsn,
+     nse) = _multihop_sample(
+         dev['indptr'], dev['indices'], None, seeds,
+         jax.random.fold_in(key, 1), dev['win2d'],
+         fanouts=self.fanouts, node_cap=self._node_cap,
+         with_edge=False, sort_locality=self.sort_locality,
+         use_fused=self._use_fused, win_e=self._win_e)
     return Batch(
         x=(_device_gather(dev['hot'], nodes, dev['id2index'],
                           use_pallas=use_pallas) if collect_x
            else None),
-        y=(_gather_labels(dev['labels'], nodes)
-           if dev['labels'] is not None else None),
         edge_index=jnp.stack([row, col]),
         node=nodes, node_mask=nodes >= 0, edge_mask=emask,
-        batch=seeds, batch_size=self.batch_size, metadata=metadata)
-
-  def _expand(self, seeds: jax.Array, key: jax.Array, dev: dict):
-    (nodes, _count, row, col, _edge, emask, seed_local, _nsn,
-     _nse) = _multihop_sample(
-         dev['indptr'], dev['indices'], None, seeds, key, dev['win2d'],
-         fanouts=self.fanouts, node_cap=self._node_cap,
-         with_edge=False, sort_locality=self.sort_locality,
-         use_fused=self._use_fused, win_e=self._win_e)
-    return seed_local, (nodes, row, col, emask)
+        batch=seeds, batch_size=b, num_sampled_nodes=nsn,
+        num_sampled_edges=nse,
+        metadata=link_metadata(seed_local, b, self._mode, self._num_neg,
+                               self._amount, pair_valid, pos_label,
+                               self._layout))
 
   def _epoch_fn(self, state: TrainState, srcs: jax.Array,
                 dsts: jax.Array, labels: Optional[jax.Array],
@@ -1205,14 +1216,15 @@ class FusedLinkEpoch(_SnapshotHooks):
       state = jax.tree_util.tree_map(
           lambda new, old: jnp.where(any_valid, new, old),
           new_state, state)
-      return state, (loss, jnp.sum((src >= 0) & (dst >= 0)))
+      return state, (loss, jnp.sum((src >= 0) & (dst >= 0)),
+                     self._fill_of(batch))
 
     steps = jnp.arange(srcs.shape[0], dtype=jnp.int32)
     labs = (labels if labels is not None
             else jnp.ones_like(srcs))             # constant positive label
-    state, (losses, valids) = jax.lax.scan(
+    state, (losses, valids, fill) = jax.lax.scan(
         body, state, (steps, srcs, dsts, labs))
-    return state, losses, jnp.sum(valids)
+    return state, losses, jnp.sum(valids), jnp.sum(fill, axis=0)
 
   def run(self, state: TrainState) -> Tuple[TrainState, 'EpochStats']:
     """One epoch; ``state`` is DONATED (thread the returned one).
@@ -1234,7 +1246,7 @@ class FusedLinkEpoch(_SnapshotHooks):
     dsts = np.stack(dsts)
     labels = np.stack(labs).astype(np.int32) if labs else None
     self._epoch_idx += 1
-    key = jax.random.fold_in(self._base_key, self._epoch_idx)
+    key = self.epoch_key(self._epoch_idx)
     s = srcs.shape[0]
     chunk = self._chunk or s
     losses, valid = [], None
@@ -1274,11 +1286,17 @@ class FusedLinkEpoch(_SnapshotHooks):
               sp, dp, lab_piece if lab_piece is not None
               else jnp.ones_like(sp), ck, self._dev)
           batches = self._fill_cold_x(batches)
-          state, ls, v = self._compiled_train(state, batches, sp, dp)
+          state, ls, v, fill = self._compiled_train(state, batches, sp,
+                                                    dp)
         else:
-          state, ls, v = self._compiled(
+          state, ls, v, fill = self._compiled(
               state, piece(srcs, c0), piece(dsts, c0), lab_piece,
               ck, self._dev, pallas_enabled())
+      self._fill.append(fill)
+      if len(self._fill) > 64:
+        # the runtime keeps at most 32 programs in flight: this one has
+        # long finished, and pulling it waits for nothing
+        self._filled += np.asarray(self._fill.pop(0), np.int64)
       losses.append(ls[:real])
       valid = v if valid is None else valid + v
       self._save_chunk_snapshot(state, c0 + chunk, chunk, losses,

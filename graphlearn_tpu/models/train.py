@@ -101,8 +101,9 @@ def apply_to_batch(apply_fn, params, batch):
     same models: their `SAGEConv` / `GATConv` layers then aggregate by
     window instead of scattering every edge slot into the target rows
     — the same logits to float32 round-off.  A batch without the entry
-    (the mesh loader's, a fused epoch's, link and induced-subgraph
-    batches) keeps the `segment_*` path.
+    (the mesh loader's, a fused node epoch's, induced-subgraph
+    batches) keeps the `segment_*` path; link batches state it since
+    PR 38.
   """
   md = getattr(batch, 'metadata', None) or {}
   kwargs = {}
@@ -215,16 +216,22 @@ def link_loss_from_metadata(emb: jax.Array, metadata: dict) -> jax.Array:
                  '(binary) nor src_index (triplet) link labels')
 
 
-def make_unsupervised_step(apply_fn, tx: optax.GradientTransformation):
+def make_unsupervised_step(apply_fn, tx: optax.GradientTransformation,
+                           remat: bool = False):
   """Build a jitted link-loss step.  The loss dispatches binary vs
   triplet by the batch's (static) metadata keys
   (`link_loss_from_metadata`), so one builder serves both the
-  per-batch loaders and `loader.fused.FusedLinkEpoch`."""
+  per-batch loaders and `loader.fused.FusedLinkEpoch`.  The model is
+  applied through `apply_to_batch`: a link batch that states its hop
+  layout gets the seed rows ``[C_0, out]``, below which every
+  endpoint's seed-local row lies.  ``remat`` rematerialises the
+  model's forward in the backward pass (`jax.checkpoint`)."""
 
   @jax.jit
   def unsupervised_step(state: TrainState, batch):
     def loss_fn(params):
-      emb = apply_fn(params, batch.x, batch.edge_index, batch.edge_mask)
+      embed = lambda p: apply_to_batch(apply_fn, p, batch)
+      emb = (jax.checkpoint(embed) if remat else embed)(params)
       with layer_scope('model', 'loss'):
         return link_loss_from_metadata(emb, batch.metadata)
 

@@ -36,6 +36,7 @@ from ..ops.pallas_window import prepare_window_table
 from ..ops.negative import edge_in_csr, sample_negative
 from ..ops.subgraph import induced_subgraph
 from ..ops.unique import InducerState, emit_dedup, induce_next, init_node
+from ..telemetry.recorder import recorder
 from ..utils.padding import INVALID_ID, max_sampled_nodes, round_up
 from ..utils.profiling import layer_scope
 from .base import (BaseSampler, EdgeSamplerInput, NegativeSampling,
@@ -207,6 +208,11 @@ def _multihop_sample(
             num_sampled_nodes, num_sampled_edges)
 
 
+#: redraws per strict-negative slot, binary (`ops.negative.sample_negative`)
+#: and triplet (`_triplet_neg_dst`) alike
+NEG_TRIALS = 5
+
+
 @functools.partial(jax.jit, static_argnames=('amount', 'num_nodes'))
 def _triplet_neg_dst(indptr: jax.Array, indices: jax.Array, src: jax.Array,
                      key: jax.Array, *, amount: int, num_nodes: int
@@ -215,7 +221,7 @@ def _triplet_neg_dst(indptr: jax.Array, indices: jax.Array, src: jax.Array,
   trials), the vectorized analog of the curand retry loop
   (`csrc/cuda/random_negative_sampler.cu:56-94`)."""
   b = src.shape[0]
-  trials = 5
+  trials = NEG_TRIALS
   cand = jax.random.randint(key, (trials, b * amount), 0, num_nodes,
                             dtype=jnp.int32)
   rows = jnp.tile(jnp.repeat(src, amount)[None, :], (trials, 1))
@@ -224,6 +230,95 @@ def _triplet_neg_dst(indptr: jax.Array, indices: jax.Array, src: jax.Array,
   pick = jnp.where(jnp.any(ok, axis=0), jnp.argmax(ok, axis=0), trials - 1)
   out = cand[pick, jnp.arange(b * amount)]
   return out.reshape(b, amount)
+
+
+def link_plan(neg: NegativeSampling, batch_size: int):
+  """``(mode, num_neg, amount, seed_width)`` of a link batch of
+  ``batch_size`` seed edges: binary draws ``num_neg`` pairs (two
+  endpoints each), triplet ``amount`` destinations per seed edge
+  (``num_neg = batch_size * amount`` endpoints); the seeds are ``[src,
+  dst, negatives]``, ``seed_width`` of them."""
+  b = int(batch_size)
+  if neg.is_binary():
+    num_neg = neg.sample_size(b)
+    return 'binary', num_neg, 0, 2 * b + 2 * num_neg
+  amount = int(np.ceil(float(neg.amount)))
+  return 'triplet', b * amount, amount, 2 * b + b * amount
+
+
+def link_seeds(indptr: jax.Array, indices: jax.Array, src: jax.Array,
+               dst: jax.Array, key: jax.Array, *, mode: str, num_neg: int,
+               amount: int, num_nodes: int, layout) -> jax.Array:
+  """The seeds of one link batch, ``[src, dst, negatives]``: the strict
+  negative draw (binary pairs by `sample_negative`, triplet
+  destinations by `_triplet_neg_dst`) under ``glt.sample/negative``.
+  ONE definition for `NeighborSampler.sample_from_edges` and
+  `loader.fused.FusedLinkEpoch`.  ``layout`` is ``(hop_capacities,
+  hop_windows)`` of the expansion the seeds go on to.
+
+  Where it is traced (a compiled program: the fused epochs' scan
+  bodies, `_link_seeds`), its flight-recorder events are the program's
+  record, once per compile: ``sample.negative`` — ``mode``,
+  ``req_num``, ``trials``, ``strict``, ``padding``, ``seed_width`` —
+  and ``link.batch`` — the hop capacities and windows the batch
+  states and ``negative_endpoints``, the seeds that are negatives'."""
+  with layer_scope('sample', 'negative'):
+    if mode == 'binary':
+      nres = sample_negative(indptr, indices, num_neg, key,
+                             trials=NEG_TRIALS, strict=True, padding=True)
+      negs = jnp.concatenate([nres.rows, nres.cols])
+    else:
+      negs = _triplet_neg_dst(indptr, indices, src, key, amount=amount,
+                              num_nodes=num_nodes).reshape(-1)
+  seeds = jnp.concatenate([src, dst, negs])
+  recorder.emit('sample.negative', mode=mode, req_num=num_neg,
+                trials=NEG_TRIALS, strict=True, padding=True,
+                seed_width=int(seeds.shape[0]))
+  recorder.emit('link.batch', mode=mode, batch=int(src.shape[0]),
+                seed_width=int(seeds.shape[0]),
+                negative_endpoints=int(negs.shape[0]),
+                hop_capacities=layout[0], hop_windows=layout[1])
+  return seeds
+
+
+_link_seeds = jax.jit(link_seeds, static_argnames=(
+    'mode', 'num_neg', 'amount', 'num_nodes', 'layout'))
+
+
+def link_metadata(seed_local: jax.Array, batch_size: int, mode: str,
+                  num_neg: int, amount: int, pair_valid: jax.Array,
+                  pos_label: jax.Array, layout) -> dict:
+  """A link batch's metadata from the seed-local rows of ``[src, dst,
+  negatives]``: PyG's link keys (binary ``edge_label_index`` /
+  ``edge_label`` / ``edge_label_mask``, positives first; triplet
+  ``src_index`` / ``dst_pos_index`` / ``dst_neg_index`` / ``pair_mask``)
+  beside ``seed_local`` and the static ``hop_capacities`` /
+  ``hop_windows`` (``layout``) that `models.train.apply_to_batch`
+  hands to the model."""
+  b, sl = int(batch_size), seed_local
+  if mode == 'binary':
+    md = {
+        'edge_label_index': jnp.stack([
+            jnp.concatenate([sl[:b], sl[2 * b:2 * b + num_neg]]),
+            jnp.concatenate([sl[b:2 * b], sl[2 * b + num_neg:]])]),
+        # raw labels here, positives then zeros; binary user labels
+        # get the reference's +1 shift at the loader
+        # (`loader/link_loader.py:146-186`)
+        'edge_label': jnp.concatenate(
+            [pos_label, jnp.zeros((num_neg,), pos_label.dtype)]),
+        'edge_label_mask': jnp.concatenate(
+            [pair_valid, jnp.ones((num_neg,), jnp.bool_)]),
+    }
+  else:
+    md = {
+        'src_index': sl[:b],
+        'dst_pos_index': sl[b:2 * b],
+        'dst_neg_index': sl[2 * b:].reshape(b, amount),
+        'pair_mask': pair_valid,
+    }
+  md['seed_local'] = sl
+  md['hop_capacities'], md['hop_windows'] = layout
+  return md
 
 
 class NeighborSampler(BaseSampler):
@@ -324,8 +419,9 @@ class NeighborSampler(BaseSampler):
     """Link-prediction sampling with binary/triplet negatives.
 
     Reference `sampler/neighbor_sampler.py:255-381`: seeds are the
-    positive endpoints plus sampled negatives; metadata carries the
-    local label indices PyG expects.
+    positive endpoints plus sampled negatives (`link_seeds`); metadata
+    carries the local label indices PyG expects (`link_metadata`) and,
+    as a node batch does, the expansion's static hop layout.
     """
     neg = neg_sampling or inputs.neg_sampling
     src = jnp.asarray(np.asarray(inputs.row, dtype=np.int32))
@@ -334,65 +430,32 @@ class NeighborSampler(BaseSampler):
     # Static-batch padding: (-1, -1) pairs are mask-outs, never examples.
     pair_valid = (src >= 0) & (dst >= 0)
     key = self._next_key()
+    pos_label = (jnp.asarray(inputs.label) if inputs.label is not None
+                 else jnp.ones((b,), jnp.int32))
 
     if neg is None:
-      seeds = jnp.concatenate([src, dst])
-      out = self.sample_from_nodes(NodeSamplerInput(node=seeds))
+      out = self.sample_from_nodes(
+          NodeSamplerInput(node=jnp.concatenate([src, dst])))
       sl = out.metadata['seed_local']
-      out.metadata = {
+      out.metadata.update({
           'edge_label_index': jnp.stack([sl[:b], sl[b:2 * b]]),
-          'edge_label': (inputs.label if inputs.label is not None
-                         else jnp.ones((b,), jnp.int32)),
+          'edge_label': pos_label,
           'edge_label_mask': pair_valid,
-          'seed_local': sl,
-      }
+      })
       return out
 
-    if neg.is_binary():
-      num_neg = neg.sample_size(b)
-      nres = sample_negative(
-          self.graph.indptr, self.graph.indices, num_neg, key,
-          strict=True, padding=True)
-      seeds = jnp.concatenate([src, dst, nres.rows, nres.cols])
-      out = self.sample_from_nodes(NodeSamplerInput(node=seeds))
-      sl = out.metadata['seed_local']
-      pos_label = (inputs.label if inputs.label is not None
-                   else jnp.ones((b,), jnp.int32))
-      edge_label_index = jnp.stack([
-          jnp.concatenate([sl[:b], sl[2 * b:2 * b + num_neg]]),
-          jnp.concatenate([sl[b:2 * b], sl[2 * b + num_neg:]]),
-      ])
-      # Binary labels get the reference's +1 shift semantics applied at
-      # the loader (`loader/link_loader.py:146-186`); raw here: pos
-      # labels then zeros.
-      edge_label = jnp.concatenate(
-          [pos_label, jnp.zeros((num_neg,), pos_label.dtype)])
-      edge_label_mask = jnp.concatenate(
-          [pair_valid, jnp.ones((num_neg,), jnp.bool_)])
-      out.metadata = {
-          'edge_label_index': edge_label_index,
-          'edge_label': edge_label,
-          'edge_label_mask': edge_label_mask,
-          'seed_local': sl,
-      }
-      return out
-
-    # triplet: per-positive-edge negative destinations.
-    amount = int(np.ceil(float(neg.amount)))
-    num_neg = b * amount
-    neg_dst = _triplet_neg_dst(
-        self.graph.indptr, self.graph.indices, src, key,
-        amount=amount, num_nodes=self.graph.num_nodes)
-    seeds = jnp.concatenate([src, dst, neg_dst.reshape(-1)])
+    mode, num_neg, amount, width = link_plan(neg, b)
+    node_cap = self.node_capacity(width)
+    layout = (hop_capacities(width, self.num_neighbors, node_cap),
+              hop_windows(width, self.num_neighbors))
+    seeds = _link_seeds(
+        self.graph.indptr, self.graph.indices, src, dst, key, mode=mode,
+        num_neg=num_neg, amount=amount, num_nodes=self.graph.num_nodes,
+        layout=layout)
     out = self.sample_from_nodes(NodeSamplerInput(node=seeds))
-    sl = out.metadata['seed_local']
-    out.metadata = {
-        'src_index': sl[:b],
-        'dst_pos_index': sl[b:2 * b],
-        'dst_neg_index': sl[2 * b:].reshape(b, amount),
-        'pair_mask': pair_valid,
-        'seed_local': sl,
-    }
+    out.metadata = link_metadata(out.metadata['seed_local'], b, mode,
+                                 num_neg, amount, pair_valid, pos_label,
+                                 layout)
     return out
 
   # (triplet negative sampling lives in module-level `_triplet_neg_dst`

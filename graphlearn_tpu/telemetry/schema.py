@@ -82,6 +82,18 @@ EVENT_KINDS: Dict[str, str] = {
         'carry their payloads; a record without the field is of the '
         'form that gathered 5 x sorted); absent = tables held at '
         'their final size from the first hop on (the mesh samplers)',
+    'sample.negative':
+        'sampler.neighbor_sampler.link_seeds at trace time, once per '
+        'compiled program that draws the seeds of a link batch '
+        '(FusedLinkEpoch scans, the per-batch _link_seeds): mode '
+        '(binary/triplet), req_num (pairs or destinations drawn), '
+        'trials, strict, padding, seed_width (2B + the negatives\' '
+        'endpoints)',
+    'link.batch':
+        'sampler.neighbor_sampler.link_seeds beside sample.negative: '
+        'mode, batch (seed edges), seed_width, negative_endpoints (seeds '
+        'that are negatives\'), and the hop_capacities / hop_windows the '
+        'batch states for its expansion',
     'exchange.plan':
         'parallel.FusedDistTreeEpoch at trace time, once per compiled '
         'mesh program: scope, layout (the exchange layout chosen: '

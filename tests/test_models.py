@@ -526,11 +526,13 @@ def test_gcn_declines_the_capacities():
 
 
 @pytest.mark.parametrize('source', ['hand-built', 'dist-loader',
-                                    'link-loader', 'plain-apply-fn'])
+                                    'subgraph-loader', 'plain-apply-fn'])
 def test_batches_without_capacities_run_the_whole_table(source):
-  """Only `NeighborSampler.sample_from_nodes` states the layout; every
-  other batch, and every ``apply_fn`` that is not a model's own
-  ``apply``, gets the whole-table stack."""
+  """Only `NeighborSampler.sample_from_nodes` (and the link batches
+  built on it, `tests/test_link_layout.py`) states the layout; every
+  other batch — hand-built, the mesh loader's, an induced subgraph's —,
+  and every ``apply_fn`` that is not a model's own ``apply``, gets the
+  whole-table stack."""
   from graphlearn_tpu.loader.transform import Batch
   from graphlearn_tpu.models.train import apply_to_batch
   model = GraphSAGE(hidden_features=8, out_features=5, num_layers=2)
@@ -553,12 +555,10 @@ def test_batches_without_capacities_run_the_whole_table(source):
     stacked = next(iter(DistNeighborLoader(
         ds, [2, 2], np.arange(n), batch_size=4, mesh=make_mesh(4), seed=0)))
     batch = jax.tree_util.tree_map(lambda v: v[0], stacked)
-  elif source == 'link-loader':
-    from graphlearn_tpu.loader import LinkNeighborLoader
-    rng = np.random.default_rng(0)
-    pairs = np.stack([rng.integers(0, 400, 32), rng.integers(0, 400, 32)])
-    batch = next(iter(LinkNeighborLoader(_skewed_dataset(), [4, 4], pairs,
-                                         batch_size=16)))
+  elif source == 'subgraph-loader':
+    from graphlearn_tpu.loader import SubGraphLoader
+    batch = next(iter(SubGraphLoader(_skewed_dataset(), [4, 4],
+                                     np.arange(64), batch_size=16)))
   else:
     batch = next(iter(NeighborLoader(_skewed_dataset(), [4, 4],
                                      np.arange(64), batch_size=16)))
