@@ -18,30 +18,90 @@ idiomatic mapping is
 The reference's ``DeviceGroup`` replication/sharding across NVLink
 cliques maps to sharding the hot tier over a `jax.sharding.Mesh` (see
 :mod:`graphlearn_tpu.parallel`); single-device behavior is here.
+
+**Layout rule.**  The hot tier is stored in the layout its row gather
+reads: ``[rows, lane_width(D, dtype)]`` (`utils.padding.lane_width`),
+the ``D`` columns zero-padded to a lane multiple once, when the tier is
+placed (`_store_hot`; a ``feature.layout`` flight-recorder event says
+what was stored).  A TPU lays out a 2-D array whose row width is no
+lane multiple column-major, and a gather program handed one copies
+the whole table into a row-major one, rows padded to 128 lanes, on
+every call.  A ``[N, 128]`` array is row-major by default, and its
+first 100 columns are a bitcast of it: `_device_gather`, the one place
+that takes the ``D`` columns back, reads the stored rows in place, in
+a per-batch program and inside a fused epoch's scan alike.  At a lane
+multiple (and at rows the gather reads in place without a copy,
+`utils.padding.GATHERED_IN_PLACE` columns or fewer) the tier is the
+caller's own buffer.
+Nothing outside this module sees the stored width (`HotTier`).  A
+padded 32-bit tier is DMA-eligible for the Pallas gather under
+``GLT_PALLAS=1`` (``D % 128 == 0`` after padding).
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
-from typing import Optional
+from typing import Optional, Union
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from ..ops.pallas_gather import gather_rows, pallas_enabled
-from ..utils.padding import next_power_of_two
+from ..telemetry.recorder import recorder
+from ..utils.padding import lane_width, next_power_of_two
 from ..utils.profiling import layer_scope
 from ..utils.tensor import convert_to_array
 
 
+@functools.partial(jax.tree_util.register_dataclass,
+                   data_fields=['rows'], meta_fields=['width'])
+@dataclasses.dataclass(frozen=True)
+class HotTier:
+  """A device tier as stored: ``rows`` is ``[N, lane_width(width)]``,
+  whose first ``width`` columns are the table and the rest zeros.  A
+  pytree whose ``width`` is static, so it crosses a ``jit`` boundary as
+  one array argument and `_device_gather` takes the table's columns
+  back inside the same program."""
+  rows: jax.Array
+  width: int
+
+
+def _first_columns(x: jax.Array, width: int) -> jax.Array:
+  return x if x.shape[1] == width else x[:, :width]
+
+
+@functools.partial(jax.jit, static_argnums=(1,))
+def _pad_columns(x: jax.Array, width: int) -> jax.Array:
+  return jnp.pad(x, ((0, 0), (0, width - x.shape[1])))
+
+
+def _store_hot(x: jax.Array) -> HotTier:
+  """``[N, D]`` device rows -> the stored tier (module docstring,
+  layout rule): ``x`` itself where ``lane_width`` keeps ``D``, else one
+  zero-padded copy.  Emits ``feature.layout``."""
+  d = int(x.shape[1])
+  width = lane_width(d, x.dtype)
+  rows = x if width == d else _pad_columns(x, width)
+  recorder.emit('feature.layout', width=d, stored_width=width,
+                dtype=str(x.dtype), rows=int(x.shape[0]),
+                stored_bytes=int(rows.nbytes), padded=width != d)
+  return HotTier(rows, d)
+
+
 @functools.partial(jax.jit, static_argnames=('use_pallas', 'part'))
-def _device_gather(hot: jax.Array, ids: jax.Array, id2index, *,
-                   use_pallas: bool, part: Optional[str] = None
-                   ) -> jax.Array:
+def _device_gather(hot: Union[HotTier, jax.Array], ids: jax.Array,
+                   id2index, *, use_pallas: bool,
+                   part: Optional[str] = None) -> jax.Array:
   # `use_pallas` is part of the jit cache key so the GLT_PALLAS
   # kill-switch keeps working mid-process (resolved per call outside).
   # ``part``: which table this is where a dataset has several (the
-  # node type), in the ops' scope and nowhere else.
+  # node type), in the ops' scope and nowhere else.  A bare ``[N, D]``
+  # table is gathered as it is: the benchmark's real-size compile of
+  # the fused epoch (`tests/chipbench/test_real_size_compile.py`) hands
+  # the epoch one.
+  if not isinstance(hot, HotTier):
+    hot = HotTier(hot, hot.shape[1])
   with layer_scope('gather', part):
     valid = ids >= 0
     idx = jnp.where(valid, ids, 0).astype(jnp.int32)
@@ -50,29 +110,36 @@ def _device_gather(hot: jax.Array, ids: jax.Array, id2index, *,
       valid = valid & (idx >= 0)
       idx = jnp.where(valid, idx, 0)
     if use_pallas:
-      out = gather_rows(hot, idx)
+      # whole stored rows, which a padded 32-bit tier lets the DMA
+      # kernel take
+      out = _first_columns(gather_rows(hot.rows, idx), hot.width)
     else:
-      out = jnp.take(hot, idx, axis=0)
+      # the table's columns before the gather, not the gathered rows'
+      # after it: the former is a bitcast, the latter a pass of its own
+      # that keeps the select below out of the rows' consumer
+      out = jnp.take(_first_columns(hot.rows, hot.width), idx, axis=0)
     return jnp.where(valid[:, None], out, 0)
 
 
 class _DeviceFeatsShim:
   """Stand-in for ``_host_feats`` when the table was constructed from
-  a device array: shape/dtype metadata come from the device array;
+  a device array: shape/dtype metadata come from the device tier;
   element access (rare — `host_get` and test assertions) pulls the
   table to host ONCE and caches it."""
 
-  def __init__(self, arr: jax.Array):
-    self._arr = arr
+  def __init__(self, tier: HotTier):
+    self._tier = tier
     self._np = None
 
-  shape = property(lambda self: self._arr.shape)
-  dtype = property(lambda self: self._arr.dtype)
-  ndim = property(lambda self: self._arr.ndim)
+  shape = property(lambda self: (self._tier.rows.shape[0],
+                                 self._tier.width))
+  dtype = property(lambda self: self._tier.rows.dtype)
+  ndim = property(lambda self: 2)
 
   def _pull(self) -> np.ndarray:
     if self._np is None:
-      self._np = np.asarray(self._arr)
+      self._np = _first_columns(np.asarray(self._tier.rows),
+                                self._tier.width)
     return self._np
 
   def __getitem__(self, key):
@@ -93,7 +160,10 @@ class Feature:
       (produced by hotness reordering); identity when ``None``.
     split_ratio: fraction of rows resident in device HBM.  ``1.0`` pins
       everything on device (DMA mode analog), ``0.0`` keeps everything
-      on host (CPU mode analog).
+      on host (CPU mode analog).  The HBM tier costs ``hot_rows x
+      lane_width(D, dtype) x itemsize`` bytes (module docstring, layout
+      rule; `hot_bytes`): at 100 float32 columns 28 % more than the
+      values, since each row is stored 128 wide.
     device: optional explicit device for the hot tier.
     dtype: optional storage dtype for the hot tier (e.g. ``bfloat16`` —
       halves HBM footprint and feeds the MXU natively).
@@ -120,7 +190,6 @@ class Feature:
                          'host by definition)')
       feats = feature_array if feature_array.ndim > 1 \
           else feature_array[:, None]
-      self._host_feats = _DeviceFeatsShim(feats)
       self._id2index_host = (np.asarray(id2index, dtype=np.int64)
                              if id2index is not None
                              and not isinstance(id2index, jax.Array)
@@ -134,7 +203,13 @@ class Feature:
         # must move it — silently keeping the old placement made the
         # `device=` argument a no-op on the device-native path
         hot = jax.device_put(hot, device)
-      self._hot = hot
+      self._hot = _store_hot(hot)
+      # host reads come from the stored tier where it holds the
+      # caller's values, so a caller that hands over its only reference
+      # keeps one table on the device, not two
+      self._host_feats = _DeviceFeatsShim(
+          self._hot if hot.dtype == feats.dtype
+          else HotTier(feats, feats.shape[1]))
       self._id2index_dev = (None if id2index is None
                             else jnp.asarray(id2index, jnp.int32))
       self.hot_rows = feats.shape[0]
@@ -152,7 +227,7 @@ class Feature:
     self.split_ratio = float(split_ratio)
     self._device = device
     self._dtype = dtype
-    self._hot = None            # jax.Array [hot_rows, D] (lazy)
+    self._hot = None            # HotTier of rows [0, hot_rows) (lazy)
     self._id2index_dev = None   # jax.Array (lazy)
     n = feats.shape[0]
     self.hot_rows = int(round(n * self.split_ratio))
@@ -179,7 +254,7 @@ class Feature:
     hot = self._host_feats[:self.hot_rows]
     if self._dtype is not None:
       hot = hot.astype(self._dtype)
-    self._hot = jax.device_put(hot, dev)
+    self._hot = _store_hot(jax.device_put(hot, dev))
     if self._id2index_host is not None:
       self._id2index_dev = jax.device_put(self._id2index_host, dev)
     if self._cache_rows and self._cold_cache is None:
@@ -203,11 +278,26 @@ class Feature:
     return self._host_feats.shape[dim]
 
   @property
-  def hot_tier(self) -> Optional[jax.Array]:
+  def hot_tier(self) -> Optional[HotTier]:
     """The device-resident block (rows ``[0, hot_rows)``), for callers
-    that gather inside jit when the whole table is HBM-resident."""
+    that gather inside jit when the whole table is HBM-resident.
+
+    It is the `HotTier` as stored — rows ``[hot_rows, lane_width(D)]``
+    with the table's width ``D`` beside them — and not a bare array, so
+    no caller is handed padded columns as if they were the table: pass
+    it to `_device_gather`, which returns ``[B, D]`` rows."""
     self.lazy_init()
     return self._hot
+
+  @property
+  def hot_bytes(self) -> int:
+    """Device bytes the hot tier takes as stored, ``hot_rows x
+    lane_width(D, dtype) x itemsize`` — before `lazy_init` places it
+    too."""
+    if self._hot is not None:
+      return int(self._hot.rows.nbytes)
+    return (self.hot_rows * lane_width(self.feature_dim, self.dtype)
+            * np.dtype(self.dtype).itemsize)
 
   # -- lookup -------------------------------------------------------------
   def __getitem__(self, ids) -> jax.Array:
@@ -260,9 +350,9 @@ class Feature:
 
     if self.hot_rows >= self._host_feats.shape[0]:
       # Fully HBM-resident: one device gather — per-row DMA kernel on
-      # TPU (`ops/pallas_gather.py`), fused XLA gather elsewhere.
-      out = gather_rows(self._hot, jnp.asarray(idx.astype(np.int32)))
-      return jnp.where(jnp.asarray(valid)[:, None], out, 0)
+      # TPU under GLT_PALLAS=1 (`ops/pallas_gather.py`), fused XLA
+      # gather otherwise.
+      return self._hot_rows(np.where(valid, idx, -1))
 
     cold_sel = valid & (idx >= self.hot_rows)
     self.cold_stats['lookups'] += int(valid.sum())
@@ -274,8 +364,7 @@ class Feature:
       return jnp.asarray(out if self._dtype is None
                          else out.astype(self._dtype))
     if not cold_sel.any():
-      out = gather_rows(self._hot, jnp.asarray(idx.astype(np.int32)))
-      return jnp.where(jnp.asarray(valid)[:, None], out, 0)
+      return self._hot_rows(np.where(valid, idx, -1))
 
     # chaos seam: the host cold tier is a service that can die
     # mid-epoch; a planned 'fail' raises here, on the batch that
@@ -292,8 +381,7 @@ class Feature:
     # Ships only the miss bytes — a full-[B, D] staging buffer or a
     # dynamic scatter is 10-200x slower (the former in transfer, the
     # latter recompiling on every batch's cold count).
-    hot_idx = np.where(cold_sel, 0, idx)
-    out = gather_rows(self._hot, jnp.asarray(hot_idx.astype(np.int32)))
+    out = self._hot_rows(np.where(valid & ~cold_sel, idx, -1))
     cache = self._cold_cache
     if cache is not None:
       hit, slot = cache.lookup(idx, cold_sel)
@@ -338,6 +426,11 @@ class Feature:
     """All-device gather (fully-hot tables, device ids): no host sync."""
     return _device_gather(self._hot, ids, self._id2index_dev,
                           use_pallas=pallas_enabled(), part=part)
+
+  def _hot_rows(self, rows: np.ndarray) -> jax.Array:
+    """``[B, D]`` hot-tier rows by host storage row (-1 = a zero row)."""
+    return _device_gather(self._hot, jnp.asarray(rows.astype(np.int32)),
+                          None, use_pallas=pallas_enabled())
 
   def _pinned_buffer(self):
     """The lazily built `data.cold_cache.PinnedColdBuffer` over the

@@ -33,6 +33,8 @@ torch loader cannot fuse Python-loop epochs into one graph.
 """
 from __future__ import annotations
 
+import inspect
+import weakref
 from typing import Callable, Optional, Sequence, Tuple
 
 import jax
@@ -86,15 +88,33 @@ def _counted_jit(fn, fast_compile: bool = False, **jit_kwargs):
   executable hit ticks ``fused.compile.hits``; a dispatch that
   compiled ticks ``fused.compile.misses`` + ``fused.compile.secs`` and
   emits a ``fused.compile`` flight-recorder event whose ``secs`` is
-  the wall of that dispatch (compile + first execution)."""
+  the wall of that dispatch (compile + first execution).
+
+  A bound method is held weakly: a driver keeps its programs, so a
+  program that kept the driver would make a cycle, and the tables the
+  driver holds (a feature tier of gigabytes) would outlive the last
+  reference to the driver until a collection."""
   import time as _time
   from ..telemetry.recorder import recorder
   if fast_compile:
     jit_kwargs = dict(jit_kwargs,
                       compiler_options=_FAST_COMPILE_OPTIONS)
-  compiled = jax.jit(fn, **jit_kwargs)
   name = getattr(fn, '__qualname__', None) or getattr(
       fn, '__name__', 'jit_fn')
+  if inspect.ismethod(fn):
+    method = weakref.WeakMethod(fn)
+
+    def target(*args, **kwargs):
+      return method()(*args, **kwargs)
+
+    # the method's name (the program's) and signature (its static and
+    # donated arguments), without `functools.wraps`' strong
+    # ``__wrapped__``
+    target.__name__, target.__qualname__ = fn.__name__, name
+    target.__module__ = fn.__module__
+    target.__signature__ = inspect.signature(fn)
+    fn = target
+  compiled = jax.jit(fn, **jit_kwargs)
 
   def call(*args, **kwargs):
     before = compiled._cache_size()

@@ -12,8 +12,12 @@ body runs, and rows stream straight into the VMEM output block.
 STATUS.  Off by default (``GLT_PALLAS=1`` opts in).  On one v5e (PR 21
 bring-up, nothing timed) the kernel compiles non-interpreted and is
 value-identical to ``jnp.take`` on a lane-aligned ``[400k, 128]`` f32
-table at 1,024 / 15,360 / 131,072 ids; on the flagship's 100-wide
-table the alignment rule below sends every call to the XLA gather.
+table at 1,024 / 15,360 / 131,072 ids.  The feature store keeps its
+device tier at a lane-multiple row width (`data.feature`'s layout
+rule, `utils.padding.lane_width`), so a padded 32-bit tier — the
+flagship's 100 columns are stored as 128 — is DMA-eligible under the
+alignment rule below, and `data.feature._device_gather` takes the
+kernel's rows back to the table's width.
 The last speed comparison on record (round 5, one v5e, deleted with
 the round's logs in PR 21) had XLA's row gather ahead of this per-row
 DMA; nothing has been measured on today's code, and ROADMAP S2/D2

@@ -150,21 +150,11 @@ class ServingEngine:
     self._tiered = feat.hot_rows < feat.size(0)
     self._feat = feat
     # memory accounting (ISSUE 17): the hot tier is the engine's HBM
-    # bill — resident bytes when materialised, the would-be bill
-    # (rows x dim x itemsize) before lazy_init
+    # bill — resident bytes when materialised, the would-be bill before
+    # lazy_init (`Feature.hot_bytes`)
     from ..telemetry.memaccount import register_tier
-
-    def _hot_bytes(f=feat):
-      h = getattr(f, '_hot', None)
-      if h is not None:
-        return int(getattr(h, 'nbytes', 0))
-      try:
-        return (int(f.hot_rows) * int(f.feature_dim)
-                * int(np.dtype(f.dtype).itemsize))
-      except Exception:
-        return 0
-
-    self._unregister_hot_tier = register_tier('hot', _hot_bytes)
+    self._unregister_hot_tier = register_tier('hot',
+                                              lambda: feat.hot_bytes)
     #: streaming ingestion (ISSUE 14): with a `StreamingGraph`
     #: attached (explicitly or via `Dataset.attach_stream`), every
     #: dispatch re-pins the newest published `GraphView` FIRST and

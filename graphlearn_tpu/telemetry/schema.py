@@ -82,6 +82,12 @@ EVENT_KINDS: Dict[str, str] = {
         'carry their payloads; a record without the field is of the '
         'form that gathered 5 x sorted); absent = tables held at '
         'their final size from the first hop on (the mesh samplers)',
+    'feature.layout':
+        'data.feature._store_hot at build time, once per Feature that '
+        'places a tier on the device: width (the table\'s D), '
+        'stored_width (utils.padding.lane_width), dtype, rows, '
+        'stored_bytes, padded (stored_width != width: the tier is a '
+        'zero-padded copy, else the caller\'s own buffer)',
     'sample.negative':
         'sampler.neighbor_sampler.link_seeds at trace time, once per '
         'compiled program that draws the seeds of a link batch '

@@ -545,7 +545,7 @@ def test_feature_device_native_honors_device():
     pytest.skip('needs >= 2 devices')
   arr = jax.device_put(jnp.ones((4, 2)), devs[0])
   f = Feature(arr, device=devs[1])
-  assert devs[1] in f.hot_tier.devices()
+  assert devs[1] in f.hot_tier.rows.devices()
   # same-device placement is a no-op (no copy)
   f0 = Feature(arr, device=devs[0])
-  assert f0.hot_tier is arr
+  assert f0.hot_tier.rows is arr
