@@ -54,7 +54,7 @@ from ..sampler.neighbor_sampler import (NeighborSampler, _multihop_sample,
                                         hop_capacities, hop_windows,
                                         link_metadata, link_plan,
                                         link_seeds)
-from ..utils.profiling import metrics, step_annotation
+from ..utils.profiling import layer_scope, metrics, step_annotation
 from .link_loader import EdgeSeedBatcher
 from .node_loader import SeedBatcher
 from .transform import Batch, _gather_labels
@@ -1055,12 +1055,20 @@ class FusedLinkEpoch(_SnapshotHooks):
   @staticmethod
   def _fill_of(batch: Batch) -> jax.Array:
     """``[rows valid, rows, edge slots valid, edge slots]`` of one
-    batch."""
-    return jnp.stack([
-        jnp.sum(batch.node_mask, dtype=jnp.int32),
-        jnp.int32(batch.node_mask.shape[0]),
-        jnp.sum(batch.edge_mask, dtype=jnp.int32),
-        jnp.int32(batch.edge_mask.shape[0])])
+    batch, under ``glt.telemetry/fill``."""
+    with layer_scope('telemetry', 'fill'):
+      return jnp.stack([
+          jnp.sum(batch.node_mask, dtype=jnp.int32),
+          jnp.int32(batch.node_mask.shape[0]),
+          jnp.sum(batch.edge_mask, dtype=jnp.int32),
+          jnp.int32(batch.edge_mask.shape[0])])
+
+  @staticmethod
+  def _fill_total(fill: jax.Array) -> jax.Array:
+    """The dispatch's steps' fills summed, under
+    ``glt.telemetry/fill``."""
+    with layer_scope('telemetry', 'fill'):
+      return jnp.sum(fill, axis=0)
 
   def _link_collect_fn(self, srcs: jax.Array, dsts: jax.Array,
                        labs: jax.Array, key: jax.Array, dev: dict,
@@ -1092,7 +1100,7 @@ class FusedLinkEpoch(_SnapshotHooks):
 
     state, (losses, valids, fill) = jax.lax.scan(body, state,
                                                  (batches, srcs, dsts))
-    return state, losses, jnp.sum(valids), jnp.sum(fill, axis=0)
+    return state, losses, jnp.sum(valids), self._fill_total(fill)
 
   def _auc_consume_fn(self, params, batches):
     def body(carry, batch):
@@ -1244,7 +1252,7 @@ class FusedLinkEpoch(_SnapshotHooks):
             else jnp.ones_like(srcs))             # constant positive label
     state, (losses, valids, fill) = jax.lax.scan(
         body, state, (steps, srcs, dsts, labs))
-    return state, losses, jnp.sum(valids), jnp.sum(fill, axis=0)
+    return state, losses, jnp.sum(valids), self._fill_total(fill)
 
   def run(self, state: TrainState) -> Tuple[TrainState, 'EpochStats']:
     """One epoch; ``state`` is DONATED (thread the returned one).

@@ -41,6 +41,7 @@ from ..sampler.hetero_neighbor_sampler import (_plan_capacities,
                                                normalize_fanouts)
 from ..typing import EdgeType, NodeType, reverse_edge_type
 from ..utils.padding import INVALID_ID
+from ..utils.profiling import layer_scope
 from .dist_data import build_dist_edge_feature, build_dist_feature
 from .dist_sampler import (ExchangeTelemetry, NEG_TRIALS, _dist_one_hop,
                            _slack_cap, dist_gather_multi,
@@ -601,7 +602,8 @@ class DistHeteroNeighborSampler(ExchangeTelemetry):
               exchange_capacity=_slack_cap(fr_nodes.shape[0], num_parts,
                                            exchange_slack,
                                            exchange_layout))
-          fr_stats = fr_stats + jnp.stack(hstats)
+          with layer_scope('telemetry', 'exchange'):
+            fr_stats = fr_stats + jnp.stack(hstats)
           states[d], rows, cols, _ = induce_next(
               states[d], fr_local, nbrs, mask)
           rows_acc[et].append(rows)
@@ -622,7 +624,8 @@ class DistHeteroNeighborSampler(ExchangeTelemetry):
                                          exchange_slack,
                                          exchange_layout),
             hot_counts=hcounts[nt] if tiered_nts[nt] else None)
-        ft_stats = ft_stats + jnp.stack(gstats)
+        with layer_scope('telemetry', 'exchange'):
+          ft_stats = ft_stats + jnp.stack(gstats)
       y = {}
       for nt in label_nts:
         (y[nt],), gstats = dist_gather_multi(
@@ -631,7 +634,8 @@ class DistHeteroNeighborSampler(ExchangeTelemetry):
             exchange_capacity=_slack_cap(table_cap[nt], num_parts,
                                          exchange_slack,
                                          exchange_layout))
-        ft_stats = ft_stats + jnp.stack(gstats)
+        with layer_scope('telemetry', 'exchange'):
+          ft_stats = ft_stats + jnp.stack(gstats)
 
       ef = {}
       for et in efeat_ets:
@@ -644,11 +648,14 @@ class DistHeteroNeighborSampler(ExchangeTelemetry):
                                          exchange_slack,
                                          exchange_layout),
             shard_mode=ef_modes[et])
-        ft_stats = ft_stats + jnp.stack(gstats)
+        with layer_scope('telemetry', 'exchange'):
+          ft_stats = ft_stats + jnp.stack(gstats)
 
-      neg_lost = (jnp.sum((~neg_ok).astype(jnp.int32))
-                  if neg_ok is not None else jnp.int32(0))
-      stats = jnp.concatenate([fr_stats, ft_stats, neg_lost[None]])
+      with layer_scope('telemetry', 'negative'):
+        neg_lost = (jnp.sum((~neg_ok).astype(jnp.int32))
+                    if neg_ok is not None else jnp.int32(0))
+      with layer_scope('telemetry', 'exchange'):
+        stats = jnp.concatenate([fr_stats, ft_stats, neg_lost[None]])
       if neg_ok is None:
         neg_ok = jnp.ones((1,), bool)
 

@@ -43,7 +43,8 @@ from ..utils.padding import INVALID_ID, max_sampled_nodes, round_up
 from ..utils.profiling import layer_scope
 from .dist_data import DistDataset
 from .exchange import (MIN_EXCHANGE_CAP, capacity_spec, dest_histogram,
-                       plan_exchange, resolve_layout, scoped_plan)
+                       offered_dropped, plan_exchange, resolve_layout,
+                       scoped_plan)
 from .partition_book import (book_owner_fn, edge_book_owner_fn,
                              edge_local_rows, edge_owner_fn,
                              hot_split_host, range_owner_fn)
@@ -65,16 +66,6 @@ EXCHANGE_STAT_NAMES = (
     'frontier.offered', 'frontier.dropped', 'frontier.slots',
     'feature.offered', 'feature.dropped', 'feature.slots',
     'negative.lost')
-
-
-def _exchange_stats(ids, slot_j, num_parts: int, cap: int):
-  """(offered, dropped, slots) triple for one bucketed exchange —
-  kept for direct `bucket_by_owner` users (the plan layouts in
-  `parallel.exchange` carry their own triple)."""
-  valid = ids >= 0
-  offered = jnp.sum(valid.astype(jnp.int32))
-  dropped = jnp.sum((valid & (slot_j < 0)).astype(jnp.int32))
-  return offered, dropped, jnp.int32(num_parts * cap)
 
 
 def bucket_by_owner(ids: jax.Array, owner: jax.Array, num_parts: int,
@@ -202,14 +193,15 @@ class _BookPlan:
         1, 0, 2).reshape(s, p * cap)
     self.kept = self.slot_j >= 0
     self.delivered = self.kept
-    valid = ids >= 0
-    offered = jnp.sum(valid.astype(jnp.int32))
-    dropped = jnp.sum((valid & ~self.kept).astype(jnp.int32))
-    self.stats = (offered, dropped, jnp.int32(p * s * cap))
+    self._ids = ids
     #: requester index per lane-recv row (the per-requester GNS mask
     #: needs the source device of every received frontier id)
     self.req_of_lane_recv = jnp.repeat(
         jnp.arange(p, dtype=jnp.int32), cap)
+
+  def count(self):
+    return offered_dropped(self._ids, self.kept) + (
+        jnp.int32(self._p * self._s * self._cap),)
 
   def reply(self, values_lanes, fill=0):
     """``[S, P*cap, ...]`` per-lane owner-side values -> ``[F, ...]``
@@ -831,7 +823,8 @@ def _expand_and_collect(indptr, indices, eids, bounds, seeds, key, *,
   attr_ft = jnp.zeros((num_parts,), jnp.int32)
   for h, k in enumerate(fanouts):
     hop_key = jax.random.fold_in(key, h)
-    attr_fr = attr_fr + dest_histogram(frontier, attr_owner, num_parts)
+    with layer_scope('telemetry', 'attribution'):
+      attr_fr = attr_fr + dest_histogram(frontier, attr_owner, num_parts)
     nbrs, mask, e, hw, hstats = _dist_one_hop(
         indptr, indices, eids, bounds, frontier, int(k), hop_key,
         axis, num_parts, with_edge,
@@ -839,7 +832,8 @@ def _expand_and_collect(indptr, indices, eids, bounds, seeds, key, *,
                                      exchange_slack, exchange_layout,
                                      caps=fr_caps),
         gns_bits=gns_bits, gns_boost=gns_boost, book_spec=book_spec)
-    fr_stats = fr_stats + jnp.stack(hstats)
+    with layer_scope('telemetry', 'exchange'):
+      fr_stats = fr_stats + jnp.stack(hstats)
     state, rows, cols, prev_cnt = induce_next(
         state, frontier_local, nbrs, mask)
     rows_acc.append(rows)
@@ -871,10 +865,12 @@ def _expand_and_collect(indptr, indices, eids, bounds, seeds, key, *,
                                      exchange_slack, exchange_layout,
                                      caps=ft_caps),
         shard_mode=ef_shard_mode, book_spec=book_spec)
-    ft_stats = ft_stats + jnp.stack(estats)
     ef_owner = (edge_owner_fn(num_parts) if ef_shard_mode == 'mod'
                 else range_owner_fn(ebounds))
-    attr_ft = attr_ft + dest_histogram(edge, ef_owner, num_parts)
+    with layer_scope('telemetry', 'exchange'):
+      ft_stats = ft_stats + jnp.stack(estats)
+    with layer_scope('telemetry', 'attribution'):
+      attr_ft = attr_ft + dest_histogram(edge, ef_owner, num_parts)
   tables = (((fshard,) if collect_features else ())
             + ((lshard,) if collect_labels else ()))
   replica_hits = jnp.zeros((1,), jnp.int32)
@@ -910,7 +906,8 @@ def _expand_and_collect(indptr, indices, eids, bounds, seeds, key, *,
         local_hit = local_hit | ((state.nodes >= lo)
                                  & (state.nodes < hi) & node_valid)
       gather_ids = jnp.where(local_hit, INVALID_ID, state.nodes)
-      replica_hits = jnp.sum(local_hit.astype(jnp.int32))[None]
+      with layer_scope('telemetry', 'cold'):
+        replica_hits = jnp.sum(local_hit.astype(jnp.int32))[None]
     got, gstats = dist_gather_multi(
         tables, bounds, gather_ids, axis, num_parts,
         exchange_capacity=_slack_cap(node_cap, num_parts,
@@ -919,10 +916,12 @@ def _expand_and_collect(indptr, indices, eids, bounds, seeds, key, *,
         hot_counts=hot_counts if collect_features else None,
         book_spec=book_spec)
     got = list(got)
-    ft_stats = ft_stats + jnp.stack(gstats)
-    attr_ft = attr_ft + dest_histogram(
-        gather_ids, attr_owner, num_parts,
-        valid=node_valid & (gather_ids >= 0))
+    with layer_scope('telemetry', 'exchange'):
+      ft_stats = ft_stats + jnp.stack(gstats)
+    with layer_scope('telemetry', 'attribution'):
+      attr_ft = attr_ft + dest_histogram(
+          gather_ids, attr_owner, num_parts,
+          valid=node_valid & (gather_ids >= 0))
     if collect_features:
       x = got.pop(0)
       if with_cache:
@@ -949,8 +948,10 @@ def _expand_and_collect(indptr, indices, eids, bounds, seeds, key, *,
   # the [2P + 1] attribution tail (frontier dests, feature dests,
   # replica-hit count) — see `ExchangeTelemetry._accumulate_stats`
   # for the host-side split
-  stats = jnp.concatenate([fr_stats, ft_stats, jnp.zeros((1,), jnp.int32),
-                           attr_fr, attr_ft, replica_hits])
+  with layer_scope('telemetry', 'exchange'):
+    stats = jnp.concatenate([fr_stats, ft_stats,
+                             jnp.zeros((1,), jnp.int32), attr_fr, attr_ft,
+                             replica_hits])
   return state, row, col, edge, seed_local, x, y, ef, nsn, stats, ew
 
 
@@ -1150,8 +1151,9 @@ def _make_dist_link_step(mesh: Mesh, num_parts: int,
             jnp.zeros((b,), jnp.int32), jnp.zeros((b, 1), jnp.int32))
 
     if neg_ok is not None:
-      stats = stats.at[6].add(
-          jnp.sum((~neg_ok).astype(jnp.int32)))
+      with layer_scope('telemetry', 'negative'):
+        stats = stats.at[6].add(
+            jnp.sum((~neg_ok).astype(jnp.int32)))
 
     def lead(v):
       return None if v is None else v[None]
@@ -1256,11 +1258,14 @@ def _make_dist_subgraph_step(mesh: Mesh, num_parts: int,
                                        exchange_slack,
                                        exchange_layout),
           book_spec=book_spec)
-      stats = stats.at[:3].add(jnp.stack(hstats))
+      with layer_scope('telemetry', 'exchange'):
+        stats = stats.at[:3].add(jnp.stack(hstats))
       # full-window hops are frontier traffic too: extend this
       # device's src->dst attribution row (stats[7:7+P])
-      stats = stats.at[7:7 + num_parts].add(
-          dest_histogram(frontier_c, range_owner_fn(bounds), num_parts))
+      with layer_scope('telemetry', 'attribution'):
+        stats = stats.at[7:7 + num_parts].add(
+            dest_histogram(frontier_c, range_owner_fn(bounds),
+                           num_parts))
       nbrs_parts.append(nb)
       mask_parts.append(mk)
       if with_edge:
@@ -2817,7 +2822,8 @@ def _make_dist_walk_step(mesh: Mesh, num_parts: int, walk_length: int,
     attr_owner = range_owner_fn(bounds)
     attr_fr = jnp.zeros((num_parts,), jnp.int32)
     for h in range(walk_length):
-      attr_fr = attr_fr + dest_histogram(cur, attr_owner, num_parts)
+      with layer_scope('telemetry', 'attribution'):
+        attr_fr = attr_fr + dest_histogram(cur, attr_owner, num_parts)
       nbrs, mask, _, _w, hstats = _dist_one_hop(
           indptr_s[0], indices_s[0], None, bounds, cur, 1,
           jax.random.fold_in(key, h), axis, num_parts, False,
@@ -2825,14 +2831,16 @@ def _make_dist_walk_step(mesh: Mesh, num_parts: int, walk_length: int,
                                        exchange_slack,
                                        exchange_layout),
           book_spec=book_spec)
-      stats = stats + jnp.stack(hstats)
+      with layer_scope('telemetry', 'exchange'):
+        stats = stats + jnp.stack(hstats)
       cur = jnp.where(mask[:, 0], nbrs[:, 0], INVALID_ID).astype(
           jnp.int32)
       path.append(cur)
     walks = jnp.stack(path, axis=1)             # [B, L+1]
-    full = jnp.concatenate(
-        [stats, jnp.zeros((4,), jnp.int32), attr_fr,
-         jnp.zeros((num_parts + 1,), jnp.int32)])
+    with layer_scope('telemetry', 'exchange'):
+      full = jnp.concatenate(
+          [stats, jnp.zeros((4,), jnp.int32), attr_fr,
+           jnp.zeros((num_parts + 1,), jnp.int32)])
     return walks[None], full[None]
 
   specs_in = (P(axis), P(axis), P(), P(axis), P())

@@ -285,6 +285,12 @@ def capacity_spec(n: int, num_parts: int, slack: Optional[float],
   return dense
 
 
+#: elements of the ``[P, chunk]`` compare one `dest_histogram` pass
+#: covers; longer id vectors are counted chunk by chunk (16 M elements
+#: = 64 MB as int32, were the compare ever materialised)
+HISTOGRAM_CHUNK_ELEMS = 1 << 24
+
+
 def dest_histogram(ids: jax.Array, owner_fn: Callable,
                    num_parts: int, valid=None) -> jax.Array:
   """[P] int32 count of valid ids per destination partition — the
@@ -294,17 +300,30 @@ def dest_histogram(ids: jax.Array, owner_fn: Callable,
   Keyed by ``owner_fn`` — callers pass the `PartitionBook` RANGE owner
   (`partition_book.range_owner_fn`), so a row means "ids in range r"
   even after an adopted book remaps which physical device serves r.
-  Traceable (runs inside the compiled step); invalid ids route to a
-  dropped overflow bin, never a partition.
+  Traceable (runs inside the compiled step); invalid ids and owners
+  past ``P`` go to a dropped bin, a negative owner counts to bin 0.
+
+  Counted as a compare of each id's owner against ``arange(P)`` and a
+  dense sum over the ids (chunk by chunk past
+  `HISTOGRAM_CHUNK_ELEMS`): a reduction that reads the owners once.
+  A ``segment_sum`` into P + 1 bins lowers to a scatter whose updates
+  all land on a handful of addresses, which the TPU applies one after
+  another: 8.2 ms of a 196 ms step on a four-chip TPU v5e host for the
+  feature gather's 937,984 ids at P = 4.  Callers trace it under
+  ``layer_scope('telemetry', 'attribution')``.
   """
   if valid is None:
     valid = ids >= 0
   owner = jnp.where(valid, owner_fn(ids).astype(jnp.int32),
                     jnp.int32(num_parts))
-  owner = jnp.clip(owner, 0, num_parts)
-  return jax.ops.segment_sum(
-      jnp.ones(ids.shape, jnp.int32), owner,
-      num_segments=num_parts + 1)[:num_parts]
+  owner = jnp.clip(owner, 0, num_parts).reshape(-1)
+  bins = jnp.arange(num_parts, dtype=jnp.int32)[:, None]
+  chunk = max(HISTOGRAM_CHUNK_ELEMS // max(num_parts, 1), 1)
+  count = jnp.zeros((num_parts,), jnp.int32)
+  for lo in range(0, owner.shape[0], chunk):
+    count = count + jnp.sum(owner[None, lo:lo + chunk] == bins, axis=1,
+                            dtype=jnp.int32)
+  return count
 
 
 _ENV_EWMA = 'GLT_EXCHANGE_EWMA'
@@ -404,6 +423,16 @@ class EwmaCapacityModel:
           _quantize_pow2(self._traffic[c] * self.headroom))
 
 
+def offered_dropped(ids: jax.Array, kept: jax.Array):
+  """``(offered, dropped)`` of one exchange: the valid ids offered, and
+  those of them that found no slot — the first two of a plan's
+  ``stats`` triple, counted under ``glt.telemetry/exchange``
+  (`scoped_plan`)."""
+  valid = ids >= 0
+  return (jnp.sum(valid.astype(jnp.int32)),
+          jnp.sum((valid & ~kept).astype(jnp.int32)))
+
+
 def _bcast(mask: jax.Array, values: jax.Array) -> jax.Array:
   """Broadcast a [F] mask over the trailing dims of [F, ...]."""
   return mask.reshape(mask.shape + (1,) * (values.ndim - 1))
@@ -447,9 +476,10 @@ class _SubExchange:
     self.cap = send.shape[1]
     self.recv = recv.reshape(-1)                  # [nbuckets * cap]
     self.kept = self.slot_j >= 0
-    valid = ids >= 0
-    self.offered = jnp.sum(valid.astype(jnp.int32))
-    self.dropped = jnp.sum((valid & ~self.kept).astype(jnp.int32))
+    self._ids = ids
+
+  def count(self):
+    return offered_dropped(self._ids, self.kept)
 
   def reply(self, values, fill):
     """[nbuckets * cap, ...] owner-side values -> [F, ...] in request
@@ -483,8 +513,10 @@ class _DensePlan:
     #: serve locally, not by the union of every device's cache
     self.requester_of_recv = jnp.repeat(
         jnp.arange(num_parts, dtype=jnp.int32), self._sub.cap)
-    self.stats = (self._sub.offered, self._sub.dropped,
-                  jnp.int32(num_parts * self._sub.cap))
+    self._slots = num_parts * self._sub.cap
+
+  def count(self):
+    return self._sub.count() + (jnp.int32(self._slots),)
 
   def reply(self, values, fill=0):
     return self._sub.reply(values, fill)
@@ -581,9 +613,11 @@ class _CompactPlan:
     self._pool_slot = inv(jnp.where(in_pool, pool_rank, -1), -1)
     self.kept = (self._slot_j >= 0) | (self._pool_slot >= 0)
     self.delivered = self.kept
-    offered = jnp.sum(valid.astype(jnp.int32))
-    dropped = jnp.sum((valid & ~self.kept).astype(jnp.int32))
-    self.stats = (offered, dropped, jnp.int32(p * cap + v))
+    self._ids = ids
+
+  def count(self):
+    return offered_dropped(self._ids, self.kept) + (
+        jnp.int32(self._p * self._cap + self._pool),)
 
   def reply(self, values, fill=0):
     p, cap, v = self._p, self._cap, self._pool
@@ -645,10 +679,11 @@ class _HierPlan:
     # c1] exchange) so the requester can mask undelivered results
     bits = st1.reply(st2.kept.astype(jnp.int8), fill=0)
     self.delivered = st1.kept & (bits > 0)
-    offered = st1.offered + st2.offered
-    dropped = st1.dropped + st2.dropped
-    self.stats = (offered, dropped,
-                  jnp.int32(cols * c1 + rows * c2))
+    self._slots = cols * c1 + rows * c2
+
+  def count(self):
+    (o1, d1), (o2, d2) = self._st1.count(), self._st2.count()
+    return o1 + o2, d1 + d2, jnp.int32(self._slots)
 
   def reply(self, values, fill=0):
     mid = self._st2.reply(values, fill)              # [cols * c1, ...]
@@ -712,8 +747,11 @@ class _RaggedPlan:  # pragma: no cover — unreachable, see below
         jnp.clip(owner_s, 0, p - 1)]
     self.kept = valid
     self.delivered = valid
-    self.stats = (jnp.sum(valid.astype(jnp.int32)), jnp.int32(0),
-                  jnp.int32(budget))
+    self._budget = budget
+
+  def count(self):
+    return (jnp.sum(self.kept.astype(jnp.int32)), jnp.int32(0),
+            jnp.int32(self._budget))
 
   def reply(self, values, fill=0):
     input_offsets, send_sizes, output_offsets, recv_sizes = self._io
@@ -733,12 +771,17 @@ class scoped_plan:
   """A plan under ``glt.exchange/<what>``: ``build()`` (pack + the
   request collective) and every ``reply`` (the reply collective +
   unpack) trace inside the scope, so a profiler trace books them to
-  the exchange and what the owners do in between to its own layer."""
+  the exchange and what the owners do in between to its own layer.
+  The plan's ``stats`` triple (offered, dropped, slots), which only
+  reporting reads, is counted after the build, under
+  ``glt.telemetry/exchange``."""
 
   def __init__(self, what: Optional[str], build: Callable):
     self._what = what
     with layer_scope('exchange', what):
       self._plan = build()
+    with layer_scope('telemetry', 'exchange'):
+      self.stats = self._plan.count()
 
   def reply(self, values, fill=0):
     with layer_scope('exchange', self._what):

@@ -63,6 +63,14 @@ from .dp import (make_dp_eval_step, make_dp_supervised_step,
                  make_dp_unsupervised_step)
 
 
+def _scan_total(counts: jax.Array, part: str) -> jax.Array:
+  """``counts`` summed over its leading axis (a scan's steps, or a
+  step's devices), under ``glt.telemetry/<part>``: only reporting
+  reads these totals."""
+  with layer_scope('telemetry', part):
+    return jnp.sum(counts, axis=0)
+
+
 class _MeshEpochDriver(_SnapshotHooks):
   """Host-driver pieces shared by the three fused mesh classes, so
   the seed/key/device-put contracts cannot drift between them.
@@ -583,14 +591,14 @@ class FusedDistEpoch(_MeshEpochDriver):
       state, loss, correct = self._dp_step(state, batch)
       # [P, H+1] new-node counts -> [H+1]: per-hop padding fill rides
       # the scan for free instead of a per-batch host sync
-      hop = jnp.sum(batch.num_sampled_nodes, axis=0)
+      hop = _scan_total(batch.num_sampled_nodes, 'hops')
       return state, (loss, correct, jnp.sum(seeds >= 0), stats, hop)
 
     steps = jnp.arange(seeds_all.shape[0], dtype=jnp.int32)
     state, (losses, corrects, valids, stats, hops) = jax.lax.scan(
         body, state, (steps, seeds_all))
     return (state, losses, jnp.sum(corrects), jnp.sum(valids),
-            jnp.sum(stats, axis=0), jnp.sum(hops, axis=0))
+            _scan_total(stats, 'exchange'), _scan_total(hops, 'hops'))
 
   def _eval_fn(self, params, seeds_all: jax.Array, key: jax.Array,
                arrs: dict):
@@ -607,7 +615,7 @@ class FusedDistEpoch(_MeshEpochDriver):
     steps = jnp.arange(seeds_all.shape[0], dtype=jnp.int32)
     _, (correct, total, stats) = jax.lax.scan(
         body, 0, (steps, seeds_all))
-    return jnp.sum(correct), jnp.sum(total), jnp.sum(stats, axis=0)
+    return jnp.sum(correct), jnp.sum(total), _scan_total(stats, 'exchange')
 
   # -- tiered fused epochs (chunked collect/train twins) --------------------
 
@@ -641,13 +649,13 @@ class FusedDistEpoch(_MeshEpochDriver):
 
     def body(state, batch):
       state, loss, correct = self._dp_step(state, batch)
-      hop = jnp.sum(batch.num_sampled_nodes, axis=0)
+      hop = _scan_total(batch.num_sampled_nodes, 'hops')
       return state, (loss, correct, jnp.sum(batch.batch >= 0), hop)
 
     state, (losses, corrects, valids, hops) = jax.lax.scan(
         body, state, batches)
     return (state, losses, jnp.sum(corrects), jnp.sum(valids),
-            jnp.sum(hops, axis=0))
+            _scan_total(hops, 'hops'))
 
   def _eval_consume_fn(self, params, batches):
     def body(carry, batch):
@@ -831,7 +839,7 @@ class FusedDistTreeEpoch(_MeshEpochDriver):
     # (`dist_sampler`); a scope around them here would claim them, so
     # only what this function does itself is scoped
     for h, k in enumerate(self.fanouts):
-      with layer_scope('exchange', 'stats'):
+      with layer_scope('telemetry', 'attribution'):
         attr_fr = attr_fr + dest_histogram(frontier, attr_owner,
                                            self.num_parts)
       hop_plans.append((frontier.shape[0], _slack_cap(
@@ -842,8 +850,9 @@ class FusedDistTreeEpoch(_MeshEpochDriver):
           False, sort_locality=False,
           exchange_capacity=hop_plans[-1][1],
           gns_bits=gns_bits, gns_boost=boost, book_spec=book_spec)
-      with layer_scope('sample', f'hop{h}'):
+      with layer_scope('telemetry', 'exchange'):
         fstats = fstats + jnp.stack(st)
+      with layer_scope('sample', f'hop{h}'):
         nxt = jnp.where(mask, nbrs, -1).reshape(-1)
         levels.append(nxt)
         if gns:
@@ -858,11 +867,13 @@ class FusedDistTreeEpoch(_MeshEpochDriver):
         (fshards_s, lshards_s), bounds, all_ids, self.axis,
         self.num_parts, exchange_capacity=feature_plan[1],
         hot_counts=hcounts, book_spec=book_spec)
-    with layer_scope('exchange', 'stats'):
+    with layer_scope('telemetry', 'attribution'):
       attr_ft = dest_histogram(all_ids, attr_owner, self.num_parts)
+    with layer_scope('telemetry', 'exchange'):
       stats7 = jnp.concatenate(
           [fstats, jnp.stack(gst), jnp.zeros((1,), jnp.int32),
            attr_fr, attr_ft, jnp.zeros((1,), jnp.int32)])
+    with layer_scope('telemetry', 'hops'):
       hop_counts = jnp.stack(
           [jnp.sum((lvl >= 0).astype(jnp.int32)) for lvl in levels])
     with layer_scope('gather', 'split'):
@@ -914,8 +925,10 @@ class FusedDistTreeEpoch(_MeshEpochDriver):
     """The DP update half of the tree step — shared by the fused
     single-program path and the tiered consume scan."""
     axis, b = self.axis, self.batch_size
-    with layer_scope('exchange', 'stats'):
+    with layer_scope('telemetry', 'hops'):
       hop_g = jax.lax.psum(hop_counts, axis)       # global [H+1]
+    with layer_scope('exchange', 'grads'):
+      # the global seed count gates the update: exchange work
       n_valid = jax.lax.psum(jnp.sum(valid), axis)
 
     def loss_fn(params):
@@ -955,14 +968,16 @@ class FusedDistTreeEpoch(_MeshEpochDriver):
       xs, masks, y, stats7, hop_counts = self._expand_collect(
           seeds, key, indptr_s[0], indices_s[0], bounds, fshards_s[0],
           lshards_s[0])
+      with layer_scope('telemetry', 'exchange'):
+        stats7 = stats7[None]               # this device's row
       valid = seeds >= 0
       if not train:
         correct, total = self._eval_tail(state_or_params, xs, masks, y,
                                          valid)
-        return correct, total, stats7[None]
+        return correct, total, stats7
       state, loss, correct, n_valid, hop_g = self._train_tail(
           state_or_params, xs, masks, y, valid, hop_counts)
-      return state, loss, correct, n_valid, stats7[None], hop_g
+      return state, loss, correct, n_valid, stats7, hop_g
 
     ax = self.axis
     if train:
@@ -1088,7 +1103,7 @@ class FusedDistTreeEpoch(_MeshEpochDriver):
     state, (losses, corrects, valids, hops) = jax.lax.scan(
         body, state, data)
     return (state, losses, jnp.sum(corrects), jnp.sum(valids),
-            jnp.sum(hops, axis=0))
+            _scan_total(hops, 'hops'))
 
   def _eval_consume_fn(self, params, data):
     def body(carry, d):
@@ -1116,7 +1131,7 @@ class FusedDistTreeEpoch(_MeshEpochDriver):
     state, (losses, corrects, valids, stats, hops) = jax.lax.scan(
         body, state, (steps, seeds_all))
     return (state, losses, jnp.sum(corrects), jnp.sum(valids),
-            jnp.sum(stats, axis=0), jnp.sum(hops, axis=0))
+            _scan_total(stats, 'exchange'), _scan_total(hops, 'hops'))
 
   def _eval_fn(self, params, seeds_all: jax.Array, key: jax.Array,
                arrs: dict):
@@ -1131,7 +1146,7 @@ class FusedDistTreeEpoch(_MeshEpochDriver):
     steps = jnp.arange(seeds_all.shape[0], dtype=jnp.int32)
     _, (correct, total, stats) = jax.lax.scan(
         body, 0, (steps, seeds_all))
-    return jnp.sum(correct), jnp.sum(total), jnp.sum(stats, axis=0)
+    return jnp.sum(correct), jnp.sum(total), _scan_total(stats, 'exchange')
 
   # run()/evaluate() come from `_MeshEpochDriver`
 
@@ -1243,7 +1258,7 @@ class FusedDistLinkEpoch(_MeshEpochDriver):
     steps = jnp.arange(pairs_all.shape[0], dtype=jnp.int32)
     state, (losses, valids, stats) = jax.lax.scan(
         body, state, (steps, pairs_all))
-    return state, losses, jnp.sum(valids), jnp.sum(stats, axis=0)
+    return state, losses, jnp.sum(valids), _scan_total(stats, 'exchange')
 
   def _link_batch(self, pairs: jax.Array, key_i: jax.Array, arrs: dict):
     """One fused distributed link sample+collect (negatives +
@@ -1363,7 +1378,7 @@ class FusedDistLinkEpoch(_MeshEpochDriver):
 
     steps = jnp.arange(pairs_all.shape[0], dtype=jnp.int32)
     _, (wins, totals, stats) = jax.lax.scan(body, 0, (steps, pairs_all))
-    return jnp.sum(wins), jnp.sum(totals), jnp.sum(stats, axis=0)
+    return jnp.sum(wins), jnp.sum(totals), _scan_total(stats, 'exchange')
 
   def evaluate(self, params, edge_label_index,
                input_space: str = 'old') -> float:

@@ -101,9 +101,23 @@ metrics = Metrics()
 #:             types and the activation) | layer<l>/trim (typed: the
 #:             prefixes a trimmed layer reads) | head | loss | metrics
 #:   optimizer (none)
-#:   exchange  frontier | feature | pairs | grads | stats
+#:   exchange  frontier | feature | pairs | grads (the DP update's
+#:             collectives: the gradient and loss means and the valid
+#:             seed count that gates the update)
+#:   telemetry attribution (`parallel.exchange.dest_histogram`) |
+#:             exchange (a plan's offered / dropped sums and the stats
+#:             tail they are stacked into) | hops (per-hop valid
+#:             counts and their psum) | fill (`FusedLinkEpoch`'s batch
+#:             fill) | cold (the replica cache's local-hit count) |
+#:             negative (strict-negative slots lost)
+#:
+#: ``telemetry`` holds the counts a program computes only so that it
+#: can report them; work the step itself reads stays in its layer.  A
+#: telemetry scope is never opened inside another ``glt.*`` scope: the
+#: first token claims the op, so a nested one would be booked to the
+#: layer around it.
 LAYERS: Tuple[str, ...] = ('sample', 'gather', 'model', 'optimizer',
-                           'exchange')
+                           'exchange', 'telemetry')
 
 
 def layer_scope(layer: str, part: Optional[str] = None):
