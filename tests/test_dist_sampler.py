@@ -249,6 +249,97 @@ def test_bucket_capacity_drops_overflow_not_valid_ids():
     assert send[d, slot_p[d, i], slot_j[d, i]] == ids[0, i]
 
 
+def _bucket_contract(ids, owner, num_parts, cap):
+  """`bucket_by_owner`'s contract as a loop over the ids in request
+  order: an owner keeps its first ``cap`` valid ids, in order; an
+  invalid id takes no slot and reads ``(0, -1)``; a valid id past its
+  owner's ``cap`` reads ``(owner, -1)``."""
+  send = np.full((num_parts, cap), -1, ids.dtype)
+  slot_p = np.zeros(len(ids), np.int32)
+  slot_j = np.full(len(ids), -1, np.int32)
+  taken = np.zeros(num_parts, np.int64)
+  for i, (v, o) in enumerate(zip(ids, owner)):
+    if v < 0:
+      continue
+    slot_p[i] = o
+    if taken[o] < cap:
+      send[o, taken[o]] = v
+      slot_j[i] = taken[o]
+    taken[o] += 1
+  return send, slot_p, slot_j
+
+
+@pytest.mark.parametrize('invalid', ['first', 'last'])
+@pytest.mark.parametrize('owners', ['uniform', 'skewed'])
+@pytest.mark.parametrize('cap', ['below', 'above'])
+@pytest.mark.parametrize('num_parts', [2, 4, 8])
+def test_bucket_by_owner_keeps_its_contract(num_parts, cap, owners,
+                                            invalid):
+  """The pack (sorts and slices) against a NumPy loop of what it
+  promises, over caps below and above the busiest owner's load, owners
+  drawn uniformly or piled on a few, and a block of invalid ids at
+  either end; the payload of `bucket_with_payload` rides its id."""
+  from graphlearn_tpu.parallel.dist_sampler import (bucket_by_owner,
+                                                    bucket_with_payload)
+  rng = np.random.default_rng(num_parts)
+  f, bad = 240, 60
+  if owners == 'uniform':
+    owner = rng.integers(0, num_parts, f)
+  else:
+    owner = np.minimum(rng.geometric(0.5, f) - 1, num_parts - 1)
+  owner = owner.astype(np.int32)
+  ids = rng.permutation(10_000)[:f].astype(np.int32)
+  ids[:bad] = -1
+  if invalid == 'last':
+    ids = np.roll(ids, -bad)
+  load = np.bincount(owner[ids >= 0], minlength=num_parts).max()
+  c = load // 2 if cap == 'below' else None
+  want = _bucket_contract(ids, owner, num_parts, f if c is None else c)
+  if cap == 'below':
+    assert (want[2][ids >= 0] < 0).any()      # the cap does drop ids
+  got = jax.jit(lambda i, o: bucket_by_owner(i, o, num_parts, None, c))(
+      ids, owner)
+  for w, g in zip(want, got):
+    assert g.dtype == w.dtype
+    np.testing.assert_array_equal(np.asarray(g), w)
+  payload = (ids.astype(np.float32) + 0.5) * (ids >= 0)
+  send, send_pl, slot_p, slot_j = jax.jit(
+      lambda i, pl, o: bucket_with_payload(i, pl, o, num_parts, None, c))(
+          ids, payload, owner)
+  np.testing.assert_array_equal(np.asarray(send), want[0])
+  np.testing.assert_array_equal(
+      np.asarray(send_pl), np.where(want[0] >= 0, want[0] + 0.5, -1))
+  np.testing.assert_array_equal(np.asarray(slot_p), want[1])
+  np.testing.assert_array_equal(np.asarray(slot_j), want[2])
+
+
+@pytest.mark.parametrize('payload', [False, True])
+@pytest.mark.parametrize('f,cap', [(937_984, 468_992), (153_600, 76_800)])
+def test_bucket_by_owner_lowers_to_sorts_and_slices(f, cap, payload):
+  """At the four-chip cell's feature (937,984 ids) and widest frontier
+  (153,600) exchanges, P = 4 and slack 2: the compiled pack holds no
+  scatter and no gather (the ops the TPU prices worst), and at most
+  two sorts."""
+  import re
+  from graphlearn_tpu.parallel.dist_sampler import (bucket_by_owner,
+                                                    bucket_with_payload)
+  i32 = jax.ShapeDtypeStruct((f,), jnp.int32)
+  if payload:
+    fn = lambda i, o, pl: bucket_with_payload(i, pl, o, 4, None, cap)
+    args = (i32, i32, i32)
+  else:
+    fn = lambda i, o: bucket_by_owner(i, o, 4, None, cap)
+    args = (i32, i32)
+  lowered = jax.jit(fn).lower(*args)
+  text = lowered.as_text()
+  assert not re.search(r'stablehlo\.(gather|scatter)\b', text)
+  assert len(re.findall(r'stablehlo\.sort\b', text)) <= 2
+  hlo = lowered.compile().as_text()
+  ops = re.findall(r'\b(gather|scatter|sort)\(', hlo)
+  assert 'gather' not in ops and 'scatter' not in ops
+  assert ops.count('sort') <= 2
+
+
 def test_exchange_capacity_drops_are_masked():
   """A skewed workload (every seed targets partition 0's range) with a
   small slack: real drops happen, survivors stay correct.  The ring is

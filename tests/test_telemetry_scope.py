@@ -5,7 +5,8 @@ histogram is a compare and a dense sum, not a scatter.
 (a) `dest_histogram` equals the ``segment_sum`` form it replaced, bin
     for bin, at every P, and its program holds no scatter;
 (b) in the mesh tree epoch's program no ``s32[P+1]`` scatter is left
-    but the exchange plans' own per-owner counts, no op carries the
+    (nor, since the exchange packs by sorts and slices, any scatter of
+    an exchange), no op carries the
     old ``glt.exchange/stats``, and every op whose value reaches only
     the reported outputs (the stats tail, the hop counts) carries
     ``glt.telemetry/`` as its first ``glt.`` token;
@@ -136,11 +137,13 @@ def test_mesh_tree_program_counts_owners_without_a_scatter(tree_hlo):
     m = _OP_NAME.search(line)
     tok = _TOKEN.search(m.group(1)) if m else None
     scopes.append(tok.group(0) if tok else '')
-  # what is left is the dense pack's own per-owner count, one for each
-  # hop's frontier exchange and one for the feature exchange: exchange
-  # work, which this scope leaves where it is
-  assert sorted(scopes) == sorted(
-      ['glt.exchange/frontier'] * len(FANOUT) + ['glt.exchange/feature'])
+  # nor the dense pack's own per-owner count: the exchange plans pack
+  # by sorts and slices (`dist_sampler._pack`), so no exchange op of
+  # the program is a scatter at all
+  assert scopes == []
+  assert not [line for line in tree_hlo.splitlines()
+              if re.search(r' scatter\(', line)
+              and 'glt.exchange/' in line]
   assert 'glt.exchange/stats' not in tree_hlo
   names = _OP_NAME.findall(tree_hlo)
   parts = {t.group(0) for n in names for t in [_TOKEN.search(n)]
